@@ -181,7 +181,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=%d")
 import jax
-jax.config.update("jax_platforms", "cpu")
 sys.path.insert(0, %r)
 from test_collective_import import (_apply, _spread_wires)
 import numpy as np
@@ -230,8 +229,7 @@ def test_tpu_pipeline_ignored_warning_with_sharded_table(caplog):
             "interval": "10s",
             "tpu_mesh_shards": 2,
             "tpu_histo_rows": 64, "tpu_set_rows": 8,
-            "tpu_counter_rows": 16, "tpu_gauge_rows": 16,
-            "accelerator_probe_timeout": "0s"}))
+            "tpu_counter_rows": 16, "tpu_gauge_rows": 16}))
     try:
         assert srv.pipeline is False
         assert any("tpu_pipeline is ignored" in r.message

@@ -206,8 +206,7 @@ def test_mesh_sharded_server_conserves_under_concurrent_flushes():
     threads racing a flusher thread across swap boundaries must
     account for exactly every counter sample and every timer count,
     and set cardinality within estimator error."""
-    srv = _mk(tpu_mesh_shards=4, tpu_histo_rows=256, tpu_set_rows=32,
-              accelerator_probe_timeout="0s")
+    srv = _mk(tpu_mesh_shards=4, tpu_histo_rows=256, tpu_set_rows=32)
     writers = 4
     batches = 20
     per_batch = 25

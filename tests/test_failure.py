@@ -155,71 +155,55 @@ def test_raising_sink_isolated_from_others(make_server):
     assert server.stats.get("flush_errors", 0) >= 1
 
 
-def test_table_init_failure_retries_on_cpu(monkeypatch):
-    """A flapping accelerator can pass the startup probe and then
-    fail backend init: Server must retry the table on the CPU
-    backend instead of dying (metrics flow > speed)."""
-    import veneur_tpu.core.server as srv
+def test_backend_init_error_surfaces_and_names_the_platform(monkeypatch):
+    """A backend that cannot start fails the server: nothing probes,
+    nothing retries on another platform, and the error says which
+    platform was asked for (a deployment without a chip sets
+    JAX_PLATFORMS=cpu itself)."""
+    import jax
 
-    real_table = srv.MetricTable
-    calls = {"n": 0}
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu': "
+                           "no device found")
 
-    class Flaky:
-        def __new__(cls, cfg):
-            calls["n"] += 1
-            if calls["n"] == 1:
-                raise RuntimeError("Unable to initialize backend")
-            return real_table(cfg)
-
-    monkeypatch.setattr(srv, "MetricTable", Flaky)
+    monkeypatch.setattr(jax, "devices", no_backend)
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     cfg = read_config(data={"statsd_listen_addresses":
                             ["udp://127.0.0.1:0"],
-                            "interval": "50ms",
-                            "accelerator_probe_timeout": "1s"})
-    s = Server(cfg, extra_sinks=[CaptureSink()])
-    try:
-        assert calls["n"] == 2  # failed once, retried on cpu
-        from veneur_tpu.protocol import dogstatsd as dsd
-        s.table.ingest(dsd.parse_metric(b"ok:1|c"))
-        s.flush_once()
-    finally:
-        s.shutdown()
+                            "interval": "50ms"})
+    with pytest.raises(RuntimeError,
+                       match=r"JAX_PLATFORMS=tpu.*Unable to initialize"):
+        Server(cfg, extra_sinks=[CaptureSink()])
 
 
-def test_table_init_failure_reworded_message_still_falls_back(monkeypatch):
-    """The backend-init message text is a JAX-internal detail; a
-    rewording across upgrades must not silently disable the CPU
-    fallback."""
+def test_table_init_error_surfaces_after_the_platform_is_logged(
+        monkeypatch, caplog):
+    """Table construction is tried once; whatever it raises surfaces,
+    after the start-up line that names the platform in use."""
+    import logging
+
     import veneur_tpu.core.server as srv
 
-    real_table = srv.MetricTable
     calls = {"n": 0}
 
-    class Flaky:
+    class Broken:
         def __new__(cls, cfg):
             calls["n"] += 1
-            if calls["n"] == 1:
-                raise RuntimeError(
-                    "PJRT plugin for tunnel device failed to start")
-            return real_table(cfg)
+            raise RuntimeError("PJRT plugin failed to start")
 
-    monkeypatch.setattr(srv, "MetricTable", Flaky)
+    monkeypatch.setattr(srv, "MetricTable", Broken)
     cfg = read_config(data={"statsd_listen_addresses":
                             ["udp://127.0.0.1:0"],
-                            "interval": "50ms",
-                            "accelerator_probe_timeout": "1s"})
-    s = Server(cfg, extra_sinks=[CaptureSink()])
-    try:
-        assert calls["n"] == 2
-    finally:
-        s.shutdown()
+                            "interval": "50ms"})
+    with caplog.at_level(logging.INFO, logger="veneur_tpu.server"):
+        with pytest.raises(RuntimeError, match="PJRT plugin"):
+            Server(cfg, extra_sinks=[CaptureSink()])
+    assert calls["n"] == 1
+    assert "device: platform=cpu" in caplog.text
 
 
 def test_table_init_oom_surfaces(monkeypatch):
-    """An HBM OOM from an oversized table config must crash loudly,
-    never demote the operator to CPU silently."""
-    import pytest
-
+    """An HBM OOM from an oversized table config must crash loudly."""
     import veneur_tpu.core.server as srv
 
     class AlwaysOOM:
@@ -231,8 +215,7 @@ def test_table_init_oom_surfaces(monkeypatch):
     monkeypatch.setattr(srv, "MetricTable", AlwaysOOM)
     cfg = read_config(data={"statsd_listen_addresses":
                             ["udp://127.0.0.1:0"],
-                            "interval": "50ms",
-                            "accelerator_probe_timeout": "1s"})
+                            "interval": "50ms"})
     with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
         Server(cfg, extra_sinks=[CaptureSink()])
 

@@ -267,6 +267,23 @@ def test_compile_warmup_overrun_is_exempt():
     assert ovl.take_coalesce() is True
 
 
+def test_compile_warmup_lag_never_engages_pressure():
+    """Cold-start flushes that compiled feed no lag to the pressure
+    EWMA: a server whose idle occupancy sits above the exit band
+    (here 0.673 / 0.95 = 0.708 > 0.7, the PR 22 chip run) would
+    otherwise latch at level 1 for good and sample its sets."""
+    ovl = Overload()
+    for _ in range(3):               # 28 s cold flushes of a 10 s tick
+        ovl.tick(0, 0.673, 2.8, 0, compiled=True)
+    assert not ovl.pressure.engaged
+    assert ovl.pressure.flush_lag_ewma == 0.0
+    # sustained lag without compiles still engages, and there it stays
+    ovl.tick(0, 0.673, 2.8, 0)
+    assert ovl.pressure.engaged and ovl.pressure.level == 1
+    ovl.tick(0, 0.673, 0.0, 0, compiled=True)
+    assert ovl.pressure.flush_lag_ewma == 1.4
+
+
 def test_coalesce_disabled_never_arms():
     ovl = Overload(coalesce=False)
     ovl.note_flush(duration_s=5.0, budget_s=1.0)

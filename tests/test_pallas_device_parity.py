@@ -4,9 +4,10 @@ The interpret-mode suite (test_pallas_merge.py) pins kernel
 SEMANTICS; this test re-proves the invariants on real hardware where
 the Mosaic lowering (bf16 splits, polynomial asin, logical-op
 selects) actually runs.  Auto-skips on non-TPU backends — under the
-CI conftest (forced 8-device CPU mesh) it always skips; it exists
-for healthy-window device runs (bench.py --pallas-parity emits the
-matching artifact)."""
+CI conftest (forced 8-device CPU mesh) it always skips: pytest can
+never see a chip here, and ``chip_smoke.py`` holds the compiled
+kernel's results to a reference on the chip (bench.py --pallas-parity
+emits the matching artifact)."""
 
 import jax
 import pytest

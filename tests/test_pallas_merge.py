@@ -4,7 +4,7 @@ The kernel runs through the Pallas interpreter on the CPU mesh (the
 same ops, minus Mosaic lowering), so these tests pin its SEMANTICS —
 cluster assignment, weight conservation, packing contract, quantile
 accuracy — against ops/tdigest's scatter path.  Device timing A/Bs
-belong to the watcher (VENEUR_TPU_MERGE=pallas in a healthy window).
+belong to a chip run (VENEUR_TPU_MERGE=pallas there).
 """
 
 from __future__ import annotations
@@ -173,7 +173,7 @@ def test_wide_union_matches_scatter():
 def test_mode_dispatch_end_to_end():
     """VENEUR_TPU_MERGE=pallas routes table-level timer ingest through
     the fused kernel (interpret mode) and still flushes accurate
-    percentiles — the integration the watcher A/Bs on device."""
+    percentiles — the integration a chip run A/Bs on device."""
     code = """
 import numpy as np, jax.numpy as jnp
 from veneur_tpu.ops import tdigest

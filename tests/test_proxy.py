@@ -431,8 +431,7 @@ def test_full_chain_emit_to_mesh_sharded_global():
         "tpu_mesh_shards": 4,
         "tpu_histo_rows": 256, "tpu_set_rows": 16,
         "percentiles": [0.5, 0.99],
-        "interval": "10s",
-        "accelerator_probe_timeout": "0s"}), extra_sinks=[gcap])
+        "interval": "10s"}), extra_sinks=[gcap])
     g.start()
     proxy = ProxyServer(ProxyConfig(
         forward_address=f"127.0.0.1:{g.grpc_ports[0]}",
@@ -442,8 +441,7 @@ def test_full_chain_emit_to_mesh_sharded_global():
     local = Server(read_config(data={
         "statsd_listen_addresses": ["udp://127.0.0.1:0"],
         "forward_address": f"127.0.0.1:{proxy.grpc_port}",
-        "forward_use_grpc": True, "interval": "10s",
-        "accelerator_probe_timeout": "0s"}), extra_sinks=[lcap])
+        "forward_use_grpc": True, "interval": "10s"}), extra_sinks=[lcap])
     local.start()
     try:
         port = local.statsd_ports[0]

@@ -118,6 +118,32 @@ def test_hll_union_across_shards(mesh, cfg):
     assert est == pytest.approx(500, rel=0.1)
 
 
+def test_merge_reduces_no_plane_narrower_than_32_bits(mesh, cfg):
+    """A TPU all-reduces a u8 plane four rows to a 32-bit word and
+    keeps one shard's word whole (PR 22: the register pmax read
+    cardinalities 3-10 % low on four chips).  The CPU mesh reduces
+    u8 correctly and cannot show it, so hold the merge step's
+    reducing collectives to 32-bit operands."""
+    from veneur_tpu.parallel import sharded
+
+    def reductions(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name.startswith(("psum", "pmax", "pmin")):
+                yield eqn
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from reductions(inner)
+
+    state = sharded.empty_state(mesh, cfg)
+    found = list(reductions(jax.make_jaxpr(
+        sharded.make_merge_step(mesh, cfg))(state).jaxpr))
+    assert len(found) >= 9     # 3 counter/gauge, 5 stats, 1 registers
+    narrow = [(e.primitive.name, v.aval.dtype) for e in found
+              for v in e.invars if v.aval.dtype.itemsize < 4]
+    assert not narrow, narrow
+
+
 def test_row_sharding_routes_all_rows(mesh, cfg):
     """Rows across the whole table land in the right series block."""
     agg = ShardedAggregator(mesh, cfg)
@@ -197,8 +223,7 @@ def test_sharded_table_server_path_production_rows():
         "interval": "10s",
         "tpu_mesh_shards": 4,
         "tpu_histo_rows": 4096, "tpu_set_rows": 64,
-        "percentiles": [0.5, 0.99],
-        "accelerator_probe_timeout": "0s"}), extra_sinks=[cap])
+        "percentiles": [0.5, 0.99]}), extra_sinks=[cap])
     try:
         rng = np.random.default_rng(31)
         # 64 series x 256 samples of raw ingest across the mesh

@@ -248,7 +248,7 @@ def test_xray_segment_golden():
     carries common tags + every span tag + indicator, annotations the
     configured subset + indicator, the http block assembles from the
     http.*/client_ip tags, the name is charset-cleaned with the
-    -indicator suffix, namespace is remote — plus the taxonomy
+    -indicator suffix, namespace is remote — plus the classes
     extension (429 throttle / 4xx error / 5xx fault)."""
     from veneur_tpu.sinks.xray import XRaySpanSink
     sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
@@ -289,7 +289,7 @@ def test_xray_segment_golden():
     assert seg["trace_id"] == f"1-{(sp.start_timestamp // 10**9) & ~0xFF:08x}-{7:024x}"
 
 
-def test_xray_error_taxonomy_and_url_default():
+def test_xray_error_classes_and_url_default():
     from veneur_tpu.sinks.xray import XRaySpanSink
     sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
     sock.bind(("127.0.0.1", 0))
@@ -832,8 +832,7 @@ def test_flush_file_format_reference_end_to_end(tmp_path):
     srv = Server(read_config(data={
         "interval": "10s", "hostname": "h0",
         "flush_file": str(path),
-        "flush_file_format": "reference",
-        "accelerator_probe_timeout": "0s"}))
+        "flush_file_format": "reference"}))
     try:
         srv.table.ingest(dsd.Sample(name="ref.hits", type=dsd.COUNTER,
                                     value=20.0))

@@ -196,8 +196,7 @@ def test_extraction_sink_counts_and_error_total():
 
     cap = CaptureSink()
     srv = Server(read_config(data={
-        "interval": "10s", "hostname": "h",
-        "accelerator_probe_timeout": "0s"}), extra_sinks=[cap])
+        "interval": "10s", "hostname": "h"}), extra_sinks=[cap])
     ext = srv.span_sinks[0]
     assert ext.name == "ssfmetrics"
     span = _span(indicator=False)

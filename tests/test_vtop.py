@@ -25,8 +25,7 @@ def cluster():
         g = Server(read_config(data={
             "grpc_listen_addresses": ["tcp://127.0.0.1:0"],
             "http_address": "127.0.0.1:0",
-            "interval": "10s", "hostname": f"vtop-g{gi}",
-            "accelerator_probe_timeout": "5s"}))
+            "interval": "10s", "hostname": f"vtop-g{gi}"}))
         g.start()
         globals_.append(g)
     addrs = ",".join(
@@ -38,8 +37,7 @@ def cluster():
             "forward_address": addrs,
             "forward_use_grpc": True,
             "tpu_sharded_global": True,
-            "interval": "10s", "hostname": f"vtop-l{li}",
-            "accelerator_probe_timeout": "5s"}))
+            "interval": "10s", "hostname": f"vtop-l{li}"}))
         l.start()
         locals_.append(l)
     try:

@@ -277,10 +277,6 @@ class Config:
     # watchdog model) pays ~0.3s per kernel instead of 20-40s cold
     # compiles.  Empty disables.
     compile_cache_dir: str = ""
-    # startup accelerator probe: if the default device backend cannot
-    # be initialized within this window (subprocess probe), fall back
-    # to the CPU backend and keep serving.  "0s" disables the probe.
-    accelerator_probe_timeout: str = "60s"
     sentry_dsn: str = ""
     stats_address: str = ""
 
@@ -575,9 +571,6 @@ class Config:
                 self.lightstep_reconnect_period == "5m":
             self.lightstep_reconnect_period = \
                 self.trace_lightstep_reconnect_period
-
-    def accelerator_probe_timeout_seconds(self) -> float:
-        return parse_duration(self.accelerator_probe_timeout)
 
     def interval_seconds(self) -> float:
         return parse_duration(self.interval)

@@ -128,13 +128,20 @@ class PressureSignals:
         self.transitions = 0
 
     def update(self, staging_depth: int, occupancy: float,
-               flush_lag_ratio: float, socket_drop_delta: int) -> None:
+               flush_lag_ratio: float, socket_drop_delta: int,
+               compiled: bool = False) -> None:
         self.staging_depth = int(staging_depth)
         self.occupancy = float(occupancy)
         # EWMA so one slow flush doesn't engage and one fast flush
-        # doesn't disengage (alpha 0.5: ~2 intervals of memory)
-        self.flush_lag_ewma = (0.5 * self.flush_lag_ewma +
-                               0.5 * float(flush_lag_ratio))
+        # doesn't disengage (alpha 0.5: ~2 intervals of memory).  A
+        # flush that triggered XLA compiles leaves it where it was:
+        # warm-up is a one-time cost, not sustained lag (the same
+        # exemption the overrun watchdog makes in note_flush), and a
+        # server whose idle occupancy sits above exit_ratio would
+        # never release what a cold start engaged
+        if not compiled:
+            self.flush_lag_ewma = (0.5 * self.flush_lag_ewma +
+                                   0.5 * float(flush_lag_ratio))
         self.socket_drop_delta = int(socket_drop_delta)
         sig = max(
             self.staging_depth / self.staging_hi,
@@ -469,11 +476,12 @@ class Overload:
 
     def tick(self, staging_depth: int, occupancy: float,
              flush_lag_ratio: float,
-             socket_drop_delta: int) -> None:
+             socket_drop_delta: int, compiled: bool = False) -> None:
         """Per-flush pressure update (called from the flush path)."""
         was = self.pressure.engaged
         self.pressure.update(staging_depth, occupancy,
-                             flush_lag_ratio, socket_drop_delta)
+                             flush_lag_ratio, socket_drop_delta,
+                             compiled=compiled)
         if self.pressure.engaged != was:
             log.warning(
                 "overload pressure %s (score=%.2f level=%d "

@@ -587,17 +587,16 @@ class PlaneExchange:
         self.n_proc = int(np.prod(mesh.devices.shape))
         self._fn = None
         if self.n_proc > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
 
             def body(x):
                 return jax.lax.all_to_all(
                     x, FWD_AXIS, split_axis=0, concat_axis=0)
 
-            self._fn = shard_map(body, mesh=mesh,
-                                 in_specs=P(FWD_AXIS),
-                                 out_specs=P(FWD_AXIS),
-                                 check_rep=False)
+            self._fn = jax.shard_map(body, mesh=mesh,
+                                     in_specs=P(FWD_AXIS),
+                                     out_specs=P(FWD_AXIS),
+                                     check_vma=False)
 
     def __call__(self, local_blocks: np.ndarray) -> np.ndarray:
         """``local_blocks`` u8[n_proc, block]: row d = block destined

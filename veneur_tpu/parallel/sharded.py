@@ -29,7 +29,8 @@ State planes (leading axis = shard, rows sharded over series):
   histo_stats   f32[S, R, 5]     merge: psum / pmin / pmax per column
   histo_means   f32[S, R, C]     merge: all_gather slots + one k-scale
   histo_weights f32[S, R, C]            re-cluster (ops.tdigest)
-  hll           u8[S, R, M]      merge: pmax over shard (register max)
+  hll           u8[S, R, M]      merge: pmax over shard (register max,
+                                        reduced as i32)
 
 The update step and the merge step are each one ``shard_map``-ped jitted
 function; everything between flushes is pure per-device work with zero
@@ -46,7 +47,6 @@ import jax.numpy as jnp
 
 from veneur_tpu.utils import jitopts
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veneur_tpu.ops import hll as hll_ops
@@ -253,9 +253,9 @@ def make_update_step(mesh: Mesh, cfg: ShardedConfig):
             "hll": regs[None],
         }
 
-    mapped = shard_map(step, mesh=mesh,
+    mapped = jax.shard_map(step, mesh=mesh,
                        in_specs=(state_specs, batch_specs()),
-                       out_specs=state_specs, check_rep=False)
+                       out_specs=state_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=jitopts.donate(0))
 
 
@@ -307,13 +307,17 @@ def make_merge_step(mesh: Mesh, cfg: ShardedConfig):
         mm, mw = tdigest._merge_impl(zm, zw, gm, gw,
                                      compression=cfg.compression)
 
-        regs = jax.lax.pmax(state["hll"][0], SHARD)
+        # widened for the all-reduce: a TPU reduces a u8 plane four
+        # rows to a 32-bit word and keeps the whole word of the shard
+        # whose word is largest, dropping the other shards' registers
+        regs = jax.lax.pmax(state["hll"][0].astype(jnp.int32),
+                            SHARD).astype(jnp.uint8)
 
         return {"counters": cnt, "gauges": gauges, "histo_stats": stats,
                 "histo_means": mm, "histo_weights": mw, "hll": regs}
 
-    mapped = shard_map(merge, mesh=mesh, in_specs=(state_specs,),
-                       out_specs=merged_specs, check_rep=False)
+    mapped = jax.shard_map(merge, mesh=mesh, in_specs=(state_specs,),
+                       out_specs=merged_specs, check_vma=False)
     return jax.jit(mapped)
 
 
@@ -449,10 +453,10 @@ class CollectiveWireFold:
             return tdigest._merge_impl(sub_m, sub_w, gm, gw,
                                        compression=comp)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fold, mesh=mesh,
             in_specs=(P(), P(), P(SHARD), P(SHARD), P(SHARD)),
-            out_specs=(P(), P()), check_rep=False)
+            out_specs=(P(), P()), check_vma=False)
 
         @partial(jax.jit, donate_argnums=jitopts.donate(0, 1))
         def run(means, weights, row_idx, stack_m, stack_w, live):
@@ -1061,6 +1065,12 @@ class ShardedTable:
         invalid for the conservation ledger."""
         return (self.counter_idx.overflow + self.gauge_idx.overflow +
                 self.histo_idx.overflow + self.set_idx.overflow)
+
+    def plane_devices(self) -> dict[str, int]:
+        """How many devices hold each state plane: the mesh's size
+        for every plane when the table is spread."""
+        return {name: len(a.sharding.device_set)
+                for name, a in self.agg.state.items()}
 
     def device_step(self, final: bool = False) -> None:
         if final or self._staged_n >= self.cfg.batch:

@@ -10,7 +10,7 @@ an http block assembled from the ``http.url``/``http.method``/
 default, name cleaned by the X-Ray charset regex and capped at 190
 with the ``-indicator`` suffix, namespace ``remote``.  On top of the
 reference's single ``error`` flag, status codes map onto X-Ray's full
-taxonomy (segment-document spec): 429 -> ``throttle``, other 4xx ->
+classes (segment-document spec): 429 -> ``throttle``, other 4xx ->
 ``error``, 5xx -> ``fault``.
 """
 
@@ -137,7 +137,7 @@ class XRaySpanSink(SpanTagExcluder):
             "start_time": span.start_timestamp / 1e9,
             "end_time": span.end_timestamp / 1e9,
             "namespace": "remote",
-            # error taxonomy (X-Ray segment-document spec): client
+            # error classes (X-Ray segment-document spec): client
             # errors -> error, throttling -> throttle, server faults
             # -> fault; the span's own error flag keeps mapping to
             # error like the reference's single flag (xray.go:230)

@@ -1,63 +1,57 @@
 """Persistent XLA compilation cache policy, in one place.
 
-Restart-after-crash (the flush-watchdog model) pays ~0.3s per kernel
-load instead of 20-40s cold compiles when the cache is enabled.  The
+Restart-after-crash (the flush-watchdog model) loads each kernel from
+disk instead of compiling it again when the cache is enabled.  The
 policy knobs (minimum compile time worth persisting) live here so the
-server and the bench can't drift.
+server, the bench and ``chip_smoke.py`` can't drift.
 
-``VENEUR_TPU_COMPILE_CACHE`` gates the cache for embedders that go
-through ``enable_from_env``: unset/``1`` uses the per-user default
-directory, ``0``/``off`` disables persistence, any other value is
-taken as the cache directory path.
+Where the cache lives, in this order: ``JAX_COMPILATION_CACHE_DIR``
+when the environment sets it (JAX reads it itself; nothing here sets a
+directory then, so whoever runs the program can place the cache); else
+the path the caller gives, a relative one resolved against the
+checkout root; else ``<checkout>/.jax_cache``.  A cache directory
+that moves never hits, so none of these is built from a temporary
+name, a uid, a pid or the time.
 """
 
 from __future__ import annotations
 
 import os
-import tempfile
 
-ENV_VAR = "VENEUR_TPU_COMPILE_CACHE"
+JAX_ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 _monitoring_installed = False
 
 
-def default_cache_dir() -> str:
-    """Per-user path: a world-shared fixed /tmp name would let another
-    local user squat the directory or plant cache entries."""
-    return os.path.join(tempfile.gettempdir(),
-                        f"veneur_tpu_jax_cache_{os.getuid()}")
+def resolve_dir(path: str = "") -> str | None:
+    """The directory ``enable(path)`` sets in code, or None when the
+    environment already placed the cache and code sets none."""
+    if os.environ.get(JAX_ENV_VAR):
+        return None
+    return os.path.join(CHECKOUT_ROOT, path or ".jax_cache")
 
 
-def enable(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path``.  Returns
-    True when the directory already held entries (a warm cache) —
-    callers that report compile times should surface this, since warm
-    'cold intervals' measure cache loads, not compiles."""
+def enable(path: str = "") -> bool:
+    """Turn on JAX's persistent compilation cache (see the module
+    docstring for where).  Returns True when the directory already
+    held entries (a warm cache) — callers that report compile times
+    should surface this, since warm 'cold intervals' measure cache
+    loads, not compiles."""
     import jax
-    warm = False
-    try:
-        warm = bool(os.listdir(path))
-    except OSError:
-        pass
-    jax.config.update("jax_compilation_cache_dir", path)
+    directory = resolve_dir(path)
+    if directory is not None:
+        jax.config.update("jax_compilation_cache_dir", directory)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       0.5)
     install_monitoring()
-    return warm
-
-
-def enable_from_env() -> bool | None:
-    """Enable the persistent cache per ``VENEUR_TPU_COMPILE_CACHE``
-    (see module docstring).  Returns the warm flag from ``enable``,
-    or None when the env var disables persistence."""
-    raw = os.environ.get(ENV_VAR, "").strip()
-    if raw.lower() in ("0", "off", "false", "no"):
-        return None
-    if raw in ("", "1", "on", "true", "yes"):
-        return enable(default_cache_dir())
-    return enable(raw)
+    try:
+        return bool(os.listdir(jax.config.jax_compilation_cache_dir))
+    except OSError:
+        return False
 
 
 def install_monitoring(registry=None) -> None:
