@@ -1,0 +1,17 @@
+"""The local turning its forward rows into the wire's bytes (a
+serialized MetricList): stage forward.encode of its flush ring, mean
+a cycle of the window.  A program without that stage reads nothing."""
+LAYER = "forward"
+UNIT = "ms"
+MOVES = "flush_lag_ms"
+STAGES = ("forward.encode",)
+
+
+def read(run):
+    cycles = [r["stages"] for r in run["rings"]["local"]
+              if r["start_unix"] <= run["t_end"]
+              and STAGES[0] in r["stages"]]
+    if not cycles:
+        return None
+    return sum(s.get(k, 0) for s in cycles
+               for k in STAGES) / len(cycles) / 1e6

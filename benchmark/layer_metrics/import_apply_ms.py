@@ -1,0 +1,23 @@
+"""The global folding a decoded wire into its table under the ingest
+lock, and the device step after it when one is due: stages
+import.apply + import.device_step of its flush ring, mean over the
+global's cycles that follow the window's cycles of the local by one
+interval (see import_decode_ms).  A program without those stages
+reads nothing."""
+LAYER = "import decode and fold"
+UNIT = "ms"
+MOVES = "flush_lag_ms"
+STAGES = ("import.apply", "import.device_step")
+
+
+def read(run):
+    iv = run["interval_s"]
+    sent = [r["start_unix"] for r in run["rings"]["local"]
+            if r["start_unix"] <= run["t_end"]]
+    cycles = [r["stages"] for r in run["rings"]["global"]
+              if STAGES[0] in r["stages"] and any(
+                  0.5 * iv < r["start_unix"] - t < 1.5 * iv for t in sent)]
+    if not cycles:
+        return None
+    return sum(s.get(k, 0) for s in cycles
+               for k in STAGES) / len(cycles) / 1e6

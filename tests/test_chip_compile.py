@@ -86,6 +86,8 @@ def test_pallas_merge_with_tail_refinement(shape, batch):
         shape((ROWS, CAP)), shape((ROWS, CAP)),
         shape((ROWS, batch)), shape((ROWS, batch))).compile()
     assert _has_kernel(compiled)
+    # the kernel's own name and static shape, as a trace will show it
+    assert f"%tdigest_merge_c{CAP}_k{batch}" in compiled.as_text()
 
 
 def test_superbatch_step_with_kernel_inlined(shape, monkeypatch):
@@ -106,6 +108,12 @@ def test_superbatch_step_with_kernel_inlined(shape, monkeypatch):
         shape((ROWS, segment.HISTO_STAT_COLS)),
         shape((0,), jnp.uint8), shape((words,), jnp.int32)).compile()
     assert _has_kernel(compiled)
+    # every class's operations carry its scope, the kernel its name
+    text = compiled.as_text()
+    assert ("jit(_fused)/sb.histo/jit(ingest_ranked_unit)/"
+            f"tdigest_merge_c{CAP}_k256/pallas_call") in text
+    assert "jit(_fused)/sb.counter/" in text
+    assert "jit(_fused)/sb.gauge/" in text
 
 
 def test_hll_insert_packed(shape):

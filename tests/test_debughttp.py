@@ -127,9 +127,10 @@ def test_debug_flushes_empty_then_populated(server):
     assert len(recs) == 1
     rec = recs[0]
     assert rec["seq"] == 1
-    for stage in ("snapshot", "device_dispatch", "readback_sync",
+    for stage in ("snapshot", "dispatch", "device_wait",
                   "host_emit", "sink_flush"):
         assert rec["stages_ns"][stage] >= 0
+    assert rec["forward_bytes"] == 0 and rec["imports"] == 0
     assert rec["readback_bytes"] > 0
     assert rec["tally"]["counters"] == 1
     assert rec["duration_ns"] > 0

@@ -36,11 +36,9 @@ def _wait(pred, timeout=10.0):
 
 STAGES = ("snapshot", "dispatch", "device_wait",
           "host_emit", "sink_flush")
-# renamed-stage dashboard aliases: recorded in stage ns (so legacy
-# veneur.flush.stage_duration_ns series keep flowing) but NOT emitted
-# as their own spans
-LEGACY_ALIASES = {"dispatch": "device_dispatch",
-                  "device_wait": "readback_sync"}
+# the names dispatch / device_wait had before the pipeline split them
+# apart: recorded nowhere any more
+DEAD_NAMES = {"device_dispatch", "readback_sync"}
 
 
 # ---------------------------------------------------------------------
@@ -94,18 +92,21 @@ def test_flush_ring_record_matches_cycle():
         srv.flush_once()
         recs = srv.flush_ring.records()
         assert [r.seq for r in recs] == [1, 2]
-        aliases = set(LEGACY_ALIASES.values())
         for rec in recs:
-            assert set(rec.stages) >= set(STAGES) | aliases
+            # pipelined swap (the default): swap_apply is a stage too
+            assert set(rec.stages) >= set(STAGES) | {"swap_apply"}
+            assert not DEAD_NAMES & set(rec.stages)
             assert all(ns >= 0 for ns in rec.stages.values())
-            # each alias mirrors its renamed stage exactly
-            for new, old in LEGACY_ALIASES.items():
-                assert rec.stages[old] == rec.stages[new]
-            # canonical stages are disjoint intervals inside the
-            # cycle (aliases are recording duplicates, not stages)
+            # top-level stages are disjoint intervals inside the
+            # cycle (a dotted stage is a child of the one it names)
             assert sum(ns for k, ns in rec.stages.items()
-                       if k not in aliases) <= rec.duration_ns
+                       if "." not in k) <= rec.duration_ns
             assert rec.error == ""
+            # nothing forwarded, nothing imported: both read zero
+            d = rec.to_dict()
+            assert d["forward_bytes"] == 0 and d["imports"] == 0
+            assert not any(k.startswith(("import", "forward"))
+                           for k in rec.stages)
         # the interval that carried the metrics read them back
         assert recs[0].readback_bytes > 0
         assert recs[0].tally["counters"] == 1
@@ -113,6 +114,76 @@ def test_flush_ring_record_matches_cycle():
         assert recs[0].metrics_emitted > 0
     finally:
         srv.shutdown()
+
+
+def _host_events(trace_dir, prefixes):
+    """name -> [(start_ns, duration_ns)] of the host planes' events
+    whose name starts with one of ``prefixes``, read back from the
+    capture's ``.xplane.pb``."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(trace_dir, "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    assert len(paths) == 1, paths
+    out = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(prefixes):
+                    out.setdefault(e.name, []).append(
+                        (e.start_ns, e.duration_ns))
+    return out
+
+
+def test_flush_stages_are_events_on_the_profilers_clock(tmp_path):
+    """Under a profiler session every stage of the flush record is
+    also a ``flush.<stage>`` event on the capture's host lines, of
+    the stage's own length, inside the cycle's ``flush`` event; so
+    are the reader's batch and the staged apply (``ingest.batch``,
+    ``apply.staged``), which have no SSF span."""
+    srv = Server(read_config(data={
+        "statsd_listen_addresses": ["udp://127.0.0.1:0"],
+        "interval": "10s", "hostname": "prof-host"}),
+        extra_sinks=[CaptureSink()])
+    srv.start()
+    try:
+        srv.handle_packet(b"prof.hits:3|c")
+        srv.flush_once()          # compiles happen outside the capture
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
+            sock.sendto(b"\n".join(b"prof.lat:%d|ms" % v
+                                   for v in range(8)),
+                        ("127.0.0.1", srv.statsd_ports[0]))
+            sock.close()
+            assert _wait(lambda: srv.stats["metrics_processed"] >= 9)
+            srv.flush_once()
+        finally:
+            jax.profiler.stop_trace()
+        rec = srv.flush_ring.records()[-1]
+    finally:
+        srv.shutdown()
+    events = _host_events(str(tmp_path),
+                          ("flush", "ingest.batch", "apply.staged"))
+    assert set(rec.stages) >= set(STAGES)
+    for stage, ns in rec.stages.items():
+        got = events.get(f"flush.{stage}")
+        assert got, (stage, sorted(events))
+        assert abs(sum(d for _, d in got) - ns) <= max(2e6, 0.1 * ns)
+    (c0, cd), = events["flush"]
+    assert abs(cd - rec.duration_ns) <= max(2e6, 0.1 * rec.duration_ns)
+    for name, got in events.items():
+        if name.startswith("flush."):
+            assert all(c0 <= s and s + d <= c0 + cd for s, d in got)
+    # the datagram's batch on the reader's thread; the interval's
+    # staged samples applied inside the swap
+    assert events["ingest.batch"]
+    (a0, ad), = events["apply.staged"]
+    (s0, sd), = events["flush.swap_apply"]
+    assert s0 <= a0 and a0 + ad <= s0 + sd
 
 
 # ---------------------------------------------------------------------

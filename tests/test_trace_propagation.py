@@ -64,9 +64,9 @@ def _last_flush_trace(server) -> int:
     return int(recs[-1].trace_id)
 
 
-def _forward_span(server, tid):
+def _forward_span(server, tid, name="flush.forward"):
     spans = server.trace_index.get(tid)
-    fwd = [s for s in spans if s["name"] == "flush.forward"]
+    fwd = [s for s in spans if s["name"] == name]
     assert fwd, [s["name"] for s in spans]
     return fwd[-1]
 
@@ -100,9 +100,9 @@ def test_http_chain_single_stitched_trace(make_server):
 
     # the global indexed its import span under the SAME trace id,
     # parented under the local's forward span
-    assert _wait(lambda: glob.trace_index.get(tid))
+    assert _wait(lambda: any(
+        s["name"] == "import" for s in glob.trace_index.get(tid)))
     imp = [s for s in glob.trace_index.get(tid) if s["name"] == "import"]
-    assert imp, glob.trace_index.get(tid)
     sp = imp[-1]
     assert sp["trace_id"] == str(tid)
     assert sp["parent_id"] == fwd["span_id"]
@@ -130,32 +130,94 @@ def test_http_chain_single_stitched_trace(make_server):
     assert any(r.get("trace_id") == str(tid) for r in d)
 
 
+IMPORT_STEPS = ("import.decode", "import.lock_wait", "import.apply",
+                "import.device_step")
+
+
 def test_grpc_chain_single_stitched_trace(make_server):
+    """The gRPC forward is split from inside: ``forward.encode`` and
+    ``forward.send`` under the local's ``forward`` stage, and on the
+    global a real ``import`` span tree under the local's
+    ``forward.send``, whose durations land in the global's next
+    flush record."""
     pytest.importorskip("grpc")
     glob, _ = make_server(
         grpc_listen_addresses=["tcp://127.0.0.1:0"],
-        statsd_listen_addresses=[])
+        statsd_listen_addresses=[], http_address="127.0.0.1:0")
     local, _ = make_server(
         forward_address=f"127.0.0.1:{glob.grpc_ports[0]}",
         forward_use_grpc=True)
     for v in range(50):
         _send_udp(local, f"tg.lat:{v}|ms".encode())
     assert _wait(lambda: local.stats.get("metrics_processed", 0) >= 50)
-    local.flush_once()
+    res = local.flush_once()
     assert _wait(lambda: glob.stats.get("imports_received", 0) >= 1)
+
+    # the local's record: the two halves, inside the forward stage
+    rec = local.flush_ring.records()[-1]
+    enc, snd = rec.stages["forward.encode"], rec.stages["forward.send"]
+    assert enc > 0 and snd > 0
+    assert enc + snd <= rec.stages["forward"]
+    # (c) the ledger's wire bytes are the serialized body's length
+    from veneur_tpu.forward.grpc_forward import rows_to_metric_list
+    body = rows_to_metric_list(
+        res.forward, float(local.config.tpu_compression)
+    ).SerializeToString()
+    assert rec.forward_bytes == len(body) > 0
+    led = local.ledger.records()[-1].to_dict()
+    assert led["forward_wire"] == {**led["forward_wire"],
+                                   "rows": len(res.forward),
+                                   "bytes": len(body)}
 
     tid = _last_flush_trace(local)
     fwd = _forward_span(local, tid)
-    assert _wait(lambda: glob.trace_index.get(tid))
-    imp = [s for s in glob.trace_index.get(tid) if s["name"] == "import"]
-    assert imp
-    assert imp[-1]["parent_id"] == fwd["span_id"]
-    assert imp[-1]["tags"]["protocol"] == "grpc"
+    halves = {s["name"]: s for s in local.trace_index.get(tid)
+              if s["name"].startswith("flush.forward.")}
+    assert set(halves) == {"flush.forward.encode", "flush.forward.send"}
+    assert all(h["parent_id"] == fwd["span_id"] for h in halves.values())
+    send = halves["flush.forward.send"]
+    assert send["tags"]["bytes"] == str(len(body))
+    assert halves["flush.forward.encode"]["tags"]["rows"] == str(
+        len(res.forward))
+
+    # the global's tree, as /debug/trace/<id> serves it: import under
+    # the local's forward.send, its steps under import, all with a
+    # real extent
+    assert len(glob.trace_index.get(tid)) == 5
+    d = json.loads(urllib.request.urlopen(
+        f"http://127.0.0.1:{glob.http_port}/debug/trace/{tid}",
+        timeout=5).read())
+    spans = {s["name"]: s for s in d["spans"]}
+    imp = spans["import"]
+    assert imp["parent_id"] == send["span_id"]
+    assert imp["end_ns"] > imp["start_ns"]
+    assert imp["tags"]["protocol"] == "grpc"
+    assert imp["tags"]["bytes"] == str(len(body))
+    assert int(imp["tags"]["accepted"]) == len(res.forward)
+    for step in IMPORT_STEPS:
+        assert spans[step]["parent_id"] == imp["span_id"]
+        assert imp["start_ns"] <= spans[step]["start_ns"]
+        assert spans[step]["end_ns"] <= imp["end_ns"]
+    # the import ran inside the local's send, on one wall clock
+    assert send["start_ns"] <= imp["start_ns"]
+    assert imp["end_ns"] <= send["end_ns"]
+
+    # into the ring: the global's next cycle takes what the handler
+    # cost, once
+    glob.flush_once()
+    grec = glob.flush_ring.records()[-1]
+    assert grec.imports == 1
+    steps = sum(grec.stages[k] for k in IMPORT_STEPS)
+    assert grec.stages["import"] >= steps > 0
+    assert grec.stages["import"] <= snd
+    glob.flush_once()
+    grec = glob.flush_ring.records()[-1]
+    assert grec.imports == 0 and "import" not in grec.stages
 
 
 def test_proxy_hop_parents_both_sides(make_server):
     """local -> proxy (gRPC) -> global: the proxy's route span
-    parents under the local's forward span, and the global's import
+    parents under the local's forward.send span, and the global's import
     span parents under the proxy hop — one three-process tree."""
     pytest.importorskip("grpc")
     from veneur_tpu.core.config import ProxyConfig
@@ -180,7 +242,8 @@ def test_proxy_hop_parents_both_sides(make_server):
         assert _wait(lambda: glob.stats.get("imports_received", 0) >= 1)
 
         tid = _last_flush_trace(local)
-        fwd = _forward_span(local, tid)
+        # the gRPC wire carries the ids of the span that ships it
+        fwd = _forward_span(local, tid, "flush.forward.send")
         assert _wait(lambda: proxy.trace_index.get(tid))
         route = [s for s in proxy.trace_index.get(tid)
                  if s["name"] == "proxy.route"]
@@ -189,10 +252,10 @@ def test_proxy_hop_parents_both_sides(make_server):
         assert rsp["parent_id"] == fwd["span_id"]
         assert rsp["service"] == "veneur-proxy"
 
-        assert _wait(lambda: glob.trace_index.get(tid))
+        assert _wait(lambda: any(
+            s["name"] == "import" for s in glob.trace_index.get(tid)))
         imp = [s for s in glob.trace_index.get(tid)
                if s["name"] == "import"]
-        assert imp
         # the global hangs under the PROXY hop, not the local directly
         assert imp[-1]["parent_id"] == rsp["span_id"]
 
@@ -259,9 +322,10 @@ def test_import_span_records_drops(make_server):
         method="POST")
     resp = json.loads(urllib.request.urlopen(req, timeout=5).read())
     assert resp["accepted"] == 1
-    spans = glob.trace_index.get(777000111)
-    assert len(spans) == 1
-    sp = spans[0]
+    spans = {s["name"]: s for s in glob.trace_index.get(777000111)}
+    assert set(spans) == {"import", *IMPORT_STEPS}
+    sp = spans["import"]
     assert sp["parent_id"] == "555000999"
+    assert sp["end_ns"] > sp["start_ns"]
     assert sp["tags"]["accepted"] == "1"
     assert sp["tags"]["dropped"] == "1"

@@ -328,13 +328,11 @@ class Flusher:
         Two traced stages: ``dispatch`` covers the async kernel
         launches (dispatch wall time only), ``device_wait`` covers
         the blocking device_get plus host re-scatter — the stage whose
-        span duration IS the d2h cost an operator wants attributed.
-        The old ``device_dispatch`` / ``readback_sync`` names are kept
-        as recording aliases for existing dashboards."""
-        with cycle.stage("dispatch", alias="device_dispatch") as sp:
+        span duration IS the d2h cost an operator wants attributed."""
+        with cycle.stage("dispatch") as sp:
             devs, pre, expand = self._dispatch(snap)
             sp.add_tag("device_arrays", str(len(devs)))
-        with cycle.stage("device_wait", alias="readback_sync") as sp:
+        with cycle.stage("device_wait") as sp:
             got = jax.device_get(devs)
             nbytes = int(sum(getattr(v, "nbytes", 0)
                              for v in got.values()))

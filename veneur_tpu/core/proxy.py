@@ -602,9 +602,11 @@ class ProxyServer:
                         credentials=(
                             self._grpc_channel_credentials()))
                     self._clients[dest] = client
-            client._call(forward_pb2.MetricList(metrics=batch),
-                         timeout=self.config.forward_timeout,
-                         metadata=metadata)
+            client.send_wire(
+                forward_pb2.MetricList(
+                    metrics=batch).SerializeToString(),
+                timeout=self.config.forward_timeout,
+                metadata=metadata)
             self.bump("forwards_sent")
         except (grpc.RpcError, OSError) as e:
             # dropped-and-counted, never retried within a flush

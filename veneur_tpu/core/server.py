@@ -36,6 +36,7 @@ from veneur_tpu.core.table import MetricTable, TableConfig
 import numpy as np
 
 from veneur_tpu.forward import http_import
+from veneur_tpu.observe import ImportSpan, annotate
 from veneur_tpu.protocol import columnar, dogstatsd as dsd
 from veneur_tpu.protocol.addr import parse_addr
 from veneur_tpu.sinks import base as sinks_base
@@ -329,6 +330,10 @@ class Server:
         # read-modify-write is not atomic, so guard with a dedicated
         # lock (cheaper than widening self.lock's critical sections)
         self._stats_lock = threading.Lock()
+        # import handlers' durations and wire count since the last
+        # flush cycle took them (under _stats_lock)
+        self._import_stages: dict[str, int] = {}
+        self._import_wires = 0
         self._pprof_lock = threading.Lock()
         self.stats: dict[str, int] = {
             "packets_received": 0, "packet_errors": 0,
@@ -869,27 +874,33 @@ class Server:
                 self.bump("metrics_shed", shed)
         return processed, dropped
 
-    def note_import_span(self, protocol: str, accepted: int,
-                         dropped: int, trace_id: int, span_id: int,
-                         nbytes: int = 0) -> None:
-        """Record this tier's half of a cross-process flush trace: the
-        sending tier stamped its cycle's (trace_id, span_id) onto the
-        wire (X-Veneur-Trace header / veneur-trace-* gRPC metadata),
-        so the import span recorded here parents under the remote
-        forward span and the whole interval stitches into one tree at
-        /debug/trace/<trace_id> on either end."""
-        if not trace_id or not getattr(self.config,
-                                       "tpu_trace_propagation", True):
-            return
-        from veneur_tpu.trace.spans import Span
-        sp = Span("import", service="veneur", trace_id=trace_id,
-                  parent_id=span_id,
-                  tags={"protocol": protocol,
-                        "accepted": str(accepted),
-                        "dropped": str(dropped),
-                        "bytes": str(nbytes)})
-        sp.finish(self.trace_client)
-        self.trace_index.add(sp.proto)
+    def import_span(self, protocol: str, trace_id: int, span_id: int):
+        """This tier's half of a cross-process flush trace, opened at
+        an import handler's entry and closed at its exit
+        (observe.ImportSpan): the ``import`` span and its steps, under
+        the remote ``forward.send`` span when the wire carried its
+        ids, and their durations into the next flush record."""
+        if not getattr(self.config, "tpu_trace_propagation", True):
+            trace_id = 0
+        return ImportSpan(self.trace_client, self.trace_index,
+                          self._note_import, protocol, trace_id,
+                          span_id)
+
+    def _note_import(self, stages: dict[str, int]) -> None:
+        with self._stats_lock:
+            self._import_wires += 1
+            for name, ns in stages.items():
+                self._import_stages[name] = (
+                    self._import_stages.get(name, 0) + ns)
+
+    def _take_imports(self, record) -> None:
+        """Move what the import handlers cost since the last cycle
+        into ``record``; called in the swap's lock round, as the
+        ledger's interval close is."""
+        with self._stats_lock:
+            stages, self._import_stages = self._import_stages, {}
+            record.imports, self._import_wires = self._import_wires, 0
+        record.stages.update(stages)
 
     def _maybe_device_step_locked(self):
         """Mid-interval device step once enough samples are staged
@@ -1439,10 +1450,11 @@ class Server:
             # MSG_DONTWAIT sweep would BLOCK on the timeout socket,
             # CPython retries flagged recvs until the timeout)
             t0 = time.monotonic_ns()
-            processed = self.handle_packet_batch(
-                batch, parser, drained=drained,
-                drained_pkts=int(drain_n.value) if drained else 0,
-                shard=shard)
+            with annotate("ingest.batch"):
+                processed = self.handle_packet_batch(
+                    batch, parser, drained=drained,
+                    drained_pkts=int(drain_n.value) if drained else 0,
+                    shard=shard)
             self.device_costs.add_reader_batch(
                 threading.current_thread().name, n_pkts, processed,
                 time.monotonic_ns() - t0, fused=shard is not None)
@@ -1513,10 +1525,11 @@ class Server:
                         if n_msgs == 0:
                             continue
                         t0 = time.monotonic_ns()
-                        processed = self.handle_packet_batch(
-                            [], parser,
-                            drained=drain_buf[:nbytes].tobytes(),
-                            drained_pkts=n_msgs, shard=None)
+                        with annotate("ingest.batch"):
+                            processed = self.handle_packet_batch(
+                                [], parser,
+                                drained=drain_buf[:nbytes].tobytes(),
+                                drained_pkts=n_msgs, shard=None)
                         self.device_costs.add_reader_batch(
                             name, n_msgs, processed,
                             time.monotonic_ns() - t0, fused=False)
@@ -1531,14 +1544,17 @@ class Server:
                     if n_msgs == 0:
                         continue
                     self.bump("packets_received", n_msgs)
-                    with self.lock:
-                        processed, dropped, others = shard.commit()
-                        self.ledger.ingest(
-                            "dogstatsd", processed=processed,
-                            staged=processed - dropped,
-                            overflow=dropped)
-                        work = self._maybe_device_step_locked()
-                    self._apply_staged(work)
+                    # (parse_ring above waits for datagrams: the
+                    # annotation starts where the host's work does)
+                    with annotate("ingest.batch"):
+                        with self.lock:
+                            processed, dropped, others = shard.commit()
+                            self.ledger.ingest(
+                                "dogstatsd", processed=processed,
+                                staged=processed - dropped,
+                                overflow=dropped)
+                            work = self._maybe_device_step_locked()
+                        self._apply_staged(work)
                     shard.reset()  # scrub local scratch off the lock
                     # slow-path lines point into commit's source
                     # (the arena, or the replay buffer on the rare
@@ -2023,27 +2039,49 @@ class Server:
                     t_imp0 = time.monotonic_ns()
                     length = int(self.headers.get("Content-Length", 0))
                     body = self.rfile.read(length)
-                    try:
+                    with server.import_span(
+                            "http", *http_import.decode_trace_header(
+                                self.headers.get(
+                                    http_import.TRACE_HEADER))) as imp:
+                        err, acc = self._import(body, imp)
+                    # answered once the span is closed, as the gRPC
+                    # handler does: a sender that has its reply finds
+                    # the whole tree at /debug/trace/<id>
+                    if err is not None:
+                        self.send_error(400, err)
+                        return
+                    server.bump("import_response_ns",
+                                time.monotonic_ns() - t_imp0)
+                    server.bump("import_responses")
+                    self._ok(json.dumps({"accepted": acc}).encode(),
+                             "application/json")
+                else:
+                    self.send_error(404)
+
+            def _import(self, body, imp):
+                """One /import wire, step by step as the gRPC handler
+                takes it (``ImportServer._import_wire``); returns
+                (error, accepted)."""
+                hdr = self.headers.get
+                try:
+                    with imp.step("decode"):
                         items = http_import.decode_body(
-                            body,
-                            self.headers.get("Content-Encoding", ""))
-                        tid, sid = http_import.decode_trace_header(
-                            self.headers.get(http_import.TRACE_HEADER))
-                        drain = http_import.decode_drain_header(
-                            self.headers.get(http_import.DRAIN_HEADER))
-                        replay = http_import.decode_replay_header(
-                            self.headers.get(
-                                http_import.REPLAY_HEADER))
-                        recovery = http_import.decode_recovery_header(
-                            self.headers.get(
-                                http_import.RECOVERY_HEADER))
-                        handoff = http_import.decode_handoff_header(
-                            self.headers.get(
-                                http_import.HANDOFF_HEADER))
-                        deduped = False
-                        acc = dropped = 0
-                        work = None
-                        with server.lock:
+                            body, hdr("Content-Encoding", ""))
+                    drain = http_import.decode_drain_header(
+                        hdr(http_import.DRAIN_HEADER))
+                    replay = http_import.decode_replay_header(
+                        hdr(http_import.REPLAY_HEADER))
+                    recovery = http_import.decode_recovery_header(
+                        hdr(http_import.RECOVERY_HEADER))
+                    handoff = http_import.decode_handoff_header(
+                        hdr(http_import.HANDOFF_HEADER))
+                    deduped = False
+                    acc = dropped = 0
+                    work = None
+                    with imp.step("lock_wait"):
+                        server.lock.acquire()
+                    try:
+                        with imp.step("apply"):
                             if (recovery and recovery
                                     in server._recovery_seen):
                                 # retransmitted recovery wire: the
@@ -2053,22 +2091,18 @@ class Server:
                                 deduped = True
                             else:
                                 if recovery:
-                                    server._recovery_seen.add(
-                                        recovery)
+                                    server._recovery_seen.add(recovery)
                                 # split dropped into overflow vs
-                                # invalid exactly: every overflow
-                                # bump happens under this same lock,
-                                # so the tally delta across
-                                # apply_import is this request's
+                                # invalid exactly: every overflow bump
+                                # happens under this same lock, so the
+                                # tally delta across apply_import is
+                                # this request's
                                 ov0 = server.table.overflow_total()
-                                acc, dropped = \
-                                    http_import.apply_import(
-                                        server.table, items)
-                                ov = (server.table.overflow_total()
-                                      - ov0)
+                                acc, dropped = http_import.apply_import(
+                                    server.table, items)
+                                ov = server.table.overflow_total() - ov0
                                 server.ledger.ingest(
-                                    "http-import-recovery"
-                                    if recovery
+                                    "http-import-recovery" if recovery
                                     else "http-import-handoff"
                                     if handoff
                                     else "http-import-drain" if drain
@@ -2076,8 +2110,7 @@ class Server:
                                     if replay
                                     else "http-import",
                                     processed=acc + dropped,
-                                    staged=acc,
-                                    overflow=ov,
+                                    staged=acc, overflow=ov,
                                     invalid=dropped - ov)
                                 if recovery:
                                     inc = recovery.split(":", 1)[0]
@@ -2088,37 +2121,32 @@ class Server:
                                         credit_reshard_received(acc)
                                 work = \
                                     server._maybe_device_step_locked()
+                    finally:
+                        server.lock.release()
+                    with imp.step("device_step"):
                         server._apply_staged(work)
-                        if deduped:
-                            server.bump("recovery_wires_deduped")
-                        elif recovery:
-                            server.bump("recovery_wires_received")
-                            server.bump("recovery_items_received",
-                                        acc)
-                        if handoff and not deduped:
-                            server.bump("handoff_wires_received")
-                            server.bump("handoff_items_received", acc)
-                        if drain:
-                            server.bump("drain_wires_received")
-                            server.bump("drain_items_received", acc)
-                        if replay:
-                            server.bump("replay_wires_received")
-                            server.bump("replay_items_received", acc)
-                        server.note_import_span(
-                            "http", acc, dropped, tid, sid,
-                            nbytes=len(body))
-                        server.bump("imports_received", acc)
-                        server.bump("metrics_dropped", dropped)
-                        server.bump("import_response_ns",
-                                    time.monotonic_ns() - t_imp0)
-                        server.bump("import_responses")
-                        self._ok(json.dumps({"accepted": acc}).encode(),
-                                 "application/json")
-                    except (ValueError, KeyError) as e:
-                        server.bump("import_errors")
-                        self.send_error(400, str(e))
-                else:
-                    self.send_error(404)
+                    if deduped:
+                        server.bump("recovery_wires_deduped")
+                    elif recovery:
+                        server.bump("recovery_wires_received")
+                        server.bump("recovery_items_received", acc)
+                    if handoff and not deduped:
+                        server.bump("handoff_wires_received")
+                        server.bump("handoff_items_received", acc)
+                    if drain:
+                        server.bump("drain_wires_received")
+                        server.bump("drain_items_received", acc)
+                    if replay:
+                        server.bump("replay_wires_received")
+                        server.bump("replay_items_received", acc)
+                    imp.result(acc, dropped, len(body))
+                    server.bump("imports_received", acc)
+                    server.bump("metrics_dropped", dropped)
+                    return None, acc
+                except (ValueError, KeyError) as e:
+                    server.bump("import_errors")
+                    imp.span.set_error(e)
+                    return str(e), 0
 
         adopted = self._adopted_socks.pop("http", None)
         if adopted is not None:
@@ -2269,6 +2297,7 @@ class Server:
                         table_staged=pend.ingested,
                         table_overflow=pend.overflow,
                         kernel_drops=kdrops)
+                    self._take_imports(cyc.record)
             with cyc.stage("swap_apply"):
                 snap = self.table.complete_swap(pend)
         else:
@@ -2285,6 +2314,7 @@ class Server:
                         table_staged=snap.ingested,
                         table_overflow=snap.overflow,
                         kernel_drops=kdrops)
+                    self._take_imports(cyc.record)
         # dispatch / device_wait / host_emit stages happen inside the
         # flusher, against the same cycle; retain_frame keeps the
         # columnar MetricFrame alive for frame-aware sinks instead of
@@ -2555,8 +2585,7 @@ class Server:
             self.bump("flush_errors")
             log.exception("sink flush failed")
 
-    def _forward(self, rows, trace_ctx=None, led=None, cyc=None,
-                 span=None):
+    def _forward(self, rows, trace_ctx, led, cyc, span):
         """Ship mergeable state upstream over gRPC or HTTP (reference
         flusher.go:82-99: forwardGRPC when configured, else
         flushForward; errors dropped-and-counted, never retried).
@@ -2577,13 +2606,13 @@ class Server:
                 if fwd is not None:
                     return self._forward_sharded(
                         fwd, rows, trace_ctx, led, cyc, span)
-                self._forward_grpc(rows, trace_ctx, led)
+                self._forward_grpc(rows, trace_ctx, led, cyc, span)
                 return None
             if getattr(self.config, "tpu_sharded_global", False):
                 # the split rides MetricList wires; HTTP JSON has no
                 # record-span router — fail open to the legacy POST
                 self.bump("sharded_forward_fallbacks")
-            self._forward_http(rows, trace_ctx, led)
+            self._forward_http(rows, trace_ctx, led, cyc)
         except Exception as e:
             # encoding bugs / missing grpcio / anything: forwarding
             # must never abort the flush pipeline
@@ -2943,6 +2972,8 @@ class Server:
             def _result(dest, n_items, err, retries, ch=ch,
                         nbytes=len(body), landed=landed):
                 if err is None:
+                    if cyc is not None:
+                        cyc.add_forward_bytes(nbytes)
                     if led is not None:
                         self.ledger.credit_forward_wire(
                             led, rows=n_items, nbytes=nbytes)
@@ -3027,7 +3058,7 @@ class Server:
             split[dest] = split.get(dest, 0) + n
         return split
 
-    def _forward_http(self, rows, trace_ctx=None, led=None) -> None:
+    def _forward_http(self, rows, trace_ctx, led, cyc) -> None:
         if self.config.forward_json_schema == "reference":
             body, headers = http_import.encode_rows_reference(
                 rows, compression=float(self.config.tpu_compression))
@@ -3060,22 +3091,33 @@ class Server:
             if self._draining:
                 self.bump("drain_wires_sent")
                 self.bump("drain_items_sent", len(rows))
+            cyc.add_forward_bytes(len(body))
             if led is not None:
                 self.ledger.credit_forward_wire(
                     led, rows=len(rows), nbytes=len(body))
 
-    def _forward_grpc(self, rows, trace_ctx=None, led=None) -> None:
-        from veneur_tpu.forward.grpc_forward import ForwardClient
+    def _forward_grpc(self, rows, trace_ctx, led, cyc, span) -> None:
+        """The single-global wire, in two timed halves under the
+        cycle's ``forward`` stage: ``forward.encode`` (rows -> bytes)
+        and ``forward.send`` (the unary call, call to return), whose
+        span's ids ride the wire."""
+        from veneur_tpu.forward.grpc_forward import (ForwardClient,
+                                                     wire_metadata)
         import grpc as _grpc
         if self._grpc_client is None:
             self._grpc_client = ForwardClient(
                 self.config.forward_address,
                 compression=float(self.config.tpu_compression),
                 credentials=self._forward_grpc_credentials())
+        with cyc.stage("forward.encode", parent=span) as sp:
+            body = self._grpc_client.encode(rows)
+            sp.add_tag("rows", str(len(rows)))
+            sp.add_tag("bytes", str(len(body)))
         try:
-            nbytes = self._grpc_client.send(
-                rows, trace_context=trace_ctx,
-                drain=self._draining)
+            with cyc.stage("forward.send", parent=span) as sp:
+                sp.add_tag("bytes", str(len(body)))
+                self._grpc_client.send_wire(body, metadata=wire_metadata(
+                    trace_ctx and cyc.wire_context(sp), self._draining))
         except _grpc.RpcError as e:
             self.bump("metrics_dropped", len(rows))
             self.bump("forward_errors")
@@ -3086,10 +3128,10 @@ class Server:
             if self._draining:
                 self.bump("drain_wires_sent")
                 self.bump("drain_items_sent", len(rows))
+            cyc.add_forward_bytes(len(body))
             if led is not None:
                 self.ledger.credit_forward_wire(
-                    led, rows=len(rows),
-                    nbytes=int(nbytes) if nbytes else 0)
+                    led, rows=len(rows), nbytes=len(body))
 
     # ------------------------------------------------------------------
 

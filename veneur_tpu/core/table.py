@@ -1462,7 +1462,7 @@ class MetricTable:
         w = self._detach_staged(final)
         if w.empty:
             return
-        with self._device_lock:
+        with self._device_lock, observe.annotate("apply.staged"):
             self._apply_work(w)
 
     def take_staged(self, final: bool = False) -> _StagedWork | None:
@@ -1489,7 +1489,7 @@ class MetricTable:
         perturbs centroid placement, set register max) — and the
         pinned state guarantees the right interval."""
         try:
-            with self._device_lock:
+            with self._device_lock, observe.annotate("apply.staged"):
                 self._apply_work(w)
         finally:
             with self._pending_cv:
@@ -3062,7 +3062,7 @@ class MetricTable:
             while pend.state.pending:
                 self._pending_cv.wait()
         if not pend.work.empty:
-            with self._device_lock:
+            with self._device_lock, observe.annotate("apply.staged"):
                 self._apply_work(pend.work)
         st = pend.state
         snap_tiers = None

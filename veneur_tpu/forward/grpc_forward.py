@@ -132,6 +132,19 @@ def decode_trace_metadata(metadata) -> tuple[int, int]:
         return 0, 0
     return tid, sid
 
+
+def wire_metadata(trace_context: tuple[int, int] | None,
+                  drain: bool = False):
+    """Invocation metadata of a forward wire: the sending span's ids
+    when both are set, and the drain flag."""
+    md = []
+    if trace_context and trace_context[0] and trace_context[1]:
+        md = [(TRACE_ID_KEY, str(trace_context[0])),
+              (SPAN_ID_KEY, str(trace_context[1]))]
+    if drain:
+        md.append((DRAIN_KEY, "1"))
+    return md or None
+
 _TYPE_TO_PB = {dsd.COUNTER: metric_pb2.Counter,
                dsd.GAUGE: metric_pb2.Gauge,
                dsd.HISTOGRAM: metric_pb2.Histogram,
@@ -820,7 +833,12 @@ class ImportServer:
     def _send_metrics(self, request, context):
         core = self._core
         md = context.invocation_metadata()
-        tid, sid = decode_trace_metadata(md)
+        with core.import_span("grpc", *decode_trace_metadata(md)) as imp:
+            self._import_wire(request, md, imp)
+        return empty_pb2.Empty()
+
+    def _import_wire(self, request, md, imp) -> None:
+        core = self._core
         drain = decode_drain_metadata(md)
         replay = decode_replay_metadata(md)
         recovery_id = decode_recovery_metadata(md)
@@ -830,47 +848,57 @@ class ImportServer:
         # interval fold holds it (or _apply_staged runs the device
         # merge), this thread's wire decode proceeds in parallel —
         # cycle N+1 decode overlaps cycle N fold
-        cols = decode_metric_list(request)
-        with core.lock:
-            # crash-recovery dedup, atomic with the apply: a segment
-            # replayed twice (restart raced, or the replayer retried a
-            # timed-out send that actually landed) is counted ONCE
-            if recovery_id is not None and recovery_id:
-                seen = getattr(core, "_recovery_seen", None)
-                if seen is not None:
-                    if recovery_id in seen:
-                        core.stats["recovery_wires_deduped"] = (
-                            core.stats.get("recovery_wires_deduped", 0)
-                            + 1)
-                        return empty_pb2.Empty()
-                    seen.add(recovery_id)
-            ov0 = core.table.overflow_total() if ledger else 0
-            if cols is None:
-                acc, dropped = apply_metric_list(
-                    core.table,
-                    forward_pb2.MetricList.FromString(request))
-            else:
-                acc, dropped = apply_decoded(core.table, request, cols)
-            if ledger is not None:
-                # the overflow delta splits this wire's drops into
-                # overflow (the table counted them) vs invalid
-                # (malformed/non-finite, dropped before the table)
-                ov = core.table.overflow_total() - ov0
-                proto = ("grpc-import-recovery" if recovery_id
-                         else "grpc-import-handoff" if handoff
-                         else "grpc-import-drain" if drain
-                         else "grpc-import-replay" if replay
-                         else "grpc-import")
-                ledger.ingest(proto, processed=acc + dropped,
-                              staged=acc, overflow=ov,
-                              invalid=dropped - ov)
-                if recovery_id:
-                    inc = recovery_id.split(":", 1)[0]
-                    ledger.recover(f"incarnation:{inc}", acc)
-                if handoff:
-                    ledger.credit_reshard_received(acc)
-            work = core._maybe_device_step_locked()
-        core._apply_staged(work)
+        with imp.step("decode"):
+            cols = decode_metric_list(request)
+            pb = (forward_pb2.MetricList.FromString(request)
+                  if cols is None else None)
+        with imp.step("lock_wait"):
+            core.lock.acquire()
+        try:
+            with imp.step("apply"):
+                # crash-recovery dedup, atomic with the apply: a
+                # segment replayed twice (restart raced, or the
+                # replayer retried a timed-out send that actually
+                # landed) is counted ONCE
+                if recovery_id is not None and recovery_id:
+                    seen = getattr(core, "_recovery_seen", None)
+                    if seen is not None:
+                        if recovery_id in seen:
+                            core.stats["recovery_wires_deduped"] = (
+                                core.stats.get(
+                                    "recovery_wires_deduped", 0) + 1)
+                            return
+                        seen.add(recovery_id)
+                ov0 = core.table.overflow_total() if ledger else 0
+                if cols is None:
+                    acc, dropped = apply_metric_list(core.table, pb)
+                else:
+                    acc, dropped = apply_decoded(core.table, request,
+                                                 cols)
+                if ledger is not None:
+                    # the overflow delta splits this wire's drops into
+                    # overflow (the table counted them) vs invalid
+                    # (malformed/non-finite, dropped before the table)
+                    ov = core.table.overflow_total() - ov0
+                    proto = ("grpc-import-recovery" if recovery_id
+                             else "grpc-import-handoff" if handoff
+                             else "grpc-import-drain" if drain
+                             else "grpc-import-replay" if replay
+                             else "grpc-import")
+                    ledger.ingest(proto, processed=acc + dropped,
+                                  staged=acc, overflow=ov,
+                                  invalid=dropped - ov)
+                    if recovery_id:
+                        inc = recovery_id.split(":", 1)[0]
+                        ledger.recover(f"incarnation:{inc}", acc)
+                    if handoff:
+                        ledger.credit_reshard_received(acc)
+                work = core._maybe_device_step_locked()
+        finally:
+            core.lock.release()
+        with imp.step("device_step"):
+            core._apply_staged(work)
+        imp.result(acc, dropped, len(request))
         core.bump("imports_received", acc)
         core.bump("received_grpc", acc + dropped)
         if drain:
@@ -898,11 +926,6 @@ class ImportServer:
             core.bump("handoff_items_received", acc)
         if dropped:
             core.bump("metrics_dropped", dropped)
-        note = getattr(core, "note_import_span", None)
-        if note is not None and tid:
-            note("grpc", acc, dropped, tid, sid,
-                 nbytes=len(request))
-        return empty_pb2.Empty()
 
     def _send_span(self, request, context):
         """ssf.SSFGRPC/SendSpan (reference networking.go:321
@@ -954,18 +977,19 @@ class ForwardClient:
             self._channel = grpc.insecure_channel(target)
         self._timeout = timeout
         self._compression = compression
-        self._call = self._channel.unary_unary(
-            _METHOD,
-            request_serializer=forward_pb2.MetricList.SerializeToString,
-            response_deserializer=empty_pb2.Empty.FromString)
-        # raw-bytes twin of _call: the columnar proxy re-encodes a
-        # destination's slice as wire bytes (concatenated record
-        # spans), so serializing through MetricList here would undo
-        # the whole zero-materialization route path
+        # raw bytes: every sender serializes first (the single-global
+        # forward so that encoding and the call are timed apart, the
+        # columnar proxy because it re-encodes a destination's slice
+        # as concatenated record spans)
         self._call_raw = self._channel.unary_unary(
             _METHOD,
             request_serializer=lambda b: b,
             response_deserializer=empty_pb2.Empty.FromString)
+
+    def encode(self, rows: list[ForwardRow]) -> bytes:
+        """The wire body of ``rows``: a serialized MetricList."""
+        return rows_to_metric_list(
+            rows, self._compression).SerializeToString()
 
     def send_wire(self, body: bytes, timeout: float | None = None,
                   metadata=None) -> None:
@@ -976,19 +1000,16 @@ class ForwardClient:
 
     def send(self, rows: list[ForwardRow],
              trace_context: tuple[int, int] | None = None,
-             drain: bool = False) -> None:
-        """Raises grpc.RpcError on failure (caller drops-and-counts).
-        ``trace_context`` = (trace_id, span_id) of the sending flush
-        cycle, stamped as invocation metadata when set; ``drain``
-        flags the wire as a shutdown handoff."""
-        metadata = []
-        if trace_context and trace_context[0] and trace_context[1]:
-            metadata = [(TRACE_ID_KEY, str(trace_context[0])),
-                        (SPAN_ID_KEY, str(trace_context[1]))]
-        if drain:
-            metadata.append((DRAIN_KEY, "1"))
-        self._call(rows_to_metric_list(rows, self._compression),
-                   timeout=self._timeout, metadata=metadata or None)
+             drain: bool = False) -> int:
+        """Encode, then ship; returns the body's length in bytes.
+        Raises grpc.RpcError on failure (caller drops-and-counts).
+        ``trace_context`` = (trace_id, span_id) of the sending span,
+        stamped as invocation metadata when set; ``drain`` flags the
+        wire as a shutdown handoff."""
+        body = self.encode(rows)
+        self.send_wire(body,
+                       metadata=wire_metadata(trace_context, drain))
+        return len(body)
 
     def close(self) -> None:
         self._channel.close()

@@ -14,9 +14,11 @@ The reference veneur traces its own flushes (flusher.go:29
 ``flushring``  — per-flush-cycle records (stage durations, readback
     bytes, tallies) in a bounded ring, served at ``/debug/flushes``.
 ``tracer``     — the flush cycle's nested SSF span tree (snapshot ->
-    device dispatch -> readback sync -> host emit -> sink flush ->
-    forward), emitted through the server's own loopback trace client
-    so flush spans flow to span sinks like any user trace.
+    dispatch -> device wait -> host emit -> sink flush -> forward ->
+    the receiving tier's import), emitted through the server's own
+    loopback trace client so flush spans flow to span sinks like any
+    user trace, each span also a ``jax.profiler.TraceAnnotation`` on
+    the device trace's clock.
 ``profiler``   — on-demand ``jax.profiler`` captures for
     ``/debug/pprof/device?seconds=N``.
 ``ledger``     — per-interval sample-conservation ledger: every hot
@@ -43,7 +45,8 @@ from veneur_tpu.observe.ledger import (ClassDropTally, Ledger,
                                        LedgerRecord, SpoolLedger,
                                        SpoolLedgerRecord)
 from veneur_tpu.observe.tracer import (FlushCycle, FlushTracer,
-                                       NULL_CYCLE, NullCycle)
+                                       ImportSpan, NULL_CYCLE,
+                                       NullCycle, annotate)
 from veneur_tpu.observe.traceindex import TraceIndex, span_to_dict
 from veneur_tpu.observe.profiler import capture_device_profile
 from veneur_tpu.observe.recorder import (FlightRecorder, read_bundle,
@@ -52,7 +55,8 @@ from veneur_tpu.observe.signals import SignalHistory
 
 __all__ = ["DeviceCostRegistry", "REGISTRY", "instrument",
            "FlushRecord", "FlushRing", "FlushCycle", "FlushTracer",
-           "NullCycle", "NULL_CYCLE", "capture_device_profile",
+           "NullCycle", "NULL_CYCLE", "ImportSpan", "annotate",
+           "capture_device_profile",
            "ClassDropTally", "Ledger", "LedgerRecord",
            "SpoolLedger", "SpoolLedgerRecord",
            "TraceIndex", "span_to_dict",

@@ -27,6 +27,10 @@ class FlushRecord:
     readback_bytes: int = 0
     metrics_emitted: int = 0
     forward_rows: int = 0
+    forward_bytes: int = 0  # serialized forward bodies shipped
+    # import wires this server folded since its previous cycle; their
+    # handler durations are in ``stages`` under ``import[.<step>]``
+    imports: int = 0
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
     error: str = ""
@@ -41,6 +45,8 @@ class FlushRecord:
                 "readback_bytes": self.readback_bytes,
                 "metrics_emitted": self.metrics_emitted,
                 "forward_rows": self.forward_rows,
+                "forward_bytes": self.forward_bytes,
+                "imports": self.imports,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
                 "error": self.error,

@@ -1,9 +1,12 @@
-"""Flush self-tracing: one nested SSF span tree per flush cycle.
+"""Flush self-tracing: one nested SSF span tree per flush cycle, the
+import handlers' span tree on the receiving tier, and every span's
+place on the device trace's clock.
 
 The reference wraps its flush in ``trace.StartSpanFromContext``
 (flusher.go:29) and child spans per phase; here ``FlushTracer.cycle``
 opens the root ``flush`` span and ``FlushCycle.stage`` hangs one
-child per pipeline stage off it:
+child per pipeline stage off it.  A dotted stage is the child of the
+stage before the dot:
 
     flush
       +- flush.snapshot     staging detach + metadata capture under the
@@ -14,17 +17,37 @@ child per pipeline stage off it:
       +- flush.device_wait  device_get — the d2h sync point
       +- flush.host_emit    InterMetric assembly from row metadata
       +- flush.sink_flush   per-sink fan-out + interval-budget wait
+      +- flush.sink.<name>  one sink's encode + delivery (its worker)
       +- flush.forward      upstream ship (local tier only)
-
-``dispatch`` / ``device_wait`` replaced the old ``device_dispatch`` /
-``readback_sync`` names when dispatch and readback stopped running
-back-to-back; stage timings are recorded under BOTH the new and old
-names (``stage(..., alias=...)``) so dashboards keyed on the old
-``veneur.flush.stage_duration_ns`` series keep working.
+           +- flush.forward.encode  rows -> MetricList bytes
+           +- flush.forward.send    the unary call, call to return;
+           |    |                   its ids ride the wire
+           |    +- import             the receiving tier's handler,
+           |         |                entry to exit (``ImportSpan``)
+           |         +- import.decode       wire -> columns, no lock
+           |         +- import.lock_wait    asking for the ingest
+           |         |                      lock to having it
+           |         +- import.apply        fold + dedup + ledger
+           |         |                      credit, under the lock
+           |         +- import.device_step  the staged apply, if the
+           |                                wire crossed the threshold
+           +- flush.forward.shard   sharded path: one per destination
 
 Spans go through the server's own loopback trace client, so they flow
 to span sinks (and ssfmetrics extraction) like any user trace.  Each
-cycle also fills a ``FlushRecord`` for the ``/debug/flushes`` ring.
+cycle also fills a ``FlushRecord`` for the ``/debug/flushes`` ring;
+the durations of the imports a server handled since its last flush
+are folded into the next cycle's record under their own names.
+
+Every span here is opened through ``_traced`` (or ``_open`` /
+``_close`` where it outlives a block), which enters a
+``jax.profiler.TraceAnnotation`` of the span's own name around the
+same work.  With no profiler session that is a sub-microsecond no-op;
+under one (``/debug/pprof/device``, ``enable_profiling``, a
+benchmark's ``--trace 1``) each span is also an event on the host
+lines of the same ``.xplane.pb`` as the device's ``XLA Ops``, on the
+profiler's clock.  ``annotate`` is the same for the two per-batch
+sites that have no SSF span (``ingest.batch``, ``apply.staged``).
 
 ``NULL_CYCLE`` is the no-tracer stand-in for direct ``Flusher.flush``
 callers (tests, benches): stages are free, but readback accounting
@@ -34,11 +57,15 @@ still reaches the device-cost registry.
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 import time
 
+from jax.profiler import TraceAnnotation as annotate
+
 from veneur_tpu.observe.devicecost import REGISTRY
 from veneur_tpu.observe.flushring import FlushRecord, FlushRing
+from veneur_tpu.trace.spans import Span
 
 
 class _NullSpan:
@@ -51,8 +78,39 @@ class _NullSpan:
     def set_error(self, err=True):
         pass
 
+    def child(self, name, **kw):
+        return self
+
     def finish(self, client=None):
         return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def _finish(span, client, index) -> None:
+    if span.finish(client) is not None and index is not None:
+        index.add(span.proto)
+
+
+@contextlib.contextmanager
+def _traced(span, name: str, client, index, note):
+    """The one way a block is timed here: as the SSF span ``span`` and
+    as a profiler annotation ``name`` around the same work, so a stage
+    cannot have one without the other; ``note(ns)`` takes the block's
+    monotonic duration."""
+    ann = annotate(name)
+    ann.__enter__()
+    t0 = time.monotonic_ns()
+    try:
+        yield span
+    except BaseException as e:
+        span.set_error(e)
+        raise
+    finally:
+        note(time.monotonic_ns() - t0)
+        ann.__exit__(None, None, None)
+        _finish(span, client, index)
 
 
 class NullCycle:
@@ -61,11 +119,11 @@ class NullCycle:
     record = None
 
     @contextlib.contextmanager
-    def stage(self, name: str, alias: str | None = None):
-        yield _NullSpan()
+    def stage(self, name: str, parent=None):
+        yield _NULL_SPAN
 
     def child(self, parent, name: str, tags=None):
-        return _NullSpan()
+        return _NULL_SPAN
 
     def finish(self, span) -> None:
         pass
@@ -94,37 +152,27 @@ class FlushCycle:
         """(trace_id, span_id) to stamp onto a forward wire so the
         receiving tier can parent its import span under ours.  Pass
         the stage span actually doing the shipping (e.g. the
-        ``forward`` child) to parent under it instead of the root."""
+        ``forward.send`` child) to parent under it instead of the
+        root."""
         sp = span if span is not None else self.root
         return sp.trace_id, sp.span_id
 
-    @contextlib.contextmanager
-    def stage(self, name: str, alias: str | None = None):
-        """Time one pipeline stage as a child span of the flush root.
-        Safe to enter from pool threads (the forward stage runs on
-        one); re-entering a stage name accumulates its ns.  ``alias``
-        records the same ns under a legacy stage name too, so renamed
-        stages don't break dashboards keyed on the old series."""
-        sp = self.root.child(f"flush.{name}")
+    def _add_stage(self, name: str, ns: int) -> None:
+        with self._lock:
+            self.record.stages[name] = (
+                self.record.stages.get(name, 0) + ns)
+
+    def stage(self, name: str, parent=None):
+        """Time one pipeline stage as a child span of the flush root,
+        or of ``parent`` for a dotted sub-stage (``forward.encode``
+        under the ``forward`` stage's span).  Safe to enter from pool
+        threads (the forward stage runs on one); re-entering a stage
+        name accumulates its ns."""
+        sp = (parent or self.root).child(f"flush.{name}")
         sp.add_tag("stage", name)
         sp.add_tag("veneur.internal", "true")
-        t0 = time.monotonic_ns()
-        try:
-            yield sp
-        except BaseException as e:
-            sp.set_error(e)
-            raise
-        finally:
-            dt = time.monotonic_ns() - t0
-            with self._lock:
-                self.record.stages[name] = (
-                    self.record.stages.get(name, 0) + dt)
-                if alias is not None:
-                    self.record.stages[alias] = (
-                        self.record.stages.get(alias, 0) + dt)
-            sp.finish(self._client)
-            if self._index is not None:
-                self._index.add(sp.proto)
+        return _traced(sp, f"flush.{name}", self._client, self._index,
+                       functools.partial(self._add_stage, name))
 
     def child(self, parent, name: str, tags=None):
         """A live child span under ``parent`` (a stage span), for
@@ -137,19 +185,80 @@ class FlushCycle:
         sp.add_tag("veneur.internal", "true")
         for k, v in (tags or {}).items():
             sp.add_tag(k, v)
+        sp.annotation = annotate(f"flush.{name}")
+        sp.annotation.__enter__()
         return sp
 
     def finish(self, span) -> None:
         """Record a :meth:`child` span to the trace client + debug
         index (mirrors the tail of :meth:`stage`)."""
-        span.finish(self._client)
-        if self._index is not None:
-            self._index.add(span.proto)
+        span.annotation.__exit__(None, None, None)
+        _finish(span, self._client, self._index)
 
     def add_readback(self, nbytes: int) -> None:
         self._registry.add_readback(nbytes)
         with self._lock:
             self.record.readback_bytes += int(nbytes)
+
+    def add_forward_bytes(self, nbytes: int) -> None:
+        with self._lock:
+            self.record.forward_bytes += int(nbytes)
+
+
+class ImportSpan:
+    """The receiving tier's half of a forward: an ``import`` span from
+    the handler's entry to its exit, one ``import.<step>`` child per
+    step, and their durations handed to ``note`` at the exit (the
+    server folds them into its next flush record).
+
+    The sending tier stamped its ``forward.send`` span's (trace_id,
+    span_id) onto the wire (X-Veneur-Trace header / veneur-trace-*
+    gRPC metadata), so the tree recorded here parents under it and
+    the whole interval stitches into one tree at
+    ``/debug/trace/<trace_id>`` on either end.  A wire without that
+    context (an old peer, propagation gated off) is timed and
+    annotated all the same, and leaves no SSF span."""
+
+    def __init__(self, client, index, note, protocol: str,
+                 trace_id: int, span_id: int):
+        self._client = client
+        self._index = index
+        self._note = note
+        self.stages: dict[str, int] = {}
+        self.span = _NULL_SPAN
+        if trace_id:
+            self.span = Span(
+                "import", service="veneur", trace_id=trace_id,
+                parent_id=span_id,
+                tags={"protocol": protocol,
+                      "veneur.internal": "true"})
+
+    def _timed(self, span, name: str):
+        return _traced(span, name, self._client, self._index,
+                       functools.partial(self.stages.__setitem__, name))
+
+    def __enter__(self):
+        self._whole = self._timed(self.span, "import")
+        self._whole.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            return self._whole.__exit__(*exc)
+        finally:
+            self._note(self.stages)
+
+    def step(self, name: str):
+        """Time ``import.<name>`` as a child of the import span."""
+        name = f"import.{name}"
+        sp = self.span.child(name)
+        sp.add_tag("veneur.internal", "true")
+        return self._timed(sp, name)
+
+    def result(self, accepted: int, dropped: int, nbytes: int) -> None:
+        self.span.add_tag("accepted", str(accepted))
+        self.span.add_tag("dropped", str(dropped))
+        self.span.add_tag("bytes", str(nbytes))
 
 
 class FlushTracer:
@@ -163,7 +272,6 @@ class FlushTracer:
 
     @contextlib.contextmanager
     def cycle(self):
-        from veneur_tpu.trace.spans import Span
         record = FlushRecord(seq=self.ring.next_seq(),
                              start_unix=time.time())
         # the internal marker exempts these spans from the user-span
@@ -175,19 +283,19 @@ class FlushTracer:
         cyc = FlushCycle(root, self.client, record, self.registry,
                          index=self.index)
         compiles0 = self.registry.totals()["compile_total"]
-        t0 = time.monotonic_ns()
         try:
-            yield cyc
-        except BaseException as e:
-            root.set_error(e)
-            record.error = f"{type(e).__name__}: {e}"
-            raise
+            with _traced(root, "flush", self.client, self.index,
+                         functools.partial(setattr, record,
+                                           "duration_ns")):
+                try:
+                    yield cyc
+                except BaseException as e:
+                    record.error = f"{type(e).__name__}: {e}"
+                    raise
+                finally:
+                    record.compiles = (
+                        self.registry.totals()["compile_total"]
+                        - compiles0)
+                    root.add_tag("flush.seq", str(record.seq))
         finally:
-            record.duration_ns = time.monotonic_ns() - t0
-            record.compiles = (self.registry.totals()["compile_total"]
-                               - compiles0)
-            root.add_tag("flush.seq", str(record.seq))
-            root.finish(self.client)
-            if self.index is not None:
-                self.index.add(root.proto)
             self.ring.append(record)

@@ -274,6 +274,9 @@ def _build(cap: int, batch_width: int, num_rows: int, delta: float,
         out_shape=[jax.ShapeDtypeStruct((num_rows, n), jnp.float32),
                    jax.ShapeDtypeStruct((num_rows, n), jnp.float32)],
         interpret=interpret,
+        # what a device trace calls this kernel: state capacity and
+        # incoming width are its static shape (rows are the grid)
+        name=f"tdigest_merge_c{cap}_k{batch_width}",
     )
 
     def merge(m_all: Array, w_all: Array) -> tuple[Array, Array]:

@@ -181,7 +181,9 @@ def _fused(spec: SBSpec, counters, gauges, means, weights, stats,
            regs, buf):
     """The one fused step.  All offsets are static; f32/u8 segments
     are bitcast views of the int32 buffer.  Absent classes pass
-    their planes through untouched (the caller skips reassignment)."""
+    their planes through untouched (the caller skips reassignment).
+    Each class's operations sit in a ``sb.<class>`` scope, which a
+    device trace shows in every operation's ``op_name``."""
     off = layout(spec)
 
     def seg(name: str, n: int):
@@ -192,59 +194,64 @@ def _fused(spec: SBSpec, counters, gauges, means, weights, stats,
         return lax.bitcast_convert_type(seg(name, n), jnp.float32)
 
     if spec.counter_rows:
-        counters = segment.counter_dense_update(
-            counters, f32("counter", spec.counter_rows))
+        with jax.named_scope("sb.counter"):
+            counters = segment.counter_dense_update(
+                counters, f32("counter", spec.counter_rows))
     if spec.gauge_rows:
-        gauges = segment.gauge_dense_update(
-            gauges, f32("gauge_dense", spec.gauge_rows),
-            seg("gauge_mask", spec.gauge_rows).astype(bool))
+        with jax.named_scope("sb.gauge"):
+            gauges = segment.gauge_dense_update(
+                gauges, f32("gauge_dense", spec.gauge_rows),
+                seg("gauge_mask", spec.gauge_rows).astype(bool))
     if spec.histo_n:
-        rows = seg("histo_rows", spec.histo_n)
-        rank = seg("histo_rank", spec.histo_n)
-        vals = f32("histo_vals", spec.histo_n)
-        sub = spec.histo_sub > 0
-        pre = (seg("histo_idx", spec.histo_sub),) if sub else ()
-        kw = dict(slots=spec.histo_slots,
-                  compression=spec.compression)
-        if spec.histo_stats:
-            if spec.histo_unit:
-                fn = (tdigest.ingest_ranked_unit_rows if sub
-                      else tdigest.ingest_ranked_unit)
-                means, weights, stats = fn(
-                    means, weights, stats, *pre, rows, rank, vals,
-                    **kw)
+        with jax.named_scope("sb.histo"):
+            rows = seg("histo_rows", spec.histo_n)
+            rank = seg("histo_rank", spec.histo_n)
+            vals = f32("histo_vals", spec.histo_n)
+            sub = spec.histo_sub > 0
+            pre = (seg("histo_idx", spec.histo_sub),) if sub else ()
+            kw = dict(slots=spec.histo_slots,
+                      compression=spec.compression)
+            if spec.histo_stats:
+                if spec.histo_unit:
+                    fn = (tdigest.ingest_ranked_unit_rows if sub
+                          else tdigest.ingest_ranked_unit)
+                    means, weights, stats = fn(
+                        means, weights, stats, *pre, rows, rank, vals,
+                        **kw)
+                else:
+                    fn = (tdigest.ingest_ranked_rows if sub
+                          else tdigest.ingest_ranked)
+                    means, weights, stats = fn(
+                        means, weights, stats, *pre, rows, rank, vals,
+                        f32("histo_wts", spec.histo_n), **kw)
+            elif spec.histo_unit:
+                fn = (tdigest.add_samples_ranked_unit_rows if sub
+                      else tdigest.add_samples_ranked_unit)
+                means, weights = fn(means, weights, *pre, rows, rank,
+                                    vals, **kw)
             else:
-                fn = (tdigest.ingest_ranked_rows if sub
-                      else tdigest.ingest_ranked)
-                means, weights, stats = fn(
-                    means, weights, stats, *pre, rows, rank, vals,
-                    f32("histo_wts", spec.histo_n), **kw)
-        elif spec.histo_unit:
-            fn = (tdigest.add_samples_ranked_unit_rows if sub
-                  else tdigest.add_samples_ranked_unit)
-            means, weights = fn(means, weights, *pre, rows, rank,
-                                vals, **kw)
-        else:
-            fn = (tdigest.add_samples_ranked_rows if sub
-                  else tdigest.add_samples_ranked)
-            means, weights = fn(means, weights, *pre, rows, rank,
-                                vals, f32("histo_wts", spec.histo_n),
-                                **kw)
+                fn = (tdigest.add_samples_ranked_rows if sub
+                      else tdigest.add_samples_ranked)
+                means, weights = fn(means, weights, *pre, rows, rank,
+                                    vals, f32("histo_wts", spec.histo_n),
+                                    **kw)
     if spec.pos_n:
-        regs = hll.insert_packed(regs,
-                                 seg("pos_rows", spec.pos_n),
-                                 seg("pos_pk", spec.pos_n))
+        with jax.named_scope("sb.set"):
+            regs = hll.insert_packed(regs,
+                                     seg("pos_rows", spec.pos_n),
+                                     seg("pos_pk", spec.pos_n))
     if spec.plane_rows:
-        words = spec.plane_rows * (hll.M // 4)
-        plane = lax.bitcast_convert_type(
-            seg("plane_regs", words),
-            jnp.uint8).reshape(spec.plane_rows, hll.M)
-        if spec.plane_full:
-            regs = hll.union(regs, plane)
-        else:
-            regs = hll.merge_rows(regs,
-                                  seg("plane_idx", spec.plane_rows),
-                                  plane)
+        with jax.named_scope("sb.set"):
+            words = spec.plane_rows * (hll.M // 4)
+            plane = lax.bitcast_convert_type(
+                seg("plane_regs", words),
+                jnp.uint8).reshape(spec.plane_rows, hll.M)
+            if spec.plane_full:
+                regs = hll.union(regs, plane)
+            else:
+                regs = hll.merge_rows(regs,
+                                      seg("plane_idx", spec.plane_rows),
+                                      plane)
     return counters, gauges, means, weights, stats, regs
 
 
