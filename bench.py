@@ -663,7 +663,7 @@ def bench_global_merge() -> dict:
     one forwarded digest or sketch."""
     from veneur_tpu.core.table import MetricTable, TableConfig
     from veneur_tpu.forward.grpc_forward import (
-        apply_metric_list_bytes, rows_to_metric_list)
+        apply_metric_list_bytes, encode_metric_list)
     from veneur_tpu.ops import hll as hll_ops, tdigest
     from veneur_tpu.protocol import dogstatsd as dsd
     import jax
@@ -700,7 +700,7 @@ def bench_global_merge() -> dict:
     # every local forwards the same series — the worst-case (full row
     # contention) and the realistic one: a fleet forwards the same
     # metric names
-    wire = rows_to_metric_list(res.forward).SerializeToString()
+    wire = encode_metric_list(res.forward)[0]
     wire_lists = [wire] * n_locals
 
     qs_dev = jnp.asarray(np.asarray([0.5, 0.9, 0.99], np.float32))
@@ -2751,7 +2751,7 @@ def cluster_bench() -> dict:
 
 # Worker for --collective-forward: one process of the N-local x
 # M-global gloo mesh.  Locals run the gRPC-wire oracle phase
-# (rows_to_metric_list -> real loopback gRPC -> global's ImportServer)
+# (encode_metric_list -> real loopback gRPC -> global's ImportServer)
 # then the collective phase (pack_block -> ONE all_to_all -> global's
 # apply_collective_blocks); phases are bracketed by empty-rendezvous
 # barriers so each phase's wall clock covers delivery-to-staged on
@@ -2839,7 +2839,7 @@ t_perf = time.perf_counter
 
 if pid < n_locals:
     from veneur_tpu.forward.grpc_forward import (ForwardClient,
-                                                 rows_to_metric_list)
+                                                 encode_metric_list)
     groups = {d: dest_rows(pid, j) for j, d in enumerate(peers)}
     tr = CollectiveTransport(schema, peers=peers, deadline=300.0)
     clients = {d: ForwardClient(d, timeout=60.0, compression=COMP)
@@ -2850,8 +2850,7 @@ if pid < n_locals:
     for _ in range(cycles):
         for d, rows in groups.items():
             s0 = t_perf()
-            body = rows_to_metric_list(
-                rows, COMP).SerializeToString()
+            body = encode_metric_list(rows, COMP)[0]
             ser_s += t_perf() - s0
             clients[d].send_wire(body)
     tr.exchange_empty(None)

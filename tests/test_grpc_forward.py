@@ -14,7 +14,11 @@ from veneur_tpu.core.flusher import ForwardRow
 from veneur_tpu.core.table import MetricTable, RowMeta, TableConfig
 from veneur_tpu.forward import hll_codec
 from veneur_tpu.forward.gen import forward_pb2, metric_pb2
-from veneur_tpu.forward.grpc_forward import (apply_metric_list,
+from veneur_tpu.forward.grpc_forward import (_PB_TO_SCOPE, _PB_TO_TYPE,
+                                             _SCOPE_TO_PB, _TYPE_TO_PB,
+                                             apply_metric_list,
+                                             decode_metric_list,
+                                             encode_metric_list,
                                              row_to_metric,
                                              rows_to_metric_list)
 from veneur_tpu.ops import hll, segment, tdigest
@@ -194,6 +198,265 @@ def test_malformed_items_dropped_per_item():
     acc, dropped = apply_metric_list(
         table, forward_pb2.MetricList(metrics=[m_bad, m_good]))
     assert (acc, dropped) == (1, 1)
+
+
+# ----------------------------------------------------------------------
+# the hand-written wire against the rows, protobuf and the native decoder
+
+def _histo(name, means, weights, mtype=dsd.TIMER, tags=(),
+           scope=dsd.SCOPE_DEFAULT, stats=(0.0, 1.0, 9.0, 0.0, 0.5)):
+    return ForwardRow(_meta(name, mtype, tags, scope), "histo",
+                      stats=np.asarray(stats, np.float32),
+                      means=np.asarray(means, np.float32),
+                      weights=np.asarray(weights, np.float32))
+
+
+def _counter(value, name="c", scope=dsd.SCOPE_GLOBAL):
+    return ForwardRow(_meta(name, dsd.COUNTER, ("veneurglobalonly",),
+                            scope), "counter", value=value)
+
+
+def _gauge(value):
+    return ForwardRow(_meta("g", dsd.GAUGE, (), dsd.SCOPE_GLOBAL),
+                      "gauge", value=value)
+
+
+def _dense_set():
+    regs = np.random.default_rng(3).integers(0, 16, hll.M)
+    return ForwardRow(_meta("s", dsd.SET), "set",
+                      regs=regs.astype(np.uint8))
+
+
+def _interleaved(width, seed):
+    rng = np.random.default_rng(seed)
+    weights = np.where(rng.random(width) < 0.4,
+                       rng.integers(1, 9, width), 0)
+    return rng.gamma(2.0, 30.0, width), weights
+
+
+_F32_TINY = float(np.float32(1e-45))  # the smallest denormal
+
+WIRE_CASES = {
+    "digest_0_live": [_histo("h", [5.0, 6.0], [0.0, 0.0])],
+    "digest_1_live": [_histo("h", [5.0, 6.0, 7.0], [0.0, 2.0, 0.0])],
+    "digest_616_live": [_histo("h", np.arange(616) + 0.5,
+                               np.ones(616))],
+    # 616 records are 12,320 bytes, a two-byte length; 1,024 are 20,480
+    "digest_1024_live_3_byte_length": [_histo(
+        "h", np.arange(1024) + 0.5, np.ones(1024))],
+    "digest_dead_interleaved": [_histo("h", *_interleaved(616, 5))],
+    "centroid_mean_edges": [_histo(
+        "h", [0.0, -0.0, _F32_TINY, 3.4e38, 0.0], [1, 2, 3, 4, 0])],
+    "digest_stats_zero": [_histo("h", [1.0], [1.0],
+                                 stats=(1.0, 0.0, -0.0, 0.0, 0.0))],
+    "counter_zero": [_counter(0.0)],
+    "counter_negative": [_counter(-7.0)],
+    "counter_rounds": [_counter(41.6)],
+    "counter_2_53": [_counter(float(2 ** 53))],
+    "gauge_zero": [_gauge(0.0)],
+    "gauge_negative": [_gauge(-2.5)],
+    "gauge_negative_zero": [_gauge(-0.0)],
+    "set_dense_16384": [_dense_set()],
+    "long_name_non_ascii_tag": [ForwardRow(
+        _meta("n" * 200, dsd.COUNTER, ("ville:orl\u00e9ans", "", "k:v"),
+              dsd.SCOPE_GLOBAL), "counter", value=3.0)],
+    "empty_name": [ForwardRow(_meta("", dsd.GAUGE), "gauge", value=1.5)],
+    "each_scope": [_counter(1.0, f"c.{s}", s) for s in _SCOPE_TO_PB],
+    "each_type": [_histo(f"h.{t}", [1.0], [1.0], mtype=t)
+                  for t in _TYPE_TO_PB],
+    "two_widths": [_histo("h.a", *_interleaved(616, 7)), _counter(2.0),
+                   _histo("h.b", *_interleaved(312, 8)),
+                   _histo("h.c", [], []),
+                   _histo("h.d", *_interleaved(616, 9)), _dense_set()],
+    "empty_list": [],
+}
+
+
+def _plain_body(rows, compression):
+    """The parent's encoder: one ``main_centroids.add()`` a centroid.
+    It is the reference for the bytes and for the values."""
+    ml = forward_pb2.MetricList()
+    for r in rows:
+        m = ml.metrics.add(name=r.meta.name, tags=list(r.meta.tags),
+                           type=_TYPE_TO_PB[r.meta.type],
+                           scope=_SCOPE_TO_PB[r.meta.scope])
+        if r.kind == "counter":
+            m.counter.value = int(round(r.value))
+        elif r.kind == "gauge":
+            m.gauge.value = float(r.value)
+        elif r.kind == "histo":
+            d = m.histogram.t_digest
+            d.compression = float(compression)
+            d.min = float(r.stats[segment.STAT_MIN])
+            d.max = float(r.stats[segment.STAT_MAX])
+            d.reciprocalSum = float(r.stats[segment.STAT_RSUM])
+            for mean, w in zip(r.means, r.weights):
+                if w > 0:
+                    c = d.main_centroids.add()
+                    c.mean = float(mean)
+                    c.weight = float(w)
+        else:
+            m.set.hyper_log_log = hll_codec.encode_dense(r.regs)
+    return ml.SerializeToString()
+
+
+def _bits(values):
+    return np.asarray(values, np.float64).view(np.uint64).tolist()
+
+
+@pytest.mark.parametrize("case", WIRE_CASES)
+def test_encoded_wire_equals_rows(case):
+    """``encode_metric_list``'s body is, byte for byte, what protobuf
+    serializes for the per-centroid ``add()`` loop, and both readers
+    of the wire (protobuf, the native columnar decoder) give back the
+    rows: same metrics, same order, the same float64 bits."""
+    rows = WIRE_CASES[case]
+    body, centroids = encode_metric_list(rows, 50.0)
+    assert body == _plain_body(rows, 50.0)
+    live = [np.asarray(r.weights) > 0 if r.kind == "histo" else None
+            for r in rows]
+    assert centroids == sum(int(m.sum()) for m in live if m is not None)
+
+    ml = forward_pb2.MetricList.FromString(body)
+    cols = decode_metric_list(body)
+    assert len(ml.metrics) == len(rows)
+    assert cols is not None and cols["n"] == len(rows)
+    kinds = {"counter": 1, "gauge": 2, "histo": 3, "set": 4}
+    for i, (r, m, lv) in enumerate(zip(rows, ml.metrics, live)):
+        assert (m.name, tuple(m.tags)) == (r.meta.name, r.meta.tags)
+        assert _PB_TO_TYPE[m.type] == r.meta.type
+        assert _PB_TO_SCOPE[m.scope] == r.meta.scope
+        assert m.WhichOneof("value") == {
+            "histo": "histogram"}.get(r.kind, r.kind)
+        off, n = int(cols["name_off"][i]), int(cols["name_len"][i])
+        assert body[off:off + n].decode() == r.meta.name
+        tags = [body[o:o + ln].decode() for o, ln in zip(
+            cols["tag_off"][cols["tag_start"][i]:][:cols["tag_cnt"][i]],
+            cols["tag_len"][cols["tag_start"][i]:][:cols["tag_cnt"][i]])]
+        assert tuple(tags) == r.meta.tags
+        assert _PB_TO_TYPE[int(cols["mtype"][i])] == r.meta.type
+        assert _PB_TO_SCOPE[int(cols["scope"][i])] == r.meta.scope
+        assert cols["kind"][i] == kinds[r.kind]
+        if r.kind == "counter":
+            assert m.counter.value == int(round(r.value))
+            assert cols["scalar"][i] == int(round(r.value))
+        elif r.kind == "gauge":
+            assert _bits([m.gauge.value]) == _bits([r.value])
+            assert _bits([cols["scalar"][i]]) == _bits([r.value])
+        elif r.kind == "set":
+            want = hll_codec.encode_dense(r.regs)
+            assert m.set.hyper_log_log == want
+            off, n = int(cols["hll_off"][i]), int(cols["hll_len"][i])
+            assert body[off:off + n] == want
+        else:
+            d = m.histogram.t_digest
+            want = [r.stats[segment.STAT_MIN], r.stats[segment.STAT_MAX],
+                    r.stats[segment.STAT_RSUM], 50.0]
+            assert _bits([d.min, d.max, d.reciprocalSum,
+                          d.compression]) == _bits(want)
+            assert _bits(cols["dstats"][i]) == _bits(want)
+            means, weights = r.means[lv], r.weights[lv]
+            assert _bits([c.mean for c in d.main_centroids]) == _bits(
+                means)
+            assert _bits([c.weight for c in d.main_centroids]) == _bits(
+                weights)
+            s, n = int(cols["cent_start"][i]), int(cols["cent_cnt"][i])
+            assert n == len(means)
+            assert _bits(cols["means"][s:s + n]) == _bits(means)
+            assert _bits(cols["weights"][s:s + n]) == _bits(weights)
+
+
+def test_encoded_wire_case_shapes():
+    """The cases above are the shapes they claim to be."""
+    assert encode_metric_list(WIRE_CASES["digest_616_live"])[1] == 616
+    body, _ = encode_metric_list(
+        WIRE_CASES["digest_1024_live_3_byte_length"])
+    # the Metric's length takes three bytes: two continued, one last
+    assert len(body) > 1 << 14
+    assert body[1] & body[2] & 0x80 and body[3] == len(body) - 4 >> 14
+    for case in ("digest_dead_interleaved", "two_widths"):
+        w = WIRE_CASES[case][0].weights
+        assert 0 < np.count_nonzero(w[:-1] * w[1:] == 0) and (w > 0).any()
+    assert encode_metric_list([]) == (b"", 0)
+    assert len(_dense_set().regs) == 16384
+    assert {len(r.means) for r in WIRE_CASES["two_widths"]
+            if r.kind == "histo"} == {616, 312, 0}
+
+
+@pytest.mark.parametrize("value", [float(2 ** 63), float("nan"),
+                                   float("inf")])
+def test_counter_outside_int64_raises(value):
+    """As protobuf's int64 field did: loudly, never wrapped."""
+    with pytest.raises((ValueError, OverflowError)):
+        encode_metric_list([_counter(value)])
+
+
+def test_unknown_kind_raises():
+    row = ForwardRow(_meta("x", dsd.COUNTER), "status", value=1.0)
+    with pytest.raises(ValueError):
+        encode_metric_list([row])
+
+
+def _cell_rows(live=100):
+    """The forward of ``local-wide-paced``: 1,000 digests of 100 live
+    centroids in 616-wide rows, 1,000 global-only counters, 100 dense
+    HLLs."""
+    rng = np.random.default_rng(27)
+    rows = []
+    for i in range(1000):
+        means, weights = np.zeros(616), np.zeros(616)
+        at = np.sort(rng.choice(616, live, replace=False))
+        means[at] = np.sort(rng.gamma(2.0, 30.0, live))
+        weights[at] = 1.0
+        rows.append(_histo(
+            f"bench.timer.{i:04d}", means, weights,
+            tags=("env:prod", f"shard:{i % 7}"),
+            stats=(live, means[at[0]], means[at[-1]], 0.0, 1.5)))
+    rows += [_counter(float(i), f"bench.counter.{i:04d}")
+             for i in range(1000)]
+    regs = rng.integers(0, 16, (100, hll.M)).astype(np.uint8)
+    rows += [ForwardRow(_meta(f"bench.set.{i:03d}", dsd.SET), "set",
+                        regs=regs[i]) for i in range(100)]
+    return rows
+
+
+def _calls_of_encode(rows):
+    """(body, centroids, every Python-level call and C call the encode
+    made), counted by a profile hook."""
+    import sys
+    calls = 0
+
+    def count(frame, event, arg):
+        nonlocal calls
+        calls += event in ("call", "c_call")
+
+    sys.setprofile(count)
+    try:
+        body, centroids = encode_metric_list(rows)
+    finally:
+        sys.setprofile(None)
+    return body, centroids, calls
+
+
+def test_cell_sized_wire_has_no_per_centroid_python():
+    """At the benchmark cell's shape the body parses to 2,100 metrics
+    and 100,000 centroids, and the encoder makes no Python-level call
+    per centroid: with twice the live centroids in the same rows it
+    makes as many calls (the per-centroid loop made 300,000 more)."""
+    body, centroids, calls = _calls_of_encode(_cell_rows())
+    assert centroids == 100_000
+    _, twice, calls_twice = _calls_of_encode(_cell_rows(live=200))
+    assert twice == 200_000
+    assert abs(calls_twice - calls) < 1000, (calls, calls_twice)
+
+    ml = forward_pb2.MetricList.FromString(body)
+    assert len(ml.metrics) == 2100
+    assert sum(len(m.histogram.t_digest.main_centroids)
+               for m in ml.metrics) == 100_000
+    cols = decode_metric_list(body)
+    assert cols["n"] == 2100
+    assert int(cols["cent_cnt"][:2100].sum()) == 100_000
+    assert body == _plain_body(_cell_rows(), 100.0)
 
 
 # ----------------------------------------------------------------------

@@ -215,6 +215,37 @@ def test_grpc_chain_single_stitched_trace(make_server):
     assert grec.imports == 0 and "import" not in grec.stages
 
 
+def test_forward_encode_span_counts_centroids(make_server):
+    """A flush of the benchmark cell's forward (1,000 timers of 100
+    samples, 1,000 global-only counters, 100 sets) through two
+    ``Server``s: the ``forward.encode`` span says how many rows and
+    how many live centroids its time was spent on."""
+    pytest.importorskip("grpc")
+    glob, _ = make_server(
+        grpc_listen_addresses=["tcp://127.0.0.1:0"],
+        statsd_listen_addresses=[])
+    local, _ = make_server(
+        forward_address=f"127.0.0.1:{glob.grpc_ports[0]}",
+        forward_use_grpc=True)
+    for i in range(1000):
+        local.handle_packet("\n".join(
+            [f"tc.lat.{i}:{v}.25|ms" for v in range(100)]
+            + [f"tc.hits.{i}:3|c|#veneurglobalonly"]).encode())
+    for i in range(100):
+        local.handle_packet("\n".join(
+            f"tc.users.{i}:u{v}|s" for v in range(50)).encode())
+    assert local.stats["metrics_processed"] == 106_000
+    res = local.flush_once()
+    assert len(res.forward) == 2100
+    assert _wait(lambda: glob.stats.get("imports_received", 0) >= 2100)
+
+    tid = _last_flush_trace(local)
+    enc = _forward_span(local, tid, "flush.forward.encode")
+    assert enc["tags"]["rows"] == "2100"
+    assert enc["tags"]["centroids"] == "100000"
+    assert int(enc["tags"]["bytes"]) > 100_000 * 20
+
+
 def test_proxy_hop_parents_both_sides(make_server):
     """local -> proxy (gRPC) -> global: the proxy's route span
     parents under the local's forward.send span, and the global's import

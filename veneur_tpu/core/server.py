@@ -3102,6 +3102,7 @@ class Server:
         and ``forward.send`` (the unary call, call to return), whose
         span's ids ride the wire."""
         from veneur_tpu.forward.grpc_forward import (ForwardClient,
+                                                     encode_metric_list,
                                                      wire_metadata)
         import grpc as _grpc
         if self._grpc_client is None:
@@ -3110,9 +3111,11 @@ class Server:
                 compression=float(self.config.tpu_compression),
                 credentials=self._forward_grpc_credentials())
         with cyc.stage("forward.encode", parent=span) as sp:
-            body = self._grpc_client.encode(rows)
+            body, centroids = encode_metric_list(
+                rows, float(self.config.tpu_compression))
             sp.add_tag("rows", str(len(rows)))
             sp.add_tag("bytes", str(len(body)))
+            sp.add_tag("centroids", str(centroids))
         try:
             with cyc.stage("forward.send", parent=span) as sp:
                 sp.add_tag("bytes", str(len(body)))

@@ -19,6 +19,7 @@ needed.
 from __future__ import annotations
 
 import logging
+import struct
 from concurrent import futures
 
 import numpy as np
@@ -158,48 +159,158 @@ _PB_TO_SCOPE = {v: k for k, v in _SCOPE_TO_PB.items()}
 
 
 # ----------------------------------------------------------------------
-# ForwardRow <-> metricpb.Metric
+# ForwardRow -> MetricList wire
 
-def row_to_metric(r: ForwardRow,
-                  compression: float = 100.0) -> metric_pb2.Metric:
-    """Encode one flush-produced forwardable row (the sending half of
-    worker.go:181 ForwardableMetrics -> metricpb).  ``compression`` is
-    the table's configured digest compression (a Go global sizes its
-    MergingDigest from this field)."""
-    m = metric_pb2.Metric(name=r.meta.name, tags=list(r.meta.tags),
-                          type=_TYPE_TO_PB[r.meta.type],
-                          scope=_SCOPE_TO_PB[r.meta.scope])
-    if r.kind == "counter":
-        # the reference wire type is int64 (metric.proto CounterValue)
-        m.counter.value = int(round(r.value))
-    elif r.kind == "gauge":
-        m.gauge.value = float(r.value)
-    elif r.kind == "histo":
-        d = m.histogram.t_digest
-        d.compression = float(compression)
-        st = r.stats
-        d.min = float(st[segment.STAT_MIN])
-        d.max = float(st[segment.STAT_MAX])
-        d.reciprocalSum = float(st[segment.STAT_RSUM])
-        live = np.asarray(r.weights) > 0
-        means = np.asarray(r.means)[live]
-        weights = np.asarray(r.weights)[live]
-        for mean, w in zip(means, weights):
-            c = d.main_centroids.add()
-            c.mean = float(mean)
-            c.weight = float(w)
-    elif r.kind == "set":
-        m.set.hyper_log_log = hll_codec.encode_dense(r.regs)
-    else:
-        raise ValueError(f"unknown forward kind {r.kind}")
-    return m
+# One live centroid on the wire: MergingDigestData field 1, a Centroid
+# of 18 bytes holding mean (field 1) and weight (field 2) as doubles.
+_CENTROID = np.dtype([("tag", "u1"), ("len", "u1"),
+                      ("mean_tag", "u1"), ("mean", "<f8"),
+                      ("weight_tag", "u1"), ("weight", "<f8")])
+# The digest's scalar doubles after its centroids: compression, min,
+# max, reciprocalSum (fields 2-5).
+_DIGEST_TAIL = np.dtype([("t2", "u1"), ("compression", "<f8"),
+                         ("t3", "u1"), ("min", "<f8"),
+                         ("t4", "u1"), ("max", "<f8"),
+                         ("t5", "u1"), ("rsum", "<f8")])
+_F64 = struct.Struct("<d")
+_U64 = (1 << 64) - 1
+
+
+_VARINT_1 = tuple(bytes((n,)) for n in range(0x80))
+
+
+def _varint(n: int) -> bytes:
+    if n < 0x80:
+        return _VARINT_1[n]
+    out = bytearray()
+    while n > 0x7F:
+        out.append((n & 0x7F) | 0x80)
+        n >>= 7
+    out.append(n)
+    return bytes(out)
+
+
+def _field(tag: bytes, payload: bytes) -> bytes:
+    """A length-delimited field."""
+    return tag + _varint(len(payload)) + payload
+
+
+def _pack(rec: np.ndarray, doubles: tuple[str, ...]
+          ) -> tuple[bytes, np.ndarray]:
+    """The bytes of wire records ``rec`` and the offset of each record
+    in them (one more offset than records).  Like protobuf's proto3
+    serializer, a field of ``doubles`` whose bits are all zero is left
+    off the wire, its tag byte with it."""
+    present = [rec[f].view("<u8") != 0 for f in doubles]
+    if all(p.all() for p in present):
+        return rec.tobytes(), np.arange(len(rec) + 1) * rec.itemsize
+    by = rec.view(np.uint8).reshape(len(rec), -1)
+    keep = np.ones(by.shape, bool)
+    for f, p in zip(doubles, present):
+        at = rec.dtype.fields[f][1]
+        keep[:, at - 1:at + 8] = p[:, None]
+    return by[keep].tobytes(), np.concatenate(
+        ([0], np.cumsum(keep.sum(axis=1))))
+
+
+def _encode_digests(rows: list[ForwardRow],
+                    compression: float) -> tuple[list[bytes], int]:
+    """The MergingDigestData bodies of ``rows`` (all histograms) and
+    the count of live centroids in them, with no Python-level work per
+    centroid: the rows' means and weights are concatenated once (so
+    rows of unequal width need no second path), the live mask is taken
+    over the whole flush, and every live centroid is laid into one
+    buffer of wire records; a row's ``main_centroids`` is a slice of
+    it."""
+    weights = np.concatenate([np.asarray(r.weights) for r in rows])
+    means = np.concatenate([np.asarray(r.means) for r in rows])
+    live = np.flatnonzero(weights > 0)
+    cent = np.empty(len(live), _CENTROID)
+    cent["tag"], cent["mean_tag"], cent["weight_tag"] = 0x0A, 0x09, 0x11
+    cent["mean"] = means[live]
+    cent["weight"] = weights[live]
+    cent["len"] = np.where(cent["mean"].view("<u8") != 0, 18, 9)
+    cents, cent_at = _pack(cent, ("mean",))
+    # the record at which each row starts, and the last one ends
+    row_at = np.concatenate(
+        ([0], np.cumsum([len(r.weights) for r in rows])))
+    cent_at = cent_at[np.searchsorted(live, row_at)].tolist()
+
+    stats = np.stack([np.asarray(r.stats) for r in rows])
+    tail = np.empty(len(rows), _DIGEST_TAIL)
+    tail["t2"], tail["t3"], tail["t4"], tail["t5"] = (0x11, 0x19, 0x21,
+                                                      0x29)
+    tail["compression"] = compression
+    tail["min"] = stats[:, segment.STAT_MIN]
+    tail["max"] = stats[:, segment.STAT_MAX]
+    tail["rsum"] = stats[:, segment.STAT_RSUM]
+    tails, tail_at = _pack(tail, ("compression", "min", "max", "rsum"))
+    tail_at = tail_at.tolist()
+    return ([cents[cent_at[i]:cent_at[i + 1]]
+             + tails[tail_at[i]:tail_at[i + 1]]
+             for i in range(len(rows))], len(live))
+
+
+def encode_metric_list(rows: list[ForwardRow],
+                       compression: float = 100.0) -> tuple[bytes, int]:
+    """The forward wire of ``rows``: a serialized ``MetricList`` (the
+    sending half of worker.go:181 ForwardableMetrics -> metricpb), and
+    the count of live centroids in it.  Written by hand from the rows'
+    arrays, byte for byte what protobuf's serializer gives for the
+    same message.  ``compression`` is the table's configured digest
+    compression (a Go global sizes its MergingDigest from this
+    field)."""
+    histos = [r for r in rows if r.kind == "histo"]
+    digests, centroids = (_encode_digests(histos, float(compression))
+                          if histos else ((), 0))
+    digests = iter(digests)
+    out = []
+    for r in rows:
+        meta = r.meta
+        m = [_field(b"\x0a", meta.name.encode())] if meta.name else []
+        m += [_field(b"\x12", t.encode()) for t in meta.tags]
+        mtype = _TYPE_TO_PB[meta.type]
+        if mtype:
+            m.append(b"\x18" + _VARINT_1[mtype])
+        if r.kind == "counter":
+            # the reference wire type is int64 (metric.proto
+            # CounterValue)
+            v = int(round(r.value))
+            if not -(1 << 63) <= v < (1 << 63):
+                raise ValueError(f"counter {meta.name} out of int64")
+            m.append(_field(
+                b"\x2a", b"\x08" + _varint(v & _U64) if v else b""))
+        elif r.kind == "gauge":
+            v = _F64.pack(float(r.value))
+            m.append(_field(b"\x32", b"\x09" + v if any(v) else b""))
+        elif r.kind == "histo":
+            m.append(_field(b"\x3a", _field(b"\x0a", next(digests))))
+        elif r.kind == "set":
+            regs = hll_codec.encode_dense(r.regs)
+            m.append(_field(
+                b"\x42", _field(b"\x0a", regs) if regs else b""))
+        else:
+            raise ValueError(f"unknown forward kind {r.kind}")
+        scope = _SCOPE_TO_PB[meta.scope]
+        if scope:
+            m.append(b"\x48" + _VARINT_1[scope])
+        m = b"".join(m)
+        out += (b"\x0a", _varint(len(m)), m)
+    return b"".join(out), centroids
 
 
 def rows_to_metric_list(rows: list[ForwardRow],
                         compression: float = 100.0
                         ) -> forward_pb2.MetricList:
-    return forward_pb2.MetricList(
-        metrics=[row_to_metric(r, compression) for r in rows])
+    """``encode_metric_list``'s wire as a message object."""
+    return forward_pb2.MetricList.FromString(
+        encode_metric_list(rows, compression)[0])
+
+
+def row_to_metric(r: ForwardRow,
+                  compression: float = 100.0) -> metric_pb2.Metric:
+    """One row of ``encode_metric_list``'s wire as a message object."""
+    return rows_to_metric_list([r], compression).metrics[0]
 
 
 def apply_metric(table: MetricTable, m: metric_pb2.Metric) -> bool:
@@ -988,8 +1099,7 @@ class ForwardClient:
 
     def encode(self, rows: list[ForwardRow]) -> bytes:
         """The wire body of ``rows``: a serialized MetricList."""
-        return rows_to_metric_list(
-            rows, self._compression).SerializeToString()
+        return encode_metric_list(rows, self._compression)[0]
 
     def send_wire(self, body: bytes, timeout: float | None = None,
                   metadata=None) -> None:

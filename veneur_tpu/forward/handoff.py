@@ -126,8 +126,7 @@ class HandoffShipper:
             metadata += [(gf.TRACE_ID_KEY, str(trace_context[0])),
                          (gf.SPAN_ID_KEY, str(trace_context[1]))]
         for member, rows in sorted(rows_by_member.items()):
-            body = gf.rows_to_metric_list(
-                rows, self.compression).SerializeToString()
+            body = gf.encode_metric_list(rows, self.compression)[0]
             try:
                 self._client(member).send_wire(body,
                                                metadata=metadata)

@@ -214,9 +214,8 @@ class ShardedForwarder:
         """One MetricList wire for the whole flush — the single
         serialization every destination's body is then a byte-gather
         of."""
-        from veneur_tpu.forward.grpc_forward import rows_to_metric_list
-        return rows_to_metric_list(
-            rows, self.compression).SerializeToString()
+        from veneur_tpu.forward.grpc_forward import encode_metric_list
+        return encode_metric_list(rows, self.compression)[0]
 
     def route(self, data: bytes,
               ring: ConsistentRing | None = None) -> RoutedWire | None:
@@ -233,15 +232,14 @@ class ShardedForwarder:
         ``row_route_key`` and serialize one MetricList per
         destination.  Same ownership as :meth:`route`, kept as the
         fail-open path and the parity oracle."""
-        from veneur_tpu.forward.grpc_forward import rows_to_metric_list
+        from veneur_tpu.forward.grpc_forward import encode_metric_list
         ring = self.ring
         groups: dict[str, list] = {}
         for row in rows:
             groups.setdefault(
                 ring.get(row_route_key(row)), []).append(row)
         return [(dest,
-                 rows_to_metric_list(
-                     batch, self.compression).SerializeToString(),
+                 encode_metric_list(batch, self.compression)[0],
                  len(batch))
                 for dest, batch in groups.items()]
 

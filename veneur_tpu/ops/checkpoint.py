@@ -85,7 +85,7 @@ def next_incarnation(directory: str) -> int:
 
 # ----------------------------------------------------------------------
 # row building: staged-capture -> ForwardRow list (the columnar wire's
-# native unit; grpc_forward.rows_to_metric_list does the encoding)
+# native unit; grpc_forward.encode_metric_list does the encoding)
 
 def _condense(values: np.ndarray, weights: np.ndarray,
               cap: int) -> tuple[np.ndarray, np.ndarray]:
@@ -266,10 +266,9 @@ def serialize_capture(cap: dict, capacity: int,
                       compression: float) -> tuple[bytes, int]:
     """(wire body, row count) for a capture — the body is a
     ``forwardrpc.MetricList``, importable by every tier."""
-    from veneur_tpu.forward.grpc_forward import rows_to_metric_list
+    from veneur_tpu.forward.grpc_forward import encode_metric_list
     rows = build_rows(cap, capacity)
-    body = rows_to_metric_list(rows, compression).SerializeToString()
-    return body, len(rows)
+    return encode_metric_list(rows, compression)[0], len(rows)
 
 
 # ----------------------------------------------------------------------

@@ -252,7 +252,7 @@ def pack_block(rows: list[ForwardRow], schema: PlaneSchema
             cm = np.zeros(schema.centroids, np.float32)
             cw = np.zeros(schema.centroids, np.float32)
             # exactly the wire's centroid list: live entries in
-            # original order (row_to_metric's weights > 0 filter)
+            # original order (encode_metric_list's weights > 0 filter)
             cm[:n_live] = means[live]
             cw[:n_live] = weights[live]
             mo = body + 20
