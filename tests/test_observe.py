@@ -21,7 +21,9 @@ from veneur_tpu.core.config import read_config
 from veneur_tpu.core.server import Server
 from veneur_tpu.core.telemetry import Telemetry, _rss_bytes
 from veneur_tpu.observe.devicecost import DeviceCostRegistry
-from veneur_tpu.observe.flushring import FlushRecord, FlushRing
+from veneur_tpu.observe.flushring import (FlushRecord, FlushRing,
+                                          is_gc_key)
+from veneur_tpu.observe.gcpause import PAUSES
 from veneur_tpu.sinks.simple import CaptureSink
 
 
@@ -100,7 +102,8 @@ def test_flush_ring_record_matches_cycle():
             # top-level stages are disjoint intervals inside the
             # cycle (a dotted stage is a child of the one it names)
             assert sum(ns for k, ns in rec.stages.items()
-                       if "." not in k) <= rec.duration_ns
+                       if "." not in k and not is_gc_key(k)
+                       ) <= rec.duration_ns
             assert rec.error == ""
             # nothing forwarded, nothing imported: both read zero
             d = rec.to_dict()
@@ -170,6 +173,8 @@ def test_flush_stages_are_events_on_the_profilers_clock(tmp_path):
                           ("flush", "ingest.batch", "apply.staged"))
     assert set(rec.stages) >= set(STAGES)
     for stage, ns in rec.stages.items():
+        if is_gc_key(stage):      # a pause, not a stage: no event
+            continue
         got = events.get(f"flush.{stage}")
         assert got, (stage, sorted(events))
         assert abs(sum(d for _, d in got) - ns) <= max(2e6, 0.1 * ns)
@@ -184,6 +189,130 @@ def test_flush_stages_are_events_on_the_profilers_clock(tmp_path):
     (a0, ad), = events["apply.staged"]
     (s0, sd), = events["flush.swap_apply"]
     assert s0 <= a0 and a0 + ad <= s0 + sd
+
+
+# ---------------------------------------------------------------------
+# collector pauses in the flush record
+
+class _CollectingSink(CaptureSink):
+    """Forces a full collection while it is handed its flush: inside
+    its own ``sink.collecting`` stage, on its worker's thread, while
+    the flush thread waits for it inside ``sink_flush``."""
+    name = "collecting"
+    collect = True
+
+    def flush(self, metrics):
+        import gc
+        if self.collect:
+            gc.collect()
+        super().flush(metrics)
+
+
+def test_forced_collection_shows_in_stage_record_and_debug_flushes():
+    import gc
+    import json
+    import urllib.request
+    sink, cap = _CollectingSink(), CaptureSink()
+    srv = Server(read_config(data={
+        "statsd_listen_addresses": [], "interval": "10s",
+        "http_address": "127.0.0.1:0", "hostname": "gc-host"}),
+        extra_sinks=[sink], extra_span_sinks=[cap])
+    srv.start()
+    try:
+        srv.handle_packet(b"gc.hits:3|c")
+        gen2 = PAUSES.collections[2]
+        srv.flush_once()
+        rec = srv.flush_ring.records()[-1]
+        # the pause is the worker's stage's and, the interpreter lock
+        # being one, that of the stage that waited for it
+        paused = rec.stages["gc.sink.collecting"]
+        assert paused > 0
+        assert rec.stages["gc.sink_flush"] >= paused
+        assert rec.gc_pause_ns == rec.stages["gc"] >= paused
+        assert rec.gc_gen2 >= 1
+        assert PAUSES.collections[2] - gen2 >= rec.gc_gen2
+        # a stage no collection ended in has no key; every key is a
+        # stage's
+        for k in rec.stages:
+            if is_gc_key(k) and k != "gc":
+                assert k[3:] in rec.stages and rec.stages[k] > 0
+        # the same on the spans: gc_ns where there was a pause
+        assert _wait(lambda: {"flush", "flush.sink.collecting",
+                              "flush.snapshot"}
+                     <= {sp.name for sp in cap.spans})
+        by_name = {sp.name: sp for sp in cap.spans}
+        assert by_name["flush.sink.collecting"].tags["gc_ns"] == str(
+            paused)
+        assert int(by_name["flush"].tags["gc_ns"]) >= paused
+        for sp in cap.spans:
+            stage = sp.tags.get("stage")
+            if stage:
+                assert ("gc_ns" in sp.tags) == (
+                    f"gc.{stage}" in rec.stages), stage
+        d = json.loads(urllib.request.urlopen(
+            f"http://127.0.0.1:{srv.http_port}/debug/flushes?n=1",
+            timeout=5).read())[0]
+        assert d["gc_pause_ns"] == rec.gc_pause_ns
+        assert d["gc_gen2"] == rec.gc_gen2
+        assert d["stages_ns"]["gc.sink.collecting"] == paused
+        # a cycle without a collection still says that it counts
+        sink.collect = False
+        gc.disable()
+        try:
+            srv.flush_once()
+        finally:
+            gc.enable()
+        assert srv.flush_ring.records()[-1].stages["gc"] == 0
+        # self-telemetry: the last whole cycle's pause as a gauge, a
+        # flush late, and no stage series for a pause key
+        srv.flush_once()
+        tele = {m.name: m for m in sink.metrics
+                if m.name.startswith("veneur.flush.")}
+        assert tele["veneur.flush.gc_pause_ns"].value > 0
+        assert not any(
+            t.startswith("stage:gc") for m in sink.metrics
+            if m.name.startswith("veneur.flush.stage_duration_ns")
+            for t in m.tags)
+    finally:
+        srv.shutdown()
+
+
+def test_one_gc_hook_a_process_removed_by_the_last_shutdown(
+        monkeypatch):
+    import gc
+    from veneur_tpu.observe.gcpause import GcPauses
+    # a counter of this test's own: a server another test left
+    # running holds the process's
+    pauses = GcPauses()
+    monkeypatch.setattr("veneur_tpu.core.server.PAUSES", pauses)
+    hooks = len(gc.callbacks)
+    a = Server(read_config(data={"statsd_listen_addresses": [],
+                                 "interval": "10s"}))
+    b = Server(read_config(data={"statsd_listen_addresses": [],
+                                 "interval": "10s"}))
+    assert len(gc.callbacks) == hooks      # constructing hooks nothing
+    a.start()
+    b.start()
+    try:
+        assert len(gc.callbacks) == hooks + 1
+        gc.collect()
+        total = pauses.pause_ns
+        assert total > 0 and pauses.collections[2] == 1
+        young = pauses.collections[0]
+        gc.collect(0)
+        assert pauses.pause_ns > total
+        assert pauses.collections[0] > young
+        assert pauses.collections[2] == 1
+    finally:
+        a.shutdown()
+        assert len(gc.callbacks) == hooks + 1 and pauses.installed
+        b.shutdown()
+    assert len(gc.callbacks) == hooks and not pauses.installed
+    b.shutdown()                            # twice: nothing to take off
+    assert len(gc.callbacks) == hooks
+    total = pauses.pause_ns
+    gc.collect()
+    assert pauses.pause_ns == total
 
 
 # ---------------------------------------------------------------------

@@ -246,6 +246,72 @@ def test_forward_encode_span_counts_centroids(make_server):
     assert int(enc["tags"]["bytes"]) > 100_000 * 20
 
 
+ROW_TAGS = ("rows_histo", "rows_sets", "rows_scalars")
+
+
+def test_row_counts_by_class_on_encode_import_and_swap(make_server):
+    """All four kinds through a local -> global flush: the three row
+    counts of ``forward.encode`` add up to its ``rows`` and equal
+    those of the global's ``import.apply``; ``flush.swap_apply``
+    counts what the interval staged; a flush with nothing to forward
+    has no such span."""
+    pytest.importorskip("grpc")
+    glob, _ = make_server(
+        grpc_listen_addresses=["tcp://127.0.0.1:0"],
+        statsd_listen_addresses=[])
+    local, _ = make_server(
+        forward_address=f"127.0.0.1:{glob.grpc_ports[0]}",
+        forward_use_grpc=True)
+    for i in range(7):                         # 7 timers x 12
+        local.handle_packet("\n".join(
+            f"rc.lat.{i}:{v}.5|ms" for v in range(12)).encode())
+    for i in range(5):                         # 5 sets x 9 members
+        local.handle_packet("\n".join(
+            f"rc.users.{i}:u{v}|s" for v in range(9)).encode())
+    local.handle_packet("\n".join(             # 3 + 4 counters, 6 gauges
+        [f"rc.g.{i}:2|c|#veneurglobalonly" for i in range(3)]
+        + [f"rc.c.{i}:2|c" for i in range(4)]
+        + [f"rc.v.{i}:{i}|g" for i in range(6)]).encode())
+    res = local.flush_once()
+    assert len(res.forward) == 7 + 5 + 3
+    assert _wait(lambda: glob.stats.get("imports_received", 0) >= 15)
+
+    tid = _last_flush_trace(local)
+    enc = _forward_span(local, tid, "flush.forward.encode")["tags"]
+    assert [enc[k] for k in ROW_TAGS] == ["7", "5", "3"]
+    assert sum(int(enc[k]) for k in ROW_TAGS) == int(enc["rows"]) == 15
+    assert enc["centroids"] == str(7 * 12)
+    assert _wait(lambda: any(s["name"] == "import.apply"
+                             for s in glob.trace_index.get(tid)))
+    app = _forward_span(glob, tid, "import.apply")["tags"]
+    assert {k: app[k] for k in ROW_TAGS + ("centroids",)} == {
+        k: enc[k] for k in ROW_TAGS + ("centroids",)}
+
+    # what the swap was about to apply: the test's lines (a server's
+    # first interval holds none of its own telemetry yet)
+    swap = _forward_span(local, tid, "flush.swap_apply")["tags"]
+    assert {k: int(v) for k, v in swap.items() if k != "stage"
+            and not k.startswith(("veneur.", "gc_"))} == {
+        "histo_samples": 7 * 12, "histo_rows": 7,
+        "set_members": 5 * 9, "set_rows": 5,
+        "scalar_rows": 3 + 4 + 6}
+    # the global's swap holds the imported centroids and rows
+    glob.flush_once()
+    gswap = _forward_span(glob, _last_flush_trace(glob),
+                          "flush.swap_apply")["tags"]
+    assert int(gswap["histo_samples"]) >= 7 * 12
+    assert int(gswap["histo_rows"]) >= 7
+    assert int(gswap["set_rows"]) >= 5
+
+    # nothing to forward: no forward span, so no counts
+    local.handle_packet(b"rc.c.0:1|c")
+    assert local.flush_once().forward == []
+    names = {s["name"] for s in
+             local.trace_index.get(_last_flush_trace(local))}
+    assert "flush.swap_apply" in names
+    assert not any(n.startswith("flush.forward") for n in names)
+
+
 def test_proxy_hop_parents_both_sides(make_server):
     """local -> proxy (gRPC) -> global: the proxy's route span
     parents under the local's forward.send span, and the global's import

@@ -17,6 +17,7 @@ the process.
 
 from __future__ import annotations
 
+import collections
 import http.server
 import json
 import logging
@@ -37,6 +38,7 @@ import numpy as np
 
 from veneur_tpu.forward import http_import
 from veneur_tpu.observe import ImportSpan, annotate
+from veneur_tpu.observe.gcpause import PAUSES
 from veneur_tpu.protocol import columnar, dogstatsd as dsd
 from veneur_tpu.protocol.addr import parse_addr
 from veneur_tpu.sinks import base as sinks_base
@@ -343,6 +345,7 @@ class Server:
 
         from veneur_tpu.core.telemetry import Telemetry
         self.telemetry = Telemetry(self)
+        self._gc_hooked = False
         self._sink_durations: dict[str, float] = {}
         self._flush_pending: dict[str, object] = {}
         # per-sink flush fan-out (VENEUR_TPU_SINK_WORKERS > 0): every
@@ -977,6 +980,12 @@ class Server:
         return run
 
     def start(self) -> None:
+        if not self._gc_hooked:
+            # collector pauses into the flush record (observe/
+            # gcpause.py): one hook a process, the last shutdown
+            # takes it off
+            self._gc_hooked = True
+            PAUSES.install()
         for ai, addr in enumerate(self.config.statsd_listen_addresses):
             self._start_statsd(addr, ai)
         if self.config.http_address:
@@ -2298,7 +2307,10 @@ class Server:
                         table_overflow=pend.overflow,
                         kernel_drops=kdrops)
                     self._take_imports(cyc.record)
-            with cyc.stage("swap_apply"):
+            staged = pend.staged_counts()
+            with cyc.stage("swap_apply") as sp:
+                for k, v in staged.items():
+                    sp.add_tag(k, str(v))
                 snap = self.table.complete_swap(pend)
         else:
             with cyc.stage("snapshot"):
@@ -3110,12 +3122,17 @@ class Server:
                 self.config.forward_address,
                 compression=float(self.config.tpu_compression),
                 credentials=self._forward_grpc_credentials())
+        kinds = collections.Counter(r.kind for r in rows)
         with cyc.stage("forward.encode", parent=span) as sp:
             body, centroids = encode_metric_list(
                 rows, float(self.config.tpu_compression))
             sp.add_tag("rows", str(len(rows)))
             sp.add_tag("bytes", str(len(body)))
             sp.add_tag("centroids", str(centroids))
+            sp.add_tag("rows_histo", str(kinds["histo"]))
+            sp.add_tag("rows_sets", str(kinds["set"]))
+            sp.add_tag("rows_scalars",
+                       str(kinds["counter"] + kinds["gauge"]))
         try:
             with cyc.stage("forward.send", parent=span) as sp:
                 sp.add_tag("bytes", str(len(body)))
@@ -3635,6 +3652,9 @@ class Server:
                 and self.config.is_local()):
             self._drain_handoff()
         self._shutdown.set()
+        if self._gc_hooked:
+            self._gc_hooked = False
+            PAUSES.remove()
         if self._checkpointer is not None:
             self._checkpointer.stop()
             self._checkpointer = None

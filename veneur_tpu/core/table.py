@@ -255,6 +255,20 @@ class _StagedWork:
                  "digest", "wire_parts", "set_parts", "stats_parts",
                  "set_import", "empty")
 
+    def histo_samples(self) -> int:
+        """Staged timer samples and centroids, local and imported."""
+        n = sum(len(st) for st in (self.histo, self.digest)
+                if st is not None)
+        if self.wire_parts is not None:
+            n += sum(len(p[0]) for p in self.wire_parts)
+        return n
+
+    def set_members(self) -> int:
+        if self.set_parts is None:
+            return 0
+        rows, _members, pos_rows, _pos = self.set_parts
+        return len(rows) + sum(len(r) for r in pos_rows)
+
 
 class _PendingSwap:
     """begin_swap's output: the final detached staging plus the row
@@ -265,6 +279,19 @@ class _PendingSwap:
                  "gauge_meta", "gauge_touched", "histo_meta",
                  "histo_touched", "set_meta", "set_touched",
                  "overflow", "ingested", "row_maps")
+
+    def staged_counts(self) -> dict[str, int]:
+        """What the final apply is about to take, for the
+        ``flush.swap_apply`` span's tags: lengths and sums of host
+        arrays already in hand.  Samples and members are those still
+        staged at the swap (a mid-interval device step took its own);
+        rows are the interval's touched rows."""
+        return {"histo_samples": self.work.histo_samples(),
+                "histo_rows": int(self.histo_touched.sum()),
+                "set_members": self.work.set_members(),
+                "set_rows": int(self.set_touched.sum()),
+                "scalar_rows": int(self.counter_touched.sum()
+                                   + self.gauge_touched.sum())}
 
 
 @dataclass
@@ -1648,16 +1675,7 @@ class MetricTable:
             # mid-interval detach: these samples move to device state
             # and out of any future checkpoint's view — tally them so
             # the checkpoint header names what it does NOT cover
-            n = 0
-            if w.histo is not None:
-                n += sum(len(r) for r in w.histo.rows)
-            if w.digest is not None:
-                n += sum(len(r) for r in w.digest.rows)
-            if w.wire_parts is not None:
-                n += sum(len(p[0]) for p in w.wire_parts)
-            if w.set_parts is not None:
-                sr, _sm, spr, _sp = w.set_parts
-                n += len(sr) + sum(len(r) for r in spr)
+            n = w.histo_samples() + w.set_members()
             if w.stats_parts is not None:
                 n += sum(len(p[0]) for p in w.stats_parts)
             self._interval_device_staged += n

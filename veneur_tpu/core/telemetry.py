@@ -23,36 +23,10 @@ import socket
 import time
 
 from veneur_tpu import observe
+from veneur_tpu.observe.flushring import is_gc_key
+from veneur_tpu.observe.gcpause import PAUSES
 from veneur_tpu.protocol import dogstatsd as dsd
 from veneur_tpu.protocol.addr import parse_addr
-
-# cumulative GC pause time via gc callbacks — the Python stand-in for
-# Go's MemStats.PauseTotalNs (reference flusher.go:36).  Installed
-# once per process; time.monotonic_ns in the callbacks costs ~100ns
-# per collection, noise next to a collection itself.
-_GC_PAUSE = {"total_ns": 0, "t0": 0, "installed": False}
-
-
-def _gc_cb(phase, info):
-    if phase == "start":
-        _GC_PAUSE["t0"] = time.monotonic_ns()
-    elif _GC_PAUSE["t0"]:
-        _GC_PAUSE["total_ns"] += time.monotonic_ns() - _GC_PAUSE["t0"]
-
-
-def _install_gc_hook() -> None:
-    # called from Telemetry.__init__, NOT at import: mutating the
-    # process-global gc.callbacks should be scoped to processes that
-    # actually emit the metric, and the flag (not an `in` check, which
-    # a reload would defeat with a fresh function object) keeps it
-    # single-registered
-    if not _GC_PAUSE["installed"]:
-        _GC_PAUSE["installed"] = True
-        gc.callbacks.append(_gc_cb)
-
-
-def _gc_pause_total_ns() -> int:
-    return _GC_PAUSE["total_ns"]
 
 
 def _rss_bytes() -> int:
@@ -362,8 +336,16 @@ class Telemetry:
         # device dispatch vs readback sync vs host emit vs sink I/O
         if record is not None:
             for stage, ns in list(record.stages.items()):
-                timer("veneur.flush.stage_duration_ns", ns,
-                      (f"stage:{stage}",))
+                if not is_gc_key(stage):
+                    timer("veneur.flush.stage_duration_ns", ns,
+                          (f"stage:{stage}",))
+            # collector pauses inside the last whole cycle (this one
+            # goes on past this call).  A gauge: a dense row, so the
+            # series adds no sample to the digest merge's batch
+            ring = getattr(self.server, "flush_ring", None)
+            done = ring.last() if ring is not None else None
+            if done is not None:
+                gauge("veneur.flush.gc_pause_ns", done.gc_pause_ns)
         # device-cost registry deltas (observe/devicecost.py): compile
         # activity in steady state means a hot-path jit silently
         # recompiled — the shape-drift failure mode the registry
@@ -607,12 +589,13 @@ class Telemetry:
                   imp_ns / resp, ("part:merge",))
 
         # runtime stats (flusher.go:32-43: gc.number, heap bytes).
-        # gc pause time comes from gc callbacks (the Python stand-in
-        # for Go's PauseTotalNs).
+        # gc pause time comes from the process-wide gc.callbacks hook
+        # (observe/gcpause.py; the Python stand-in for Go's
+        # PauseTotalNs), installed by Server.start.
         counts = gc.get_stats()
         gauge("veneur.gc.number",
               sum(s.get("collections", 0) for s in counts))
-        gauge("veneur.gc.pause_total_ns", _gc_pause_total_ns())
+        gauge("veneur.gc.pause_total_ns", PAUSES.pause_ns)
         gauge("veneur.mem.heap_alloc_bytes", _rss_bytes())
         gauge("veneur.flush.flush_timestamp_ns", time.time_ns())
 

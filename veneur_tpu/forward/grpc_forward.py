@@ -844,6 +844,28 @@ def apply_decoded(table: MetricTable, data: bytes,
     return accepted, dropped
 
 
+def wire_row_counts(cols: dict | None, pb=None) -> dict[str, int]:
+    """What a received wire holds, by class, for the ``import.apply``
+    span's tags: the same three row counts the sender's
+    ``forward.encode`` span carries, and the centroids of its
+    digests.  From the decoded columns, or from the protobuf on the
+    fallback path."""
+    if cols is not None:
+        nm = cols["n"]
+        kind = cols["kind"][:nm]
+        by = np.bincount(kind, minlength=5)
+        return {"rows_histo": int(by[3]), "rows_sets": int(by[4]),
+                "rows_scalars": int(by[1] + by[2]),
+                "centroids": int(cols["cent_cnt"][:nm][kind == 3].sum())}
+    which = [m.WhichOneof("value") for m in pb.metrics]
+    return {"rows_histo": which.count("histogram"),
+            "rows_sets": which.count("set"),
+            "rows_scalars": which.count("counter") + which.count("gauge"),
+            "centroids": sum(len(m.histogram.t_digest.main_centroids)
+                             for m, w in zip(pb.metrics, which)
+                             if w == "histogram")}
+
+
 def apply_metric_list_bytes(table: MetricTable,
                             data: bytes) -> tuple[int, int]:
     """apply_metric_list from the RAW wire: columnar native decode +
@@ -963,10 +985,13 @@ class ImportServer:
             cols = decode_metric_list(request)
             pb = (forward_pb2.MetricList.FromString(request)
                   if cols is None else None)
+        counts = wire_row_counts(cols, pb)
         with imp.step("lock_wait"):
             core.lock.acquire()
         try:
-            with imp.step("apply"):
+            with imp.step("apply") as sp:
+                for k, v in counts.items():
+                    sp.add_tag(k, str(v))
                 # crash-recovery dedup, atomic with the apply: a
                 # segment replayed twice (restart raced, or the
                 # replayer retried a timed-out send that actually

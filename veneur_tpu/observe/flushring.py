@@ -17,12 +17,19 @@ from dataclasses import dataclass, field
 DEFAULT_CAPACITY = 128
 
 
+def is_gc_key(stage: str) -> bool:
+    """Keys of ``FlushRecord.stages`` that are collector pauses and
+    not stages: ``gc`` (the cycle's) and ``gc.<stage>``."""
+    return stage == "gc" or stage.startswith("gc.")
+
+
 @dataclass
 class FlushRecord:
     seq: int = 0
     start_unix: float = 0.0
     duration_ns: int = 0
-    # stage name -> cumulative ns (a stage entered twice accumulates)
+    # stage name -> cumulative ns (a stage entered twice accumulates);
+    # also the collector's pauses, see ``is_gc_key``
     stages: dict[str, int] = field(default_factory=dict)
     readback_bytes: int = 0
     metrics_emitted: int = 0
@@ -33,6 +40,12 @@ class FlushRecord:
     imports: int = 0
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
+    # collector pauses that ended inside the cycle, on any thread
+    # (observe/gcpause.py), and how many were full collections; a
+    # stage's share is ``stages["gc.<stage>"]``, the whole is also
+    # ``stages["gc"]``
+    gc_pause_ns: int = 0
+    gc_gen2: int = 0
     error: str = ""
     # trace id of the cycle's span tree — the /debug/flushes ->
     # /debug/trace/<id> link (string in JSON: ids are 63-bit)
@@ -49,6 +62,8 @@ class FlushRecord:
                 "imports": self.imports,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
+                "gc_pause_ns": self.gc_pause_ns,
+                "gc_gen2": self.gc_gen2,
                 "error": self.error,
                 "trace_id": str(self.trace_id)}
 
@@ -74,6 +89,10 @@ class FlushRing:
         """Oldest -> newest."""
         with self._lock:
             return list(self._ring)
+
+    def last(self) -> FlushRecord | None:
+        with self._lock:
+            return self._ring[-1] if self._ring else None
 
     def to_json(self, limit: int | None = None) -> bytes:
         """``limit`` bounds the dump to the newest N records (the

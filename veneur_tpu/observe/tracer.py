@@ -49,6 +49,12 @@ lines of the same ``.xplane.pb`` as the device's ``XLA Ops``, on the
 profiler's clock.  ``annotate`` is the same for the two per-batch
 sites that have no SSF span (``ingest.batch``, ``apply.staged``).
 
+``_traced`` also reads the process's collector-pause counter
+(``observe/gcpause.py``) on entry and exit: a span in which a
+collection ended carries ``gc_ns``, a flush stage also the key
+``gc.<stage>`` in its record's ``stages``, and every record the key
+``gc`` (the cycle's whole pause) with ``gc_pause_ns`` / ``gc_gen2``.
+
 ``NULL_CYCLE`` is the no-tracer stand-in for direct ``Flusher.flush``
 callers (tests, benches): stages are free, but readback accounting
 still reaches the device-cost registry.
@@ -65,6 +71,7 @@ from jax.profiler import TraceAnnotation as annotate
 
 from veneur_tpu.observe.devicecost import REGISTRY
 from veneur_tpu.observe.flushring import FlushRecord, FlushRing
+from veneur_tpu.observe.gcpause import PAUSES
 from veneur_tpu.trace.spans import Span
 
 
@@ -94,13 +101,17 @@ def _finish(span, client, index) -> None:
 
 
 @contextlib.contextmanager
-def _traced(span, name: str, client, index, note):
+def _traced(span, name: str, client, index, note, note_gc=None):
     """The one way a block is timed here: as the SSF span ``span`` and
     as a profiler annotation ``name`` around the same work, so a stage
     cannot have one without the other; ``note(ns)`` takes the block's
-    monotonic duration."""
+    monotonic duration.  Collector pauses that ended inside the block
+    (on any thread: a collection holds the interpreter lock the block
+    needs) are the span's tag ``gc_ns`` and go to ``note_gc(ns)``,
+    both only where there were any."""
     ann = annotate(name)
     ann.__enter__()
+    gc0 = PAUSES.pause_ns
     t0 = time.monotonic_ns()
     try:
         yield span
@@ -110,6 +121,11 @@ def _traced(span, name: str, client, index, note):
     finally:
         note(time.monotonic_ns() - t0)
         ann.__exit__(None, None, None)
+        paused = PAUSES.pause_ns - gc0
+        if paused:
+            span.add_tag("gc_ns", str(paused))
+            if note_gc is not None:
+                note_gc(paused)
         _finish(span, client, index)
 
 
@@ -172,7 +188,8 @@ class FlushCycle:
         sp.add_tag("stage", name)
         sp.add_tag("veneur.internal", "true")
         return _traced(sp, f"flush.{name}", self._client, self._index,
-                       functools.partial(self._add_stage, name))
+                       functools.partial(self._add_stage, name),
+                       functools.partial(self._add_stage, f"gc.{name}"))
 
     def child(self, parent, name: str, tags=None):
         """A live child span under ``parent`` (a stage span), for
@@ -283,6 +300,7 @@ class FlushTracer:
         cyc = FlushCycle(root, self.client, record, self.registry,
                          index=self.index)
         compiles0 = self.registry.totals()["compile_total"]
+        gc0, gen2_0 = PAUSES.pause_ns, PAUSES.collections[2]
         try:
             with _traced(root, "flush", self.client, self.index,
                          functools.partial(setattr, record,
@@ -296,6 +314,11 @@ class FlushTracer:
                     record.compiles = (
                         self.registry.totals()["compile_total"]
                         - compiles0)
+                    # the cycle's whole pause, 0 included: the key
+                    # says that this program counts
+                    record.gc_pause_ns = PAUSES.pause_ns - gc0
+                    record.gc_gen2 = PAUSES.collections[2] - gen2_0
+                    cyc._add_stage("gc", record.gc_pause_ns)
                     root.add_tag("flush.seq", str(record.seq))
         finally:
             self.ring.append(record)
