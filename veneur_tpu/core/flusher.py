@@ -29,6 +29,7 @@ Histo aggregate emission matches samplers/samplers.go:511-672: .min
 from __future__ import annotations
 
 import time
+from collections.abc import Sequence
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -182,9 +183,105 @@ class ForwardRow:
 
 
 @dataclass
+class ForwardBlock:
+    """One class's rows of mergeable state as columns (the forward's
+    counterpart of ``MetricFrame``): the series' ``RowMeta`` in row
+    order and the class's values as arrays over them.  A block owns
+    its arrays: the forward is encoded on the pool after
+    ``snap.release()`` and may outlive its cycle, so nothing here may
+    alias a plane the snapshot hands back."""
+    kind: str  # counter | gauge | histo | set
+    metas: list[RowMeta]
+    values: np.ndarray | None = None  # f64[n], counter | gauge
+    stats: np.ndarray | None = None  # f32[n,5]
+    # f32[n,C]; or, where rows differ in width (the tiered path's
+    # compact rows), f32[total] with row i at [row_at[i]:row_at[i+1]]
+    means: np.ndarray | None = None
+    weights: np.ndarray | None = None
+    row_at: np.ndarray | None = None  # i64[n+1], ragged digests only
+    regs: np.ndarray | None = None  # u8[n,M]
+
+    def __len__(self) -> int:
+        return len(self.metas)
+
+    @classmethod
+    def ragged_histo(cls, metas: list[RowMeta], stats: np.ndarray,
+                     means: list, weights: list) -> ForwardBlock:
+        """A histogram block from one (means, weights) pair of arrays
+        a row, of any widths: both planes laid flat, once."""
+        row_at = np.zeros(len(metas) + 1, np.int64)
+        np.cumsum([len(w) for w in weights], out=row_at[1:])
+        return cls("histo", metas, stats=stats,
+                   means=np.concatenate(means),
+                   weights=np.concatenate(weights), row_at=row_at)
+
+    def digest_planes(self) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """(means, weights, row_at) with both planes flat and row i's
+        slots at ``[row_at[i]:row_at[i+1]]``, whichever way the block
+        holds them."""
+        if self.row_at is not None:
+            return self.means, self.weights, self.row_at
+        n, width = self.means.shape
+        return (self.means.reshape(-1), self.weights.reshape(-1),
+                np.arange(n + 1, dtype=np.int64) * width)
+
+    def rows(self):
+        """The block's ``ForwardRow``s, built on demand: views into
+        the block's arrays, for the row-wise consumers (shard router,
+        arc handoff, HTTP forward, tests)."""
+        kind, metas = self.kind, self.metas
+        if kind == "histo":
+            means, weights, at = self.means, self.weights, self.row_at
+            for i, meta in enumerate(metas):
+                sl = i if at is None else slice(at[i], at[i + 1])
+                yield ForwardRow(meta, kind, stats=self.stats[i],
+                                 means=means[sl], weights=weights[sl])
+        elif kind == "set":
+            for meta, regs in zip(metas, self.regs):
+                yield ForwardRow(meta, kind, regs=regs)
+        else:
+            for meta, v in zip(metas, self.values.tolist()):
+                yield ForwardRow(meta, kind, value=v)
+
+
+class ForwardList(Sequence):
+    """``FlushResult.forward``: a flush's forwardable state in wire
+    order, as ``ForwardBlock``s (the columnar flush appends one per
+    class) and loose ``ForwardRow``s (the per-row reference loops).
+    It reads as the list of rows it used to be, rows built on demand:
+    ``len()`` counts rows, iteration yields ``ForwardRow``s, an empty
+    one is false and equals ``[]``.  The encoder takes ``parts``."""
+
+    def __init__(self, parts=()):
+        self.parts: list[ForwardBlock | ForwardRow] = list(parts)
+
+    def append(self, part: ForwardBlock | ForwardRow) -> None:
+        self.parts.append(part)
+
+    def __len__(self) -> int:
+        return sum(len(p) if isinstance(p, ForwardBlock) else 1
+                   for p in self.parts)
+
+    def __iter__(self):
+        for p in self.parts:
+            if isinstance(p, ForwardBlock):
+                yield from p.rows()
+            else:
+                yield p
+
+    def __getitem__(self, i):
+        return list(self)[i]
+
+    def __eq__(self, other) -> bool:
+        if not isinstance(other, Sequence):
+            return NotImplemented
+        return list(self) == list(other)
+
+
+@dataclass
 class FlushResult:
     metrics: list[im.InterMetric] = field(default_factory=list)
-    forward: list[ForwardRow] = field(default_factory=list)
+    forward: ForwardList = field(default_factory=ForwardList)
     tally: dict[str, int] = field(default_factory=dict)
     # columnar emit: when the flush ran with ``retain_frame=True`` the
     # emitted aggregates stay in ``frame`` and ``metrics`` holds only
@@ -884,9 +981,9 @@ class Flusher:
             fwd = ho | (sc == _SCOPE_GLOBAL)
         else:
             fwd = ho
-        for r, v in zip(rows[fwd], v64[fwd]):
-            res.forward.append(ForwardRow(metas[r], kind,
-                                          value=float(v)))
+        if fwd.any():
+            res.forward.append(ForwardBlock(
+                kind, [metas[r] for r in rows[fwd]], values=v64[fwd]))
         emit = ~fwd
         frame.add_block(metas, rows[emit], v64[emit],
                         type_code=type_code)
@@ -927,12 +1024,23 @@ class Flusher:
         all_pcts = pre["all_pcts"]
 
         # forward rows first, in row order (same interleave-free
-        # order the legacy loop produces per class)
-        for pos, r in enumerate(pre["histo_fwd"]):
-            res.forward.append(ForwardRow(
-                metas[r], "histo", stats=stats[r].copy(),
-                means=pre["fwd_means"][pos].copy(),
-                weights=pre["fwd_weights"][pos].copy()))
+        # order the legacy loop produces per class).  The gathers'
+        # readbacks and ``stats[fwd]`` are private to this flush, so
+        # the block takes them as they are, less ``_pad_idx``'s tail
+        fwd = pre["histo_fwd"]
+        if fwd:
+            fmetas = [metas[r] for r in fwd]
+            if snap.tiers is not None:
+                # one array a row, of unequal width, some of them the
+                # compact store's own
+                res.forward.append(ForwardBlock.ragged_histo(
+                    fmetas, stats[fwd], pre["fwd_means"],
+                    pre["fwd_weights"]))
+            else:
+                res.forward.append(ForwardBlock(
+                    "histo", fmetas, stats=stats[fwd],
+                    means=pre["fwd_means"][:len(fwd)],
+                    weights=pre["fwd_weights"][:len(fwd)]))
 
         sc = _scope_codes(metas, rows)
         # routing counts mirror the legacy loop: on a local node every
@@ -1035,9 +1143,16 @@ class Flusher:
         metas = snap.set_meta
         ests = pre.get("ests")
         fwd = pre.get("set_fwd", ())
-        for pos, r in enumerate(fwd):
-            res.forward.append(ForwardRow(
-                metas[r], "set", regs=pre["fwd_regs"][pos].copy()))
+        if fwd:
+            # a gather of the host plane or a readback of the device
+            # gather: a private copy either way (the plane itself goes
+            # back to the table's pool at ``snap.release()``); the
+            # tiered path hands one fresh array a row
+            regs = pre["fwd_regs"]
+            regs = (np.stack(regs) if snap.tiers is not None
+                    else regs[:len(fwd)])
+            res.forward.append(ForwardBlock(
+                "set", [metas[r] for r in fwd], regs=regs))
         in_fwd = np.zeros(len(rows), dtype=bool)
         if fwd:
             in_fwd = np.isin(rows, np.asarray(fwd))

@@ -17,7 +17,6 @@ the process.
 
 from __future__ import annotations
 
-import collections
 import http.server
 import json
 import logging
@@ -2347,8 +2346,9 @@ class Server:
         if tsnap is not None:
             self.ledger.credit_tiers(led, tsnap.movements)
             self._last_plane_bytes = tsnap.plane_bytes
-        # the interval's reads are done (forward rows hold copies);
-        # recycle the host set plane into the table's reuse pool
+        # the interval's reads are done (the forward blocks own their
+        # arrays); recycle the host set plane into the table's reuse
+        # pool
         snap.release()
         self.last_flush = time.monotonic()
         self.bump("flushes")
@@ -3122,17 +3122,16 @@ class Server:
                 self.config.forward_address,
                 compression=float(self.config.tpu_compression),
                 credentials=self._forward_grpc_credentials())
-        kinds = collections.Counter(r.kind for r in rows)
+        counts: dict = {}
         with cyc.stage("forward.encode", parent=span) as sp:
             body, centroids = encode_metric_list(
-                rows, float(self.config.tpu_compression))
+                rows, float(self.config.tpu_compression), counts)
             sp.add_tag("rows", str(len(rows)))
             sp.add_tag("bytes", str(len(body)))
             sp.add_tag("centroids", str(centroids))
-            sp.add_tag("rows_histo", str(kinds["histo"]))
-            sp.add_tag("rows_sets", str(kinds["set"]))
-            sp.add_tag("rows_scalars",
-                       str(kinds["counter"] + kinds["gauge"]))
+            for key, n in counts.items():
+                sp.add_tag(key, str(n))
+        cyc.note_forward_encode(counts)
         try:
             with cyc.stage("forward.send", parent=span) as sp:
                 sp.add_tag("bytes", str(len(body)))

@@ -332,6 +332,14 @@ class RowMeta:
     # row is known to the fast-path key index; 0 for rows only ever
     # touched by the slow path
     key_hash: int = 0
+    # the series' identity on the forward wire, (bytes before the
+    # value, bytes after it), written by the encoder at the row's
+    # first forward and reused every interval after
+    # (grpc_forward._wire_ident).  It lives and dies with the row: a
+    # compaction that drops the series drops its RowMeta, and a row
+    # number that passes to another series gets that series' own
+    wire_ident: tuple[bytes, bytes] | None = field(
+        default=None, repr=False, compare=False)
 
 
 class _ClassIndex:

@@ -24,7 +24,7 @@ from concurrent import futures
 
 import numpy as np
 
-from veneur_tpu.core.flusher import ForwardRow
+from veneur_tpu.core.flusher import ForwardBlock, ForwardRow
 from veneur_tpu.core.table import MetricTable
 from veneur_tpu.forward import hll_codec
 from veneur_tpu.forward.gen import forward_pb2, metric_pb2, tdigest_pb2
@@ -159,7 +159,7 @@ _PB_TO_SCOPE = {v: k for k, v in _SCOPE_TO_PB.items()}
 
 
 # ----------------------------------------------------------------------
-# ForwardRow -> MetricList wire
+# ForwardBlock | ForwardRow -> MetricList wire
 
 # One live centroid on the wire: MergingDigestData field 1, a Centroid
 # of 18 bytes holding mean (field 1) and weight (field 2) as doubles.
@@ -195,35 +195,106 @@ def _field(tag: bytes, payload: bytes) -> bytes:
     return tag + _varint(len(payload)) + payload
 
 
+def _varints(n: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """Each (non-negative, under 2**35) value's varint, left-aligned
+    in a row of five bytes, and which of the five it takes."""
+    sh = n.astype(np.int64)[:, None] >> (7 * np.arange(5))
+    more = sh >> 7 != 0
+    by = (sh & 0x7F | more << 7).astype(np.uint8)
+    keep = np.ones(by.shape, bool)
+    keep[:, 1:] = more[:, :-1]
+    return by, keep
+
+
+def _squeeze(by: np.ndarray, keep: np.ndarray
+             ) -> tuple[np.ndarray, np.ndarray]:
+    """The kept bytes of ``by`` (one record a row), laid end to end,
+    and the offset of each row's record in them (one more offset than
+    rows)."""
+    at = np.zeros(len(by) + 1, np.int64)
+    np.cumsum(keep.sum(axis=1), out=at[1:])
+    return by[keep], at
+
+
+def _lay(*cols) -> tuple[bytes, list[int]]:
+    """One short record a row: ``cols`` are constant bytes (a tag) and
+    ``_varints`` results, in wire order.  Bytes and offsets as
+    ``_squeeze`` gives them."""
+    n = next(len(c[0]) for c in cols if not isinstance(c, int))
+    by, at = _squeeze(
+        np.concatenate([np.full((n, 1), c, np.uint8)
+                        if isinstance(c, int) else c[0]
+                        for c in cols], axis=1),
+        np.concatenate([np.ones((n, 1), bool)
+                        if isinstance(c, int) else c[1]
+                        for c in cols], axis=1))
+    return by.tobytes(), at.tolist()
+
+
 def _pack(rec: np.ndarray, doubles: tuple[str, ...]
-          ) -> tuple[bytes, np.ndarray]:
-    """The bytes of wire records ``rec`` and the offset of each record
-    in them (one more offset than records).  Like protobuf's proto3
-    serializer, a field of ``doubles`` whose bits are all zero is left
-    off the wire, its tag byte with it."""
+          ) -> tuple[np.ndarray, np.ndarray]:
+    """The bytes of wire records ``rec`` (as a u8 array) and the
+    offset of each record in them (one more offset than records).
+    Like protobuf's proto3 serializer, a field of ``doubles`` whose
+    bits are all zero is left off the wire, its tag byte with it."""
     present = [rec[f].view("<u8") != 0 for f in doubles]
+    by = rec.view(np.uint8).reshape(len(rec), rec.itemsize)
     if all(p.all() for p in present):
-        return rec.tobytes(), np.arange(len(rec) + 1) * rec.itemsize
-    by = rec.view(np.uint8).reshape(len(rec), -1)
+        return by.reshape(-1), np.arange(len(rec) + 1) * rec.itemsize
     keep = np.ones(by.shape, bool)
     for f, p in zip(doubles, present):
         at = rec.dtype.fields[f][1]
         keep[:, at - 1:at + 8] = p[:, None]
-    return by[keep].tobytes(), np.concatenate(
-        ([0], np.cumsum(keep.sum(axis=1))))
+    return _squeeze(by, keep)
 
 
-def _encode_digests(rows: list[ForwardRow],
-                    compression: float) -> tuple[list[bytes], int]:
-    """The MergingDigestData bodies of ``rows`` (all histograms) and
-    the count of live centroids in them, with no Python-level work per
-    centroid: the rows' means and weights are concatenated once (so
-    rows of unequal width need no second path), the live mask is taken
-    over the whole flush, and every live centroid is laid into one
-    buffer of wire records; a row's ``main_centroids`` is a slice of
-    it."""
-    weights = np.concatenate([np.asarray(r.weights) for r in rows])
-    means = np.concatenate([np.asarray(r.means) for r in rows])
+def _wire_ident(meta) -> tuple[bytes, bytes]:
+    """A series' identity as the wire holds it: the Metric's bytes
+    before its value (name, tags, type) and after it (scope).  Kept
+    on the ``RowMeta``, so a series is encoded once and not once an
+    interval."""
+    m = [_field(b"\x0a", meta.name.encode())] if meta.name else []
+    m += [_field(b"\x12", t.encode()) for t in meta.tags]
+    mtype = _TYPE_TO_PB[meta.type]
+    if mtype:
+        m.append(b"\x18" + _VARINT_1[mtype])
+    scope = _SCOPE_TO_PB[meta.scope]
+    meta.wire_ident = ident = (
+        b"".join(m), b"\x48" + _VARINT_1[scope] if scope else b"")
+    return ident
+
+
+def _encode_scalars(blk: ForwardBlock, idents: list,
+                    out: list) -> None:
+    """The Metrics of a counter or gauge block, appended to ``out``."""
+    v = np.asarray(blk.values, np.float64)
+    if blk.kind == "counter":
+        # the reference wire type is int64 (metric.proto CounterValue)
+        bad = ~(np.isfinite(v) & (v >= -2.0 ** 63) & (v < 2.0 ** 63))
+        if bad.any():
+            name = blk.metas[int(np.flatnonzero(bad)[0])].name
+            raise ValueError(f"counter {name} out of int64")
+        vals = [b"\x08" + _varint(x & _U64) if x else b""
+                for x in np.rint(v).astype(np.int64).tolist()]
+        tag = b"\x2a"
+    else:
+        vals = [b"\x09" + x if any(x) else b""
+                for x in map(_F64.pack, v.tolist())]
+        tag = b"\x32"
+    for (head, scope), val in zip(idents, vals):
+        m = b"".join((head, tag, _VARINT_1[len(val)], val, scope))
+        out += (b"\x0a", _varint(len(m)), m)
+
+
+def _encode_digests(blk: ForwardBlock, compression: float,
+                    idents: list, out: list) -> int:
+    """The Metrics of a histogram block, appended to ``out``, and the
+    count of live centroids in them, with no Python-level work per
+    centroid: the live mask is taken over the whole block (a matrix,
+    or flat planes where rows differ in width), every live centroid is
+    laid into one buffer of wire records, and a row's
+    ``main_centroids`` goes to the wire as a view of it."""
+    means, weights, row_at = blk.digest_planes()
     live = np.flatnonzero(weights > 0)
     cent = np.empty(len(live), _CENTROID)
     cent["tag"], cent["mean_tag"], cent["weight_tag"] = 0x0A, 0x09, 0x11
@@ -231,13 +302,11 @@ def _encode_digests(rows: list[ForwardRow],
     cent["weight"] = weights[live]
     cent["len"] = np.where(cent["mean"].view("<u8") != 0, 18, 9)
     cents, cent_at = _pack(cent, ("mean",))
-    # the record at which each row starts, and the last one ends
-    row_at = np.concatenate(
-        ([0], np.cumsum([len(r.weights) for r in rows])))
-    cent_at = cent_at[np.searchsorted(live, row_at)].tolist()
+    # the byte at which each row's records start, and the last one ends
+    cent_at = cent_at[np.searchsorted(live, row_at)]
 
-    stats = np.stack([np.asarray(r.stats) for r in rows])
-    tail = np.empty(len(rows), _DIGEST_TAIL)
+    stats = np.asarray(blk.stats)
+    tail = np.empty(len(blk), _DIGEST_TAIL)
     tail["t2"], tail["t3"], tail["t4"], tail["t5"] = (0x11, 0x19, 0x21,
                                                       0x29)
     tail["compression"] = compression
@@ -245,57 +314,135 @@ def _encode_digests(rows: list[ForwardRow],
     tail["max"] = stats[:, segment.STAT_MAX]
     tail["rsum"] = stats[:, segment.STAT_RSUM]
     tails, tail_at = _pack(tail, ("compression", "min", "max", "rsum"))
-    tail_at = tail_at.tolist()
-    return ([cents[cent_at[i]:cent_at[i + 1]]
-             + tails[tail_at[i]:tail_at[i + 1]]
-             for i in range(len(rows))], len(live))
+
+    # Metric > HistogramValue (field 7) > MergingDigestData (field 1):
+    # each length from the one inside it, every row's at once
+    heads, scopes = zip(*idents)
+    dlen = np.diff(cent_at) + np.diff(tail_at)
+    dvar = _varints(dlen)
+    hlen = 1 + dvar[1].sum(axis=1) + dlen
+    hvar = _varints(hlen)
+    mlen = (np.fromiter(map(len, heads), np.int64, len(heads))
+            + np.fromiter(map(len, scopes), np.int64, len(heads))
+            + 1 + hvar[1].sum(axis=1) + hlen)
+    pre, pre_at = _lay(0x0A, _varints(mlen))
+    mid, mid_at = _lay(0x3A, hvar, 0x0A, dvar)
+    cents, tails = memoryview(cents), tails.tobytes()
+    cent_at, tail_at = cent_at.tolist(), tail_at.tolist()
+    for i, (head, scope) in enumerate(idents):
+        j = i + 1
+        out += (pre[pre_at[i]:pre_at[j]], head,
+                mid[mid_at[i]:mid_at[j]], cents[cent_at[i]:cent_at[j]],
+                tails[tail_at[i]:tail_at[j]], scope)
+    return len(live)
 
 
-def encode_metric_list(rows: list[ForwardRow],
-                       compression: float = 100.0) -> tuple[bytes, int]:
+def _encode_sets(blk: ForwardBlock, idents: list, out: list) -> None:
+    """The Metrics of a set block, appended to ``out``: every row's
+    dense sketch from one pass over the block's registers."""
+    sketches = hll_codec.encode_dense_rows(blk.regs)
+    # SetValue (field 8) > hyper_log_log (field 1), the same lengths
+    # for every row
+    slen = sketches.shape[1]
+    value = (b"\x42" + _varint(1 + len(_varint(slen)) + slen)
+             + b"\x0a" + _varint(slen))
+    for (head, scope), sketch in zip(idents, sketches):
+        out += (b"".join((b"\x0a", _varint(len(head) + len(value) + slen
+                                           + len(scope)), head, value)),
+                sketch, scope)
+
+
+def _block_of(run: list[ForwardRow]) -> ForwardBlock:
+    """Loose rows of one kind as a block: what arrives row by row
+    (checkpoint replay, a shard's batch, the per-row reference flush)
+    is encoded like everything else."""
+    kind, metas = run[0].kind, [r.meta for r in run]
+    if kind in ("counter", "gauge"):
+        return ForwardBlock(kind, metas, values=np.asarray(
+            [r.value for r in run], np.float64))
+    if kind == "histo":
+        # flat planes, so rows of unequal width need no second path
+        return ForwardBlock.ragged_histo(
+            metas, np.stack([np.asarray(r.stats) for r in run]),
+            [np.asarray(r.means) for r in run],
+            [np.asarray(r.weights) for r in run])
+    if kind == "set":
+        for r in run:
+            if np.shape(r.regs) != (hll_codec.M,):
+                raise hll_codec.HLLCodecError(
+                    f"bad register shape {np.shape(r.regs)}")
+        return ForwardBlock(kind, metas, regs=np.stack(
+            [np.asarray(r.regs, np.uint8) for r in run]))
+    return ForwardBlock(kind, metas)  # no such kind: the encoder's to say
+
+
+def _as_blocks(rows) -> tuple[list[ForwardBlock], int]:
+    """``rows`` (a ``ForwardList``, or any sequence of blocks and
+    rows) as blocks in the order given, each run of loose rows of one
+    kind grouped into a block; and the count of rows that came
+    loose."""
+    blocks: list[ForwardBlock] = []
+    run: list[ForwardRow] = []
+    loose = 0
+    for part in getattr(rows, "parts", rows):
+        is_row = not isinstance(part, ForwardBlock)
+        if run and not (is_row and part.kind == run[0].kind):
+            blocks.append(_block_of(run))
+            run = []
+        if is_row:
+            run.append(part)
+            loose += 1
+        else:
+            blocks.append(part)
+    if run:
+        blocks.append(_block_of(run))
+    return blocks, loose
+
+
+def encode_metric_list(rows, compression: float = 100.0,
+                       counts: dict | None = None) -> tuple[bytes, int]:
     """The forward wire of ``rows``: a serialized ``MetricList`` (the
     sending half of worker.go:181 ForwardableMetrics -> metricpb), and
-    the count of live centroids in it.  Written by hand from the rows'
-    arrays, byte for byte what protobuf's serializer gives for the
-    same message.  ``compression`` is the table's configured digest
+    the count of live centroids in it.  Written by hand from the
+    blocks' arrays, byte for byte what protobuf's serializer gives for
+    the same message, metrics in the order given.  ``rows`` is a
+    flush's ``ForwardList`` or any sequence of ``ForwardBlock``s and
+    ``ForwardRow``s.  ``compression`` is the table's configured digest
     compression (a Go global sizes its MergingDigest from this
-    field)."""
-    histos = [r for r in rows if r.kind == "histo"]
-    digests, centroids = (_encode_digests(histos, float(compression))
-                          if histos else ((), 0))
-    digests = iter(digests)
-    out = []
-    for r in rows:
-        meta = r.meta
-        m = [_field(b"\x0a", meta.name.encode())] if meta.name else []
-        m += [_field(b"\x12", t.encode()) for t in meta.tags]
-        mtype = _TYPE_TO_PB[meta.type]
-        if mtype:
-            m.append(b"\x18" + _VARINT_1[mtype])
-        if r.kind == "counter":
-            # the reference wire type is int64 (metric.proto
-            # CounterValue)
-            v = int(round(r.value))
-            if not -(1 << 63) <= v < (1 << 63):
-                raise ValueError(f"counter {meta.name} out of int64")
-            m.append(_field(
-                b"\x2a", b"\x08" + _varint(v & _U64) if v else b""))
-        elif r.kind == "gauge":
-            v = _F64.pack(float(r.value))
-            m.append(_field(b"\x32", b"\x09" + v if any(v) else b""))
-        elif r.kind == "histo":
-            m.append(_field(b"\x3a", _field(b"\x0a", next(digests))))
-        elif r.kind == "set":
-            regs = hll_codec.encode_dense(r.regs)
-            m.append(_field(
-                b"\x42", _field(b"\x0a", regs) if regs else b""))
+    field).  ``counts``, where given, takes what the encode worked
+    on: ``rows_block`` and ``rows_loose`` (rows that came in blocks,
+    and row by row), ``ident_cached`` (series whose identity bytes
+    were there from an earlier encode), ``rows_histo``, ``rows_sets``
+    and ``rows_scalars``."""
+    blocks, loose = _as_blocks(rows)
+    out: list = []
+    centroids = cached = 0
+    by_kind = dict.fromkeys(("histo", "set", "counter", "gauge"), 0)
+    for blk in blocks:
+        if blk.kind not in by_kind:
+            raise ValueError(f"unknown forward kind {blk.kind}")
+        by_kind[blk.kind] += len(blk)
+        idents = [m.wire_ident for m in blk.metas]
+        for i, ident in enumerate(idents):
+            if ident is None:
+                idents[i] = _wire_ident(blk.metas[i])
+            else:
+                cached += 1
+        if not idents:
+            continue
+        if blk.kind == "histo":
+            centroids += _encode_digests(blk, float(compression),
+                                         idents, out)
+        elif blk.kind == "set":
+            _encode_sets(blk, idents, out)
         else:
-            raise ValueError(f"unknown forward kind {r.kind}")
-        scope = _SCOPE_TO_PB[meta.scope]
-        if scope:
-            m.append(b"\x48" + _VARINT_1[scope])
-        m = b"".join(m)
-        out += (b"\x0a", _varint(len(m)), m)
+            _encode_scalars(blk, idents, out)
+    if counts is not None:
+        n = sum(by_kind.values())
+        counts.update(
+            rows_block=n - loose, rows_loose=loose, ident_cached=cached,
+            rows_histo=by_kind["histo"], rows_sets=by_kind["set"],
+            rows_scalars=by_kind["counter"] + by_kind["gauge"])
     return b"".join(out), centroids
 
 

@@ -35,6 +35,12 @@ class FlushRecord:
     metrics_emitted: int = 0
     forward_rows: int = 0
     forward_bytes: int = 0  # serialized forward bodies shipped
+    # the single-global forward's encode: rows it took from the
+    # flush's column blocks, rows that came one by one, and series
+    # whose identity bytes an earlier interval had left on the row
+    rows_block: int = 0
+    rows_loose: int = 0
+    ident_cached: int = 0
     # import wires this server folded since its previous cycle; their
     # handler durations are in ``stages`` under ``import[.<step>]``
     imports: int = 0
@@ -59,6 +65,9 @@ class FlushRecord:
                 "metrics_emitted": self.metrics_emitted,
                 "forward_rows": self.forward_rows,
                 "forward_bytes": self.forward_bytes,
+                "rows_block": self.rows_block,
+                "rows_loose": self.rows_loose,
+                "ident_cached": self.ident_cached,
                 "imports": self.imports,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,

@@ -19,7 +19,13 @@ stage before the dot:
       +- flush.sink_flush   per-sink fan-out + interval-budget wait
       +- flush.sink.<name>  one sink's encode + delivery (its worker)
       +- flush.forward      upstream ship (local tier only)
-           +- flush.forward.encode  rows -> MetricList bytes
+           +- flush.forward.encode  rows -> MetricList bytes; tags
+           |                        ``rows_block`` (rows encoded from
+           |                        the flush's column blocks),
+           |                        ``rows_loose`` (rows that came one
+           |                        by one), ``ident_cached`` (series
+           |                        whose identity bytes were kept
+           |                        from an earlier interval)
            +- flush.forward.send    the unary call, call to return;
            |    |                   its ids ride the wire
            |    +- import             the receiving tier's handler,
@@ -220,6 +226,14 @@ class FlushCycle:
     def add_forward_bytes(self, nbytes: int) -> None:
         with self._lock:
             self.record.forward_bytes += int(nbytes)
+
+    def note_forward_encode(self, counts: dict) -> None:
+        """What ``encode_metric_list`` said it worked on, onto the
+        cycle's record."""
+        with self._lock:
+            self.record.rows_block += counts["rows_block"]
+            self.record.rows_loose += counts["rows_loose"]
+            self.record.ident_cached += counts["ident_cached"]
 
 
 class ImportSpan:
