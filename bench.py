@@ -853,122 +853,6 @@ def global_merge_import() -> dict:
     return out
 
 
-
-def bench_flush_wide_cardinality() -> dict:
-    """Config 5: the flush->emit path at wide cardinality — >=100k
-    touched series (counters + timers + sets, mixed scopes and tags)
-    flushed from ONE snapshot, columnar MetricFrame assembly vs the
-    legacy per-row emit loop.  Ingest is untimed setup; the headline
-    is emitted metrics per second of host_emit (the stage the
-    columnar path rewrites), with the end-to-end flush wall and the
-    d2h split (dispatch + device_wait) reported alongside so the
-    emit win can't hide a readback regression.  Both paths flush the
-    SAME snapshot and must produce the same metric count — the
-    bit-level parity oracle lives in tests/test_columnar_emit.py."""
-    from contextlib import contextmanager
-    from veneur_tpu.core.flusher import Flusher
-    from veneur_tpu.protocol import columnar
-
-    n_counters = max(100, 70_000 // SCALE)
-    n_histos = max(50, 25_000 // SCALE)
-    n_sets = max(10, 5_000 // SCALE)
-    lines = []
-    for i in range(n_counters):
-        lines.append(
-            f"wide.req.{i % 127}:{1 + i % 9}|c"
-            f"|#route:r{i % 997},shard:s{i}".encode())
-    for i in range(n_histos):
-        # 3 samples/series: enough to exercise min/max/avg spread
-        for v in (3.5, 41.0, 87.25):
-            lines.append(
-                f"wide.lat.{i % 63}:{v + i % 11}|ms"
-                f"|#route:r{i % 997},shard:h{i}".encode())
-    for i in range(n_sets):
-        lines.append(f"wide.uniq.{i % 31}:m{i % 17}|s"
-                     f"|#shard:u{i}".encode())
-    chunk = 1 << 20
-    bufs = [b"\n".join(lines[i:i + chunk])
-            for i in range(0, len(lines), chunk)]
-
-    parser = columnar.ColumnarParser()
-    table = _mk_table(counter_rows=1 << 18, gauge_rows=64,
-                      histo_rows=1 << 16, set_rows=1 << 13)
-    _ingest_interval(table, bufs, parser)
-    snap = table.swap()
-    _block(table)
-    touched = (int(snap.counter_touched[:len(snap.counter_meta)].sum())
-               + int(snap.histo_touched[:len(snap.histo_meta)].sum())
-               + int(snap.set_touched[:len(snap.set_meta)].sum()))
-
-    class _RecCycle:
-        """Stage recorder quacking like observe.FlushCycle: the
-        flusher's own spans (dispatch / device_wait / host_emit) ARE
-        the measurement, so the bench attributes exactly what the
-        server traces."""
-
-        def __init__(self):
-            self.stages: dict = {}
-
-        @contextmanager
-        def stage(self, name, alias=None):
-            t0 = time.perf_counter()
-            try:
-                yield self
-            finally:
-                self.stages[name] = (self.stages.get(name, 0.0)
-                                     + time.perf_counter() - t0)
-
-        def add_tag(self, *a) -> None:
-            pass
-
-        def add_readback(self, n) -> None:
-            pass
-
-    kw = dict(is_local=False, percentiles=(0.5, 0.9, 0.99),
-              aggregates=("min", "max", "sum", "avg", "count"),
-              hostname="bench-host")
-
-    def timed(flusher, retain):
-        # pass 0 is cold (readout compiles); medians over warm passes
-        walls, emits, d2hs = [], [], []
-        res = None
-        for i in range(BENCH_PASSES + 1):
-            cyc = _RecCycle()
-            t0 = time.perf_counter()
-            res = flusher.flush(snap, now=1_700_000_000, cycle=cyc,
-                                retain_frame=retain)
-            wall = time.perf_counter() - t0
-            if i == 0:
-                continue
-            walls.append(wall)
-            emits.append(cyc.stages.get("host_emit", wall))
-            d2hs.append(cyc.stages.get("dispatch", 0.0)
-                        + cyc.stages.get("device_wait", 0.0))
-        return (res, float(np.median(walls)), float(np.median(emits)),
-                float(np.median(d2hs)))
-
-    res_l, wall_l, emit_l, d2h_l = timed(
-        Flusher(columnar=False, **kw), False)
-    res_c, wall_c, emit_c, d2h_c = timed(
-        Flusher(columnar=True, **kw), True)
-    n_emit = res_c.metric_count()
-    assert n_emit == len(res_l.metrics), (n_emit, len(res_l.metrics))
-    return {
-        "touched_series": touched,
-        "emitted_metrics": n_emit,
-        "flush_wall_s": round(wall_c, 4),
-        "host_emit_s": round(emit_c, 4),
-        "d2h_s": round(d2h_c, 4),
-        "emitted_metrics_per_sec": round(n_emit / emit_c, 1),
-        "legacy_flush_wall_s": round(wall_l, 4),
-        "legacy_host_emit_s": round(emit_l, 4),
-        "legacy_d2h_s": round(d2h_l, 4),
-        "legacy_emitted_metrics_per_sec": round(n_emit / emit_l, 1),
-        "speedup_vs_legacy": round(emit_l / emit_c, 2),
-        "passes": BENCH_PASSES,
-    }
-
-
 def _rss_now_kb() -> int:
     # current (not peak) RSS: ru_maxrss is a lifetime high-water
     # mark and cannot measure growth during a run
@@ -4573,7 +4457,6 @@ CONFIGS = (
     ("2_timers_10k_series", bench_timers),
     ("3_sets_1m_uniques", bench_sets),
     ("4_global_merge_64_locals", bench_global_merge),
-    ("5_flush_wide_cardinality", bench_flush_wide_cardinality),
 )
 
 CKPT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -4723,7 +4606,7 @@ def _summary_line(out: dict) -> str:
             continue
         row: dict = {}
         for key in ("samples_per_sec", "items_per_sec",
-                    "packets_per_sec", "emitted_metrics_per_sec"):
+                    "packets_per_sec"):
             if v.get(key) is not None:
                 row["rate"] = v[key]
                 break

@@ -1,7 +1,7 @@
-"""Golden parity suite for the columnar emit path: the MetricFrame
-assembly (VENEUR_TPU_COLUMNAR_EMIT) must produce a bit-identical
-metric set to the legacy per-row loop — names, values, tags, types,
-hostnames — order-insensitive, across scopes x aggregates x
+"""Golden parity suite for the flush's emit: the MetricFrame
+assembly must produce a bit-identical metric set to the per-row
+reference loops (tests/flush_reference.py) — names, values, tags,
+types, hostnames — order-insensitive, across scopes x aggregates x
 percentile-naming modes, with exact forward-row agreement.  Plus the
 frame-native sink encoders (datadog/signalfx/prometheus) against
 their legacy dict encoders, and the satellite fixes (tally slicing,
@@ -13,6 +13,7 @@ import zlib
 import numpy as np
 import pytest
 
+from tests.flush_reference import RowFlusher
 from veneur_tpu.core.flusher import Flusher
 from veneur_tpu.core.table import MetricTable, TableConfig
 from veneur_tpu.protocol import dogstatsd as dsd
@@ -65,10 +66,10 @@ def fwd_key(f):
 
 
 def flush_pair(snap, **kw):
-    """Flush the SAME snapshot through the legacy loop and the
-    columnar assembly (flush does not mutate the snapshot)."""
-    legacy = Flusher(columnar=False, **kw).flush(snap, now=1234)
-    col = Flusher(columnar=True, **kw).flush(snap, now=1234)
+    """Flush the SAME snapshot through the reference loops and the
+    program's flush (flush does not mutate the snapshot)."""
+    legacy = RowFlusher(**kw).flush(snap, now=1234)
+    col = Flusher(**kw).flush(snap, now=1234)
     return legacy, col
 
 
@@ -90,6 +91,7 @@ def assert_parity(legacy, col):
                 np.testing.assert_array_equal(np.asarray(av),
                                               np.asarray(bv))
     assert legacy.tally == col.tally
+    assert legacy.row_accounting == col.row_accounting
 
 
 @pytest.mark.parametrize("is_local", [False, True])
@@ -133,40 +135,44 @@ def test_columnar_parity_quantile_interpolation_reference():
 
 
 def test_retained_frame_matches_materialized_list():
+    """The one shape: nothing is materialised until ``metrics`` is
+    read; what is read is the reference's list."""
     snap = mixed_table().swap()
-    fl = Flusher(is_local=True, aggregates=ALL_AGGS,
-                 percentiles=(0.5,), hostname="h")
-    res = fl.flush(snap, now=99, retain_frame=True)
-    assert res.frame is not None and not res.metrics
-    direct = fl.flush(snap, now=99)
-    assert direct.frame is None
-    assert (sorted(metric_key(m) for m in res.all_metrics()) ==
-            sorted(metric_key(m) for m in direct.metrics))
+    kw = dict(is_local=True, aggregates=ALL_AGGS, percentiles=(0.5,),
+              hostname="h")
+    res = Flusher(**kw).flush(snap, now=99)
+    assert len(res.frame) and res.frame._materialized is None
+    direct = RowFlusher(**kw).flush(snap, now=99)
     assert res.metric_count() == len(direct.metrics)
+    assert res.frame._materialized is None  # counting builds nothing
+    assert (sorted(metric_key(m) for m in res.metrics) ==
+            sorted(metric_key(m) for m in direct.metrics))
+    assert res.frame._materialized is not None
+    assert res.metrics is res.metrics  # the frame's cache, no copy
 
 
 # ---------------------------------------------------------------------
 # satellite fixes
 
 
-@pytest.mark.parametrize("columnar", [False, True])
-def test_zero_sum_histogram_still_emits_sum_and_avg(columnar):
+@pytest.mark.parametrize("flusher", [RowFlusher, Flusher])
+def test_zero_sum_histogram_still_emits_sum_and_avg(flusher):
     """A locally-sampled histogram whose values sum to exactly 0 used
     to lose .sum and .avg to the st_sum != 0 gate; the reference gates
     on LocalWeight (samplers.go:592-607)."""
     t = MetricTable(TableConfig(histo_rows=16))
     t.ingest(dsd.parse_metric(b"zs:-5|ms"))
     t.ingest(dsd.parse_metric(b"zs:5|ms"))
-    res = Flusher(is_local=True, aggregates=("sum", "avg", "count"),
-                  columnar=columnar).flush(t.swap())
+    res = flusher(is_local=True,
+                  aggregates=("sum", "avg", "count")).flush(t.swap())
     m = {x.name: x for x in res.metrics}
     assert m["zs.sum"].value == 0.0
     assert m["zs.avg"].value == 0.0
     assert m["zs.count"].value == 2.0
 
 
-@pytest.mark.parametrize("columnar", [False, True])
-def test_tally_slices_stale_touch_bits(columnar):
+@pytest.mark.parametrize("flusher", [RowFlusher, Flusher])
+def test_tally_slices_stale_touch_bits(flusher):
     """Touch bits past len(meta) (a stale plane) must not inflate the
     tallies — slice before summing."""
     t = MetricTable(TableConfig(counter_rows=64, gauge_rows=64,
@@ -178,7 +184,7 @@ def test_tally_slices_stale_touch_bits(columnar):
     snap.gauge_touched[len(snap.gauge_meta) + 3] = True
     snap.histo_touched[len(snap.histo_meta) + 3] = True
     snap.set_touched[len(snap.set_meta) + 3] = True
-    res = Flusher(is_local=False, columnar=columnar).flush(snap)
+    res = flusher(is_local=False).flush(snap)
     assert res.tally["counters"] == 2
     assert res.tally["gauges"] == 1
     assert res.tally["histograms"] == 1
@@ -190,8 +196,7 @@ def test_tally_slices_stale_touch_bits(columnar):
 
 
 def frame_for(snap, **kw):
-    return Flusher(columnar=True, **kw).flush(
-        snap, now=77, retain_frame=True).frame
+    return Flusher(**kw).flush(snap, now=77).frame
 
 
 def test_frame_route_matches_legacy_route():

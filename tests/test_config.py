@@ -26,14 +26,24 @@ def test_yaml_file(tmp_path):
     assert c.is_local()
 
 
-def test_unknown_key_warns_not_fails(tmp_path):
-    c = read_config(data={"no_such_key": 1})
+# a key the program never had, and one it had until its option went
+# (PR 31: one producer out of the flush)
+GONE_KEYS = [{"no_such_key": 1}, {"tpu_columnar_emit": False}]
+
+
+@pytest.mark.parametrize("data", GONE_KEYS)
+def test_unknown_key_warns_not_fails(data, caplog):
+    with caplog.at_level("WARNING"):
+        c = read_config(data=data)
     assert isinstance(c, Config)
+    assert "unknown config keys" in caplog.text
+    assert next(iter(data)) in caplog.text
 
 
-def test_unknown_key_strict_fails():
+@pytest.mark.parametrize("data", GONE_KEYS)
+def test_unknown_key_strict_fails(data):
     with pytest.raises(ValueError, match="unknown config keys"):
-        read_config(data={"no_such_key": 1}, strict=True)
+        read_config(data=data, strict=True)
 
 
 def test_env_override():

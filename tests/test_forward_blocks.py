@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from tests import forward_wire_reference as reference
+from tests.flush_reference import RowFlusher
 from veneur_tpu.core.config import read_config
 from veneur_tpu.core.flusher import (Flusher, ForwardBlock, ForwardList,
                                      ForwardRow, _pad_idx)
@@ -396,11 +397,11 @@ def _routed_table():
 
 
 def _flush_both(table):
-    """One snapshot through the columnar flush (blocks) and the
-    per-row reference loops (a list of rows)."""
+    """One snapshot through the flush (blocks) and the per-row
+    reference loops (a list of rows)."""
     snap = table.swap()
-    blocks = Flusher(is_local=True, columnar=True).flush(snap, now=7)
-    rows = Flusher(is_local=True, columnar=False).flush(snap, now=7)
+    blocks = Flusher(is_local=True).flush(snap, now=7)
+    rows = RowFlusher(is_local=True).flush(snap, now=7)
     assert {type(p) for p in blocks.forward.parts} == {ForwardBlock}
     assert {type(p) for p in rows.forward.parts} == {ForwardRow}
     return blocks, rows

@@ -214,3 +214,63 @@ def test_debug_vars_surfaces_per_sink_counters():
         assert cap["flushes"] >= 1 and cap["errors"] == 0
     finally:
         server.shutdown()
+
+
+@pytest.mark.parametrize("workers", [1, 0])
+def test_frame_sinks_leave_the_flush_unmaterialized(workers):
+    """A cycle whose sinks all take ``flush_frame`` builds no
+    InterMetric list: the result holds the frame and the status-check
+    riders, and ``metrics`` is built when it is read."""
+    from veneur_tpu.core.config import read_config
+    from veneur_tpu.core.server import Server
+    from veneur_tpu.sinks.base import SinkBase
+
+    class FrameSink(SinkBase):
+        name = "frames"
+
+        def __init__(self):
+            super().__init__()
+            self.frames = []
+
+        def flush(self, metrics):
+            raise AssertionError("a frame-aware sink was handed a list")
+
+        def flush_frame(self, frame):
+            self.frames.append(frame)
+
+    sink = FrameSink()
+    server = Server(read_config(data={
+        "statsd_listen_addresses": [], "interval": "10s",
+        "hostname": "frame-host", "tpu_sink_workers": workers}),
+        extra_sinks=[sink])
+    try:
+        for i in range(5):
+            server.handle_packet(f"fr.hits.{i}:{i + 1}|c".encode())
+        server.handle_packet(b"fr.lat:3|ms\nfr.lat:5|ms")
+        server.handle_packet(b"_sc|fr.up|1|m:warn")
+        res = server.flush_once()
+        assert _wait_for(lambda: sink.frames)
+        rec = server.flush_ring.last()
+        assert res.frame._materialized is None
+        assert [m.name for m in res.riders] == ["fr.up"]
+        # the sink got the frame's blocks as they are, the rider beside
+        got = sink.frames[0]
+        assert got.blocks is res.frame.blocks
+        assert [m.name for m in got.extra] == ["fr.up"]
+        assert rec.metrics_emitted == res.metric_count() == len(
+            res.frame) + 1
+        assert res.frame._materialized is None
+        metrics = res.metrics
+        assert res.frame._materialized is not None
+        assert len(metrics) == rec.metrics_emitted
+        assert metrics[0].name == "fr.up" and metrics[0].type == "status"
+        assert metrics[1:] == res.frame.materialize()
+        names = {m.name for m in metrics}
+        assert {"fr.hits.0", "fr.hits.4", "fr.lat.count"} <= names
+        # a flush that is refused (shutdown) has the same shape
+        server.shutdown()
+        empty = server.flush_once()
+        assert empty.metrics == [] and empty.metric_count() == 0
+        assert len(empty.frame) == 0 and not empty.forward
+    finally:
+        server.shutdown()

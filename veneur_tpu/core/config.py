@@ -350,13 +350,6 @@ class Config:
     # serial per-wire scan stays available under "off" as the parity
     # oracle.
     tpu_collective_import: str = "auto"
-    # columnar flush->emit: assemble the flush as a MetricFrame
-    # (parallel NumPy columns over the row-metadata pool) instead of
-    # one InterMetric object per aggregate, and let frame-aware sinks
-    # encode straight off the columns.  VENEUR_TPU_COLUMNAR_EMIT=0
-    # falls back to the per-row legacy loop (kept as the parity
-    # oracle).
-    tpu_columnar_emit: bool = True
     # per-sink flush fan-out: >0 gives every metric sink its own
     # dedicated worker thread with a one-slot queue, per-sink timeout
     # accounting and retry-with-backoff, so one stalled sink can't

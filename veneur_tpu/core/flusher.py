@@ -1,12 +1,13 @@
-"""Flush: device table snapshot -> InterMetrics + forwardable state.
+"""Flush: device table snapshot -> MetricFrame + forwardable state.
 
 The reference's flush pipeline (flusher.go:28 ``Flush`` ->
 :172 ``tallyMetrics`` -> :228 ``generateInterMetrics``) walks every
 sampler object and calls its ``Flush()``.  Here the equivalent work is a
 handful of device readouts over whole tables — counter/gauge vectors,
 the histo quantile kernel over all rows at once, the HLL estimate kernel
-over all register planes — followed by host-side assembly of
-InterMetrics from row metadata.
+over all register planes — followed by host-side assembly of column
+blocks over the row metadata: a ``MetricFrame`` of what is emitted here,
+``ForwardBlock``s of what goes upstream.
 
 Role semantics (reference flusher.go:61-99, worker.go:181
 ``ForwardableMetrics``):
@@ -57,8 +58,7 @@ _SCOPE_CODE = {dsd.SCOPE_DEFAULT: _SCOPE_DEFAULT,
 
 def _scope_codes(metas: list, rows: np.ndarray) -> np.ndarray:
     """uint8 scope code per selected row — the one O(touched-rows)
-    Python pass the columnar path makes over metadata (vs the legacy
-    loop's per-AGGREGATE object construction per row)."""
+    Python pass the flush makes over metadata."""
     code = _SCOPE_CODE
     return np.fromiter((code[metas[r].scope] for r in rows),
                        np.uint8, len(rows))
@@ -246,8 +246,8 @@ class ForwardBlock:
 
 class ForwardList(Sequence):
     """``FlushResult.forward``: a flush's forwardable state in wire
-    order, as ``ForwardBlock``s (the columnar flush appends one per
-    class) and loose ``ForwardRow``s (the per-row reference loops).
+    order, as ``ForwardBlock``s (the flush appends one per class) and
+    loose ``ForwardRow``s (checkpoint replay, the tests' reference).
     It reads as the list of rows it used to be, rows built on demand:
     ``len()`` counts rows, iteration yields ``ForwardRow``s, an empty
     one is false and equals ``[]``.  The encoder takes ``parts``."""
@@ -280,14 +280,16 @@ class ForwardList(Sequence):
 
 @dataclass
 class FlushResult:
-    metrics: list[im.InterMetric] = field(default_factory=list)
+    """What one flush produced.  ``frame`` holds every emitted
+    aggregate as columns (an empty frame where nothing was flushed);
+    ``riders`` are the InterMetrics synthesized beside it (the
+    server's status checks); ``forward`` is the mergeable state bound
+    for the global tier.  ``metrics`` reads frame and riders as one
+    list of InterMetrics, built when somebody asks for it."""
+    frame: MetricFrame = field(default_factory=lambda: MetricFrame(0))
+    riders: list[im.InterMetric] = field(default_factory=list)
     forward: ForwardList = field(default_factory=ForwardList)
     tally: dict[str, int] = field(default_factory=dict)
-    # columnar emit: when the flush ran with ``retain_frame=True`` the
-    # emitted aggregates stay in ``frame`` and ``metrics`` holds only
-    # riders appended afterwards (status checks); otherwise the frame
-    # is materialized into ``metrics`` and this is None
-    frame: MetricFrame | None = None
     # row-granularity routing counts for the conservation ledger:
     # every touched row is emitted, forwarded, both (overlap —
     # default-scope histos on a local node), or retained (neither).
@@ -321,16 +323,15 @@ class FlushResult:
             self.forward_split[dest] = (
                 self.forward_split.get(dest, 0) + int(n))
 
-    def metric_count(self) -> int:
-        return len(self.metrics) + (len(self.frame)
-                                    if self.frame is not None else 0)
+    @property
+    def metrics(self) -> list[im.InterMetric]:
+        """Riders first, then the frame's metrics in block order; the
+        frame caches its materialization."""
+        made = self.frame.materialize()
+        return self.riders + made if self.riders else made
 
-    def all_metrics(self) -> list[im.InterMetric]:
-        """Every emitted InterMetric (frame materialized + riders) —
-        the adapter consumers like plugins use."""
-        if self.frame is None:
-            return self.metrics
-        return self.frame.materialize() + self.metrics
+    def metric_count(self) -> int:
+        return len(self.riders) + len(self.frame)
 
 
 def _percentile_suffix(p: float, naming: str = "precise") -> str:
@@ -354,8 +355,7 @@ class Flusher:
                  aggregates: tuple[str, ...] = DEFAULT_AGGREGATES,
                  hostname: str = "", tags: tuple[str, ...] = (),
                  percentile_naming: str = "precise",
-                 quantile_interpolation: str = "interp",
-                 columnar: bool = True):
+                 quantile_interpolation: str = "interp"):
         self.is_local = is_local
         self.percentiles = tuple(percentiles)
         self.aggregates = tuple(aggregates)
@@ -363,10 +363,6 @@ class Flusher:
         self.common_tags = tuple(tags)
         self.percentile_naming = percentile_naming
         self.quantile_interpolation = quantile_interpolation
-        # VENEUR_TPU_COLUMNAR_EMIT: vectorized MetricFrame assembly
-        # (default).  False runs the per-row legacy loop — kept as the
-        # parity oracle the columnar suite asserts against.
-        self.columnar = columnar
         # scale-out arc handoff override: a ``(meta) -> bool``
         # installed for exactly one flush (Server.arc_handoff).  True
         # force-forwards the row even on a node whose flusher never
@@ -378,39 +374,24 @@ class Flusher:
     # ------------------------------------------------------------------
 
     def flush(self, snap: Snapshot, now: int | None = None,
-              cycle=None, retain_frame: bool = False) -> FlushResult:
-        """``cycle`` is an observe.FlushCycle (or the NULL_CYCLE
-        default): stage spans and readback accounting for the three
-        phases this method owns — device dispatch, readback sync,
-        host emit.
-
-        ``retain_frame=True`` (the server's columnar fast path) keeps
-        the emitted aggregates in ``res.frame`` for frame-native sink
-        encoding; otherwise the frame is materialized into
-        ``res.metrics`` so direct callers see the legacy shape either
-        way."""
+              cycle=None) -> FlushResult:
+        """Read the snapshot out into a ``FlushResult``: the emitted
+        aggregates as ``res.frame``'s column blocks, the forwardable
+        state as ``res.forward``'s.  ``cycle`` is an
+        observe.FlushCycle (or the NULL_CYCLE default): stage spans
+        and readback accounting for the three phases this method owns
+        (device dispatch, readback sync, host emit)."""
         if cycle is None:
             cycle = observe.NULL_CYCLE
         ts = int(now if now is not None else time.time())
-        res = FlushResult()
+        res = FlushResult(frame=MetricFrame(ts, self.hostname,
+                                            self.common_tags))
         pre = self._prefetch(snap, cycle)
         with cycle.stage("host_emit"):
-            if self.columnar:
-                frame = MetricFrame(ts, self.hostname,
-                                    self.common_tags)
-                self._frame_counters(snap, res, pre, frame)
-                self._frame_gauges(snap, res, pre, frame)
-                self._frame_histos(snap, res, pre, frame)
-                self._frame_sets(snap, res, pre, frame)
-                if retain_frame:
-                    res.frame = frame
-                else:
-                    res.metrics.extend(frame.materialize())
-            else:
-                self._flush_counters(snap, ts, res, pre)
-                self._flush_gauges(snap, ts, res, pre)
-                self._flush_histos(snap, ts, res, pre)
-                self._flush_sets(snap, ts, res, pre)
+            self._frame_counters(snap, res, pre)
+            self._frame_gauges(snap, res, pre)
+            self._frame_histos(snap, res, pre)
+            self._frame_sets(snap, res, pre)
         res.tally["overflow"] = sum(snap.overflow.values())
         return res
 
@@ -754,215 +735,15 @@ class Flusher:
             return False
         return always or meta.scope == dsd.SCOPE_GLOBAL
 
-    def _mk(self, name: str, ts: int, value: float, meta: RowMeta,
-            mtype: str) -> im.InterMetric:
-        return im.InterMetric(name=name, timestamp=ts, value=value,
-                              tags=meta.tags + self.common_tags,
-                              type=mtype, hostname=self.hostname)
-
-    def _flush_counters(self, snap: Snapshot, ts: int, res: FlushResult,
-                        pre: dict) -> None:
-        vals = pre.get("counters")
-        if vals is None:
-            return
-        n_fwd = n_emit = n_ret = 0
-        for row in np.nonzero(
-                snap.counter_touched[:len(snap.counter_meta)])[0]:
-            meta = snap.counter_meta[row]
-            v = float(vals[row])
-            if self._forwardable(meta, always=False):
-                res.forward.append(ForwardRow(meta, "counter", value=v))
-                n_fwd += 1
-            elif self._emit_local(meta):
-                res.metrics.append(
-                    self._mk(meta.name, ts, v, meta, im.COUNTER))
-                n_emit += 1
-            else:
-                n_ret += 1
-        res.account_rows(staged=n_fwd + n_emit + n_ret,
-                         emitted=n_emit, forwarded=n_fwd,
-                         retained=n_ret)
-        # slice to the meta-backed rows before summing so the tally
-        # matches emitted+forwarded rows (the full plane can carry
-        # stale touch bits past len(meta))
-        res.tally["counters"] = int(
-            snap.counter_touched[:len(snap.counter_meta)].sum())
-
-    def _flush_gauges(self, snap: Snapshot, ts: int, res: FlushResult,
-                      pre: dict) -> None:
-        vals = pre.get("gauges")
-        if vals is None:
-            return
-        n_fwd = n_emit = n_ret = 0
-        for row in np.nonzero(
-                snap.gauge_touched[:len(snap.gauge_meta)])[0]:
-            meta = snap.gauge_meta[row]
-            v = float(vals[row])
-            if self._forwardable(meta, always=False):
-                res.forward.append(ForwardRow(meta, "gauge", value=v))
-                n_fwd += 1
-            elif self._emit_local(meta):
-                res.metrics.append(
-                    self._mk(meta.name, ts, v, meta, im.GAUGE))
-                n_emit += 1
-            else:
-                n_ret += 1
-        res.account_rows(staged=n_fwd + n_emit + n_ret,
-                         emitted=n_emit, forwarded=n_fwd,
-                         retained=n_ret)
-        res.tally["gauges"] = int(
-            snap.gauge_touched[:len(snap.gauge_meta)].sum())
-
-    def _flush_histos(self, snap: Snapshot, ts: int, res: FlushResult,
-                      pre: dict) -> None:
-        rows = pre["histo_rows"]
-        if not len(rows):
-            return
-        # Two stat planes: ``stats`` holds aggregates of raw samples
-        # ingested by THIS node ("Local*" in the reference,
-        # samplers/samplers.go:484); ``imp`` holds merged forwarded stat
-        # rows, pre-combined on device into ``comb``.  Aggregates for
-        # mixed-scope rows come only from the local plane (reference
-        # gates on LocalWeight/LocalMin/LocalMax, samplers.go:530-621 —
-        # emitting them from merged state would double-count against
-        # the local tier's own emission); rows flushed with global=true
-        # use the combined plane, the analogue of reading min/max/sum
-        # off the merged digest itself.
-        stats = pre["stats"]
-        comb = pre["comb"]
-        qvals = pre.get("qvals")
-        all_pcts = pre["all_pcts"]
-        emit_pcts = not self.is_local
-        fwd_pos = {r: i for i, r in enumerate(pre["histo_fwd"])}
-
-        n_fwd = n_emit = n_both = n_ret = 0
-        for row in rows:
-            meta = snap.histo_meta[row]
-            st = stats[row]
-            pos = fwd_pos.get(int(row))
-            if pos is not None:
-                res.forward.append(ForwardRow(
-                    meta, "histo", stats=st.copy(),
-                    means=pre["fwd_means"][pos].copy(),
-                    weights=pre["fwd_weights"][pos].copy()))
-                n_fwd += 1
-                # an arc handed off to a new ring owner forwards ONLY:
-                # the state now lives on the new member, which emits it
-                # next interval — emitting here too would double-report
-                # the row's mass cluster-wide for the handoff interval
-                if self.handoff is not None and self.handoff(meta):
-                    continue
-            # mixed-scope histos emit local aggregates even while their
-            # digest forwards; global-only histos emit nothing locally
-            if meta.scope == dsd.SCOPE_GLOBAL and self.is_local:
-                if pos is None:
-                    n_ret += 1
-                continue
-            n_emit += 1
-            if pos is not None:
-                n_both += 1
-            # the reference's ``global`` flag (samplers.go:511 Flush):
-            # true only for global-scope rows flushed on a global node
-            global_mode = (meta.scope == dsd.SCOPE_GLOBAL and
-                           not self.is_local)
-            self._emit_histo_row(res, meta, ts,
-                                 comb[row] if global_mode else st,
-                                 qvals, row, all_pcts,
-                                 with_percentiles=emit_pcts or
-                                 meta.scope == dsd.SCOPE_LOCAL,
-                                 global_mode=global_mode)
-        res.account_rows(staged=len(rows), emitted=n_emit,
-                         forwarded=n_fwd, overlap=n_both,
-                         retained=n_ret)
-        res.tally["histograms"] = int(
-            snap.histo_touched[:len(snap.histo_meta)].sum())
-
-    def _emit_histo_row(self, res, meta, ts, st, qvals, row,
-                        all_pcts, with_percentiles, global_mode=False):
-        agg = set(self.aggregates)
-        out = res.metrics
-        weight = float(st[segment.STAT_WEIGHT])
-        st_min = float(st[segment.STAT_MIN])
-        st_max = float(st[segment.STAT_MAX])
-        st_sum = float(st[segment.STAT_SUM])
-        st_rsum = float(st[segment.STAT_RSUM])
-        # sparse-emission gates (samplers.go:530-660): each aggregate is
-        # emitted from local values only when locally sampled, or
-        # unconditionally in global mode (merged state).  min/max use
-        # the untouched sentinels as the reference uses +/-Inf.
-        sampled = weight != 0
-        if "max" in agg and (global_mode or
-                             st_max != float(segment.STAT_MAX_EMPTY)):
-            out.append(self._mk(f"{meta.name}.max", ts, st_max, meta,
-                                im.GAUGE))
-        if "min" in agg and (global_mode or
-                             st_min != float(segment.STAT_MIN_EMPTY)):
-            out.append(self._mk(f"{meta.name}.min", ts, st_min, meta,
-                                im.GAUGE))
-        # sum/avg gate on SAMPLED (weight != 0), not st_sum != 0, like
-        # the reference (samplers.go:592-607 LocalWeight guards) — a
-        # locally-sampled histogram whose values sum to exactly 0 must
-        # still emit both aggregates
-        if "sum" in agg and (global_mode or sampled):
-            out.append(self._mk(f"{meta.name}.sum", ts, st_sum, meta,
-                                im.GAUGE))
-        if "avg" in agg and weight != 0:
-            out.append(self._mk(
-                f"{meta.name}.avg", ts, st_sum / weight, meta, im.GAUGE))
-        if "count" in agg and (global_mode or sampled):
-            out.append(self._mk(f"{meta.name}.count", ts, weight, meta,
-                                im.COUNTER))
-        if "hmean" in agg and weight != 0 and st_rsum != 0:
-            out.append(self._mk(
-                f"{meta.name}.hmean", ts, weight / st_rsum, meta,
-                im.GAUGE))
-        if "median" in agg and qvals is not None:
-            out.append(self._mk(f"{meta.name}.median", ts,
-                                float(qvals[row, len(all_pcts) - 1]),
-                                meta, im.GAUGE))
-        if with_percentiles and qvals is not None:
-            for pi, p in enumerate(self.percentiles):
-                out.append(self._mk(
-                    f"{meta.name}."
-                    f"{_percentile_suffix(p, self.percentile_naming)}",
-                    ts, float(qvals[row, pi]), meta, im.GAUGE))
-
-    def _flush_sets(self, snap: Snapshot, ts: int, res: FlushResult,
-                    pre: dict) -> None:
-        rows = pre["set_rows"]
-        if not len(rows):
-            return
-        ests = pre.get("ests")
-        fwd_pos = {r: i for i, r in enumerate(pre.get("set_fwd", ()))}
-        n_fwd = n_emit = n_ret = 0
-        for row in rows:
-            meta = snap.set_meta[row]
-            pos = fwd_pos.get(int(row))
-            if pos is not None:
-                res.forward.append(ForwardRow(
-                    meta, "set", regs=pre["fwd_regs"][pos].copy()))
-                n_fwd += 1
-            elif self._emit_local(meta):
-                res.metrics.append(self._mk(
-                    meta.name, ts, float(round(ests[row])), meta,
-                    im.GAUGE))
-                n_emit += 1
-            else:
-                n_ret += 1
-        res.account_rows(staged=len(rows), emitted=n_emit,
-                         forwarded=n_fwd, retained=n_ret)
-        res.tally["sets"] = int(
-            snap.set_touched[:len(snap.set_meta)].sum())
-
     # ------------------------------------------------------------------
-    # columnar emit (VENEUR_TPU_COLUMNAR_EMIT, default): the same
-    # routing/gating semantics as the row loops above, evaluated as
-    # boolean arrays over whole planes.  One scope-code pass per class
-    # replaces per-aggregate object construction per row; percentile
-    # suffixes are built once per flush, not once per row.
+    # emit: the routing and gating semantics of the module docstring,
+    # evaluated as boolean arrays over whole planes.  One scope-code
+    # pass per class; percentile suffixes are built once per flush.
+    # (tests/flush_reference.py holds the same semantics one row at a
+    # time, as the parity reference.)
 
     def _frame_scalar_class(self, metas, touched, vals, kind,
-                            type_code, res, frame) -> None:
+                            type_code, res) -> None:
         """Counters and gauges share one shape: forward global-scope
         rows on a local node, emit everything else."""
         rows = np.nonzero(touched[:len(metas)])[0]
@@ -970,7 +751,7 @@ class Flusher:
             return
         v64 = np.asarray(vals)[rows].astype(np.float64)
         # arc-handoff rows forward ONLY, on either tier: their state
-        # now lives on the new ring owner (see _flush_histos)
+        # now lives on the new ring owner, which emits it next interval
         ho = np.zeros(len(rows), dtype=bool)
         if self.handoff is not None:
             ho = np.fromiter(
@@ -985,35 +766,35 @@ class Flusher:
             res.forward.append(ForwardBlock(
                 kind, [metas[r] for r in rows[fwd]], values=v64[fwd]))
         emit = ~fwd
-        frame.add_block(metas, rows[emit], v64[emit],
-                        type_code=type_code)
+        res.frame.add_block(metas, rows[emit], v64[emit],
+                            type_code=type_code)
         res.account_rows(staged=len(rows),
                          emitted=int(emit.sum()),
                          forwarded=int(fwd.sum()))
 
     def _frame_counters(self, snap: Snapshot, res: FlushResult,
-                        pre: dict, frame: MetricFrame) -> None:
+                        pre: dict) -> None:
         vals = pre.get("counters")
         if vals is None:
             return
         self._frame_scalar_class(snap.counter_meta,
                                  snap.counter_touched, vals,
-                                 "counter", TYPE_COUNTER, res, frame)
+                                 "counter", TYPE_COUNTER, res)
         res.tally["counters"] = int(
             snap.counter_touched[:len(snap.counter_meta)].sum())
 
     def _frame_gauges(self, snap: Snapshot, res: FlushResult,
-                      pre: dict, frame: MetricFrame) -> None:
+                      pre: dict) -> None:
         vals = pre.get("gauges")
         if vals is None:
             return
         self._frame_scalar_class(snap.gauge_meta, snap.gauge_touched,
-                                 vals, "gauge", TYPE_GAUGE, res, frame)
+                                 vals, "gauge", TYPE_GAUGE, res)
         res.tally["gauges"] = int(
             snap.gauge_touched[:len(snap.gauge_meta)].sum())
 
     def _frame_histos(self, snap: Snapshot, res: FlushResult,
-                      pre: dict, frame: MetricFrame) -> None:
+                      pre: dict) -> None:
         rows = pre["histo_rows"]
         if not len(rows):
             return
@@ -1023,8 +804,7 @@ class Flusher:
         qvals = pre.get("qvals")
         all_pcts = pre["all_pcts"]
 
-        # forward rows first, in row order (same interleave-free
-        # order the legacy loop produces per class).  The gathers'
+        # forward rows first, in row order.  The gathers'
         # readbacks and ``stats[fwd]`` are private to this flush, so
         # the block takes them as they are, less ``_pad_idx``'s tail
         fwd = pre["histo_fwd"]
@@ -1043,9 +823,9 @@ class Flusher:
                     weights=pre["fwd_weights"][:len(fwd)]))
 
         sc = _scope_codes(metas, rows)
-        # routing counts mirror the legacy loop: on a local node every
-        # non-local-scope row forwards and every non-global-scope row
-        # emits (default scope does both); a global node emits all.
+        # routing counts: on a local node every non-local-scope row
+        # forwards and every non-global-scope row emits (default scope
+        # does both); a global node emits all.
         # Arc-handoff rows forward ONLY on either tier (emitting too
         # would double-report their mass for the handoff interval).
         ho = np.zeros(len(rows), dtype=bool)
@@ -1085,9 +865,17 @@ class Flusher:
             gm = sc[emit_mask] == _SCOPE_GLOBAL
             with_pcts = np.ones(len(erows), dtype=bool)
 
-        # aggregates for mixed-scope rows come only from the local
-        # plane; rows flushed global use the device-combined plane
-        # (see _flush_histos for the reference mapping)
+        # Two stat planes: ``stats`` holds aggregates of raw samples
+        # ingested by THIS node ("Local*" in the reference,
+        # samplers/samplers.go:484); ``comb`` is that plane combined on
+        # device with the merged forwarded stat rows.  Aggregates for
+        # mixed-scope rows come only from the local plane (reference
+        # gates on LocalWeight/LocalMin/LocalMax, samplers.go:530-621:
+        # emitting them from merged state would double-count against
+        # the local tier's own emission); rows flushed with global=true
+        # (global scope on a global node, samplers.go:511) use the
+        # combined plane, the analogue of reading min/max/sum off the
+        # merged digest itself.
         st = np.where(gm[:, None], comb[erows], stats[erows]) \
             .astype(np.float64)
         weight = st[:, segment.STAT_WEIGHT]
@@ -1100,11 +888,15 @@ class Flusher:
         agg = set(self.aggregates)
 
         def block(mask, vals, suffix, type_code=TYPE_GAUGE):
-            frame.add_block(metas, erows[mask], vals, suffix,
-                            type_code)
+            res.frame.add_block(metas, erows[mask], vals, suffix,
+                                type_code)
 
-        # sparse-emission gates, identical to _emit_histo_row
-        # (including the sampled-gated sum/avg fix)
+        # sparse-emission gates (samplers.go:530-660): each aggregate
+        # is emitted from local values only when locally sampled, or
+        # unconditionally in global mode (merged state); min/max use
+        # the untouched sentinels as the reference uses +/-Inf.  sum
+        # and avg gate on SAMPLED (weight != 0), not on sum != 0: a
+        # histogram whose values sum to exactly 0 still emits both
         if "max" in agg:
             m = gm | (st_max != float(segment.STAT_MAX_EMPTY))
             block(m, st_max[m], ".max")
@@ -1136,7 +928,7 @@ class Flusher:
             snap.histo_touched[:len(metas)].sum())
 
     def _frame_sets(self, snap: Snapshot, res: FlushResult,
-                    pre: dict, frame: MetricFrame) -> None:
+                    pre: dict) -> None:
         rows = pre["set_rows"]
         if not len(rows):
             return
@@ -1164,6 +956,6 @@ class Flusher:
         erows = rows[emit]
         if len(erows) and ests is not None:
             vals = np.round(np.asarray(ests)[erows]).astype(np.float64)
-            frame.add_block(metas, erows, vals)
+            res.frame.add_block(metas, erows, vals)
         res.tally["sets"] = int(
             snap.set_touched[:len(metas)].sum())

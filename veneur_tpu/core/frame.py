@@ -1,12 +1,12 @@
 """MetricFrame: the columnar flush->emit interchange.
 
-The legacy emit path builds one ``InterMetric`` object per aggregate —
-a single histogram row fans out to 8+ Python objects before any sink
-sees it, and at wide cardinality (100k-1M live series) that per-row
-object churn, not the d2h readback or the XLA merge, is the flush
-ceiling (the "serialization cost dominates sketch cost" regime SALSA
-identifies for streaming sketches).  A ``MetricFrame`` keeps the data
-columnar from the device readback to the sink wire:
+What a flush emits is one ``MetricFrame`` (``FlushResult.frame``).  One
+``InterMetric`` object per aggregate — a single histogram row fans out
+to 8+ of them — is, at wide cardinality (100k-1M live series), a cost
+larger than the d2h readback or the XLA merge (the "serialization cost
+dominates sketch cost" regime SALSA identifies for streaming sketches),
+so a frame keeps the data columnar from the device readback to the
+sink wire:
 
 - a frame is a list of ``Block``s; each block is ONE aggregate kind
   (the counter plane, ``<histo>.max``, one percentile column, ...)
@@ -16,14 +16,16 @@ columnar from the device readback to the sink wire:
   are never copied per metric — a histogram's 8 aggregate blocks all
   point at the same pool rows
 - values are one f64 NumPy column per block (widened from the f32
-  device planes, bit-identical to the legacy ``float()`` per row)
+  device planes, bit-identical to ``float()`` per row)
 - the name suffix (``".max"``, ``".99percentile"``) and the metric
   type are per-BLOCK scalars, computed once per flush instead of once
   per row
 
 Sinks that understand frames (``flush_frame``) encode straight off the
-columns; everything else goes through ``materialize()``, which builds
-the exact legacy ``InterMetric`` list lazily and caches it.  Per-sink
+columns; whoever needs the list of ``InterMetric`` (a sink that only
+knows ``flush(list)``, a plugin, a reader of ``FlushResult.metrics``)
+goes through ``materialize()``, which builds it on first call and
+caches it; a cycle in which nobody asks builds none.  Per-sink
 routing (``veneursinkonly:`` whitelists + excluded-tag stripping,
 reference sinks/sinks.go:51) is evaluated once per POOL ROW, not once
 per metric — the masks broadcast to every block sharing the pool.
@@ -72,9 +74,9 @@ class MetricFrame:
         self.hostname = hostname
         self.common_tags = tuple(common_tags)
         self.blocks: list[Block] = []
-        # legacy InterMetrics that ride along with the frame (status
-        # checks, anything synthesized outside the columnar path);
-        # routed frames carry the sink's filtered slice here
+        # InterMetrics that ride along with a routed frame (the
+        # sink's filtered slice of ``FlushResult.riders``: status
+        # checks, anything synthesized outside the flush)
         self.extra: list[InterMetric] = []
         self._materialized: list[InterMetric] | None = None
         # when a routed view shares this frame's blocks verbatim, it
@@ -116,7 +118,7 @@ class MetricFrame:
         return block.metas[int(block.rows[j])].name + block.suffix
 
     def iter_metrics(self):
-        """Yield legacy InterMetrics in block order (then extras)."""
+        """Yield InterMetrics in block order (then extras)."""
         yield from self._iter_block_metrics()
         yield from self.extra
 
@@ -147,8 +149,9 @@ class MetricFrame:
         return src._materialized
 
     def materialize(self) -> list[InterMetric]:
-        """The legacy list, built lazily and cached — the adapter for
-        sinks and plugins that never learned frames."""
+        """The list of InterMetrics, blocks then extras, built on
+        first call and cached — the adapter for sinks and plugins
+        that never learned frames."""
         blocks = self._materialize_blocks()
         return blocks + self.extra if self.extra else blocks
 

@@ -215,8 +215,7 @@ class Server:
                       else socket.gethostname()),
             tags=tuple(config.tags),
             percentile_naming=config.percentile_naming,
-            quantile_interpolation=config.quantile_interpolation,
-            columnar=bool(getattr(config, "tpu_columnar_emit", True)))
+            quantile_interpolation=config.quantile_interpolation)
 
         self.metric_sinks: list = list(extra_sinks or [])
         self.plugins: list = list(extra_plugins or [])
@@ -2327,10 +2326,8 @@ class Server:
                         kernel_drops=kdrops)
                     self._take_imports(cyc.record)
         # dispatch / device_wait / host_emit stages happen inside the
-        # flusher, against the same cycle; retain_frame keeps the
-        # columnar MetricFrame alive for frame-aware sinks instead of
-        # materializing InterMetrics eagerly
-        res = self.flusher.flush(snap, cycle=cyc, retain_frame=True)
+        # flusher, against the same cycle
+        res = self.flusher.flush(snap, cycle=cyc)
         # row-granularity flush balance: the flusher's routing counts
         # are synchronous, so they are balance inputs (wire outcomes
         # below are async and informational only)
@@ -2356,7 +2353,7 @@ class Server:
         ts = int(time.time())
         for (name, _, tags, _), (val, msg, stags) in (
                 (k, v) for k, v in status.items()):
-            res.metrics.append(im.InterMetric(
+            res.riders.append(im.InterMetric(
                 name=name, timestamp=ts, value=val, tags=stags,
                 type=im.STATUS, message=msg,
                 hostname=self.flusher.hostname))
@@ -2412,7 +2409,7 @@ class Server:
                            self._guarded_sink_flush, fn)
             for plugin in self.plugins:
                 submit(f"plugin:{plugin.name}", plugin.flush,
-                       list(res.all_metrics()), self.flusher.hostname)
+                       list(res.metrics), self.flusher.hostname)
             handoff_pending = self._handoff_pending
             if handoff_pending is not None and res.forward:
                 # scale-out arc handoff (Server.arc_handoff): this
@@ -2536,35 +2533,28 @@ class Server:
                 record=cyc.record)
         except Exception:
             log.exception("self-telemetry emission failed")
-        # flush_once callers see the legacy FlushResult shape: fold
-        # the frame back into res.metrics (sink closures bound the
-        # frame object itself, so late workers are unaffected; the
-        # materialization is cached on the frame either way)
-        if res.frame is not None:
-            res.metrics.extend(res.frame.materialize())
-            res.frame = None
         return res
 
     def _sink_flush_fn(self, sink, res, other, cyc, led=None):
         """Build the flush closure for one sink: routing (whitelists +
         excluded tags) happens HERE on the flush thread — vectorized
-        per pool row for frames — so the worker only encodes and
-        POSTs.  Frame-aware sinks get the routed MetricFrame; everyone
-        else gets the routed legacy list (materialized once, shared).
-        The closure raises on failure so the fan-out worker can
-        retry."""
+        per pool row for the frame, per metric for the riders — so the
+        worker only encodes and POSTs.  A sink with ``flush_frame``
+        (every ``SinkBase``) gets the routed MetricFrame, the routed
+        riders as its ``extra``; a duck-typed sink that only has
+        ``flush`` gets the routed list of ``res.metrics``, which
+        materializes the frame (once, cached on it).  The closure
+        raises on failure so the fan-out worker can retry."""
         base = sink if isinstance(sink, sinks_base.SinkBase) else None
-        frame = res.frame
-        if frame is not None and hasattr(sink, "flush_frame"):
-            extra = sinks_base.route(res.metrics, sink.name, base)
-            payload = frame.route(sink.name, sink, extra=extra)
+        if hasattr(sink, "flush_frame"):
+            extra = sinks_base.route(res.riders, sink.name, base)
+            payload = res.frame.route(sink.name, sink, extra=extra)
             n_routed = payload.total_len()
 
             def call():
                 sink.flush_frame(payload)
         else:
-            batch = sinks_base.route(res.all_metrics(), sink.name,
-                                     base)
+            batch = sinks_base.route(res.metrics, sink.name, base)
             n_routed = len(batch)
 
             def call():
