@@ -15,6 +15,7 @@ import pytest
 
 from tests.flush_reference import RowFlusher
 from veneur_tpu.core.flusher import Flusher
+from veneur_tpu.core.metrics import InterMetric
 from veneur_tpu.core.table import MetricTable, TableConfig
 from veneur_tpu.protocol import dogstatsd as dsd
 from veneur_tpu.sinks import base as sinks_base
@@ -199,27 +200,150 @@ def frame_for(snap, **kw):
     return Flusher(**kw).flush(snap, now=77).frame
 
 
-def test_frame_route_matches_legacy_route():
+def _sink(name, excluded=()):
+    sink = sinks_base.SinkBase()
+    sink.name = name
+    sink.set_excluded_tags(excluded)
+    return sink
+
+
+def _routed_key(metrics):
+    return sorted((m.name, m.value, m.tags) for m in metrics)
+
+
+@pytest.mark.parametrize("name,excluded", [
+    ("datadog", ()), ("signalfx", ()), ("datadog", ("env",)),
+    ("signalfx", ("env", "route"))],
+    ids=["named", "other", "named-excluding", "other-excluding"])
+def test_frame_route_matches_legacy_route(name, excluded):
+    """With a whitelisted series live, the frame's routing gives each
+    kind of sink (the one the whitelist names, another, either with
+    excluded tags) what the per-metric route gives over the
+    materialized list: same metrics, same tag tuples."""
     snap = mixed_table().swap()
+    assert snap.sink_only_rows == 1
     frame = frame_for(snap, is_local=False, aggregates=ALL_AGGS,
                       percentiles=(0.5,), tags=("c:t",))
+    assert frame.sink_only_rows == 1
     legacy = frame.materialize()
-
-    class Sink(sinks_base.SinkBase):
-        name = "datadog"
-    sink = Sink()
-    sink.set_excluded_tags(("env",))
+    sink = _sink(name, excluded)
     routed = frame.route(sink.name, sink)
+    assert routed is not frame
     want = sinks_base.route(legacy, sink.name, sink)
-    assert (sorted((m.name, m.value, m.tags) for m in
-                   routed.materialize()) ==
-            sorted((m.name, m.value, m.tags) for m in want))
-    # the whitelist row reached datadog but must not reach others
-    other = frame.route("signalfx", None)
-    names = {m.name for m in other.materialize()}
-    assert "only.dd" not in names
-    assert any(m.name == "only.dd"
-               for m in routed.materialize())
+    assert _routed_key(routed.materialize()) == _routed_key(want)
+    # the whitelist row reaches datadog and no other
+    assert (any(m.name == "only.dd" for m in routed.materialize())
+            == (name == "datadog"))
+    assert len(routed) == len(legacy) - (name != "datadog")
+
+
+@pytest.mark.parametrize("name", ["datadog", "signalfx"])
+def test_frame_route_common_tag_whitelist_still_routes(name):
+    """A ``veneursinkonly:`` among the server's common tags restricts
+    every series, whitelisted rows or none."""
+    t = MetricTable(TableConfig(counter_rows=16, histo_rows=16))
+    for ln in (b"a:1|c", b"b:2|c|#env:prod", b"lat:4|ms"):
+        t.ingest(dsd.parse_metric(ln))
+    snap = t.swap()
+    assert snap.sink_only_rows == 0
+    frame = frame_for(snap, is_local=False,
+                      tags=("veneursinkonly:datadog",))
+    routed = frame.route(name, _sink(name))
+    assert routed is not frame
+    want = sinks_base.route(frame.materialize(), name, _sink(name))
+    assert _routed_key(routed.materialize()) == _routed_key(want)
+    assert len(routed) == (len(frame) if name == "datadog" else 0)
+
+
+def _only_dd(t, how):
+    if how == "ingested":
+        t.ingest(dsd.parse_metric(
+            b"only.dd:5|c|#veneursinkonly:datadog"))
+    else:   # a global's row, made by a local's forward
+        assert t.import_counter("only.dd",
+                                ("veneursinkonly:datadog",), 5.0)
+
+
+@pytest.mark.parametrize("how", ["ingested", "imported"])
+def test_sink_only_count_follows_the_table(how):
+    """The count the frame reads is kept where rows are made and
+    dropped: 0 on an ordinary table, 1 from the interval a
+    ``veneursinkonly:`` series appears, 0 again once a compaction
+    has dropped it."""
+    t = MetricTable(TableConfig(counter_rows=8, compact_threshold=0.5))
+    plain = [f"p{i}:1|c|#env:prod".encode() for i in range(5)]
+    for ln in plain:
+        t.ingest(dsd.parse_metric(ln))
+    assert t.counter_idx.sink_only_rows == 0
+    assert t.swap().sink_only_rows == 0
+
+    _only_dd(t, how)
+    _only_dd(t, how)    # the row is there: counted once
+    for ln in plain:
+        t.ingest(dsd.parse_metric(ln))
+    assert t.counter_idx.sink_only_rows == 1
+    snap = t.swap()
+    assert snap.sink_only_rows == 1
+    meta, = [m for m in snap.counter_meta if m.sink_only is not None]
+    assert (meta.name, meta.sink_only) == ("only.dd", {"datadog"})
+    frame = frame_for(snap, is_local=False)
+    assert frame.sink_only_rows == 1
+    assert "only.dd" not in {
+        m.name for m in frame.route("signalfx").materialize()}
+
+    # untouched for an interval: the pool handed out still has the
+    # row (and says so), the compaction at this swap's end drops it
+    for ln in plain:
+        t.ingest(dsd.parse_metric(ln))
+    epoch = t._reindex_epoch
+    snap = t.swap()
+    assert t._reindex_epoch == epoch + 1
+    assert snap.sink_only_rows == 1
+    assert t.counter_idx.sink_only_rows == 0
+    for ln in plain:
+        t.ingest(dsd.parse_metric(ln))
+    snap = t.swap()
+    assert snap.sink_only_rows == 0
+    frame = frame_for(snap, is_local=False)
+    assert frame.route("signalfx") is frame
+
+
+class _SealedPool(list):
+    """A pool that may be indexed into by a sink's encoder, never
+    walked or read by the routing."""
+
+    def __iter__(self):
+        raise AssertionError("the routing walked a pool")
+
+    def __getitem__(self, i):
+        raise AssertionError("the routing read a pool row")
+
+
+def test_route_of_ordinary_series_reads_no_pool():
+    """The tick path holds no walk: with no whitelisted series live,
+    a sink without excluded tags is handed the frame itself and no
+    pool row is looked at, whatever the pools' size."""
+    n = 50_000
+    t = MetricTable(TableConfig(counter_rows=1 << 16, gauge_rows=16,
+                                histo_rows=16, set_rows=16))
+    for i in range(n):
+        t.ingest(dsd.parse_metric(
+            b"wide.%d:1|c|#env:prod,k:%d" % (i, i % 7)))
+    snap = t.swap()
+    assert len(snap.counter_meta) == n and snap.sink_only_rows == 0
+    frame = frame_for(snap, is_local=False, tags=("c:t",))
+    assert len(frame) == n
+    for b in frame.blocks:
+        b.metas = _SealedPool(b.metas)
+    sink = _sink("bench")
+    assert frame.route(sink.name, sink) is frame
+    extra = [InterMetric(name="x", timestamp=1, value=1.0)]
+    with_extra = frame.route(sink.name, sink, extra=extra)
+    assert with_extra.blocks is frame.blocks
+    # the seal does what it says: a sink that excludes tags has to
+    # read the rows
+    with pytest.raises(AssertionError, match="walked a pool"):
+        frame.route("dd", _sink("dd", ("env",)))
 
 
 def test_frame_route_no_filter_shares_self_and_materialization():
@@ -228,7 +352,6 @@ def test_frame_route_no_filter_shares_self_and_materialization():
     frame = frame_for(t.swap(), is_local=False)
     routed = frame.route("blackhole", None)
     assert routed is frame  # nothing filtered -> shared
-    from veneur_tpu.core.metrics import InterMetric
     extra = [InterMetric(name="x", timestamp=1, value=1.0, tags=(),
                          type="gauge")]
     with_extra = frame.route("blackhole", None, extra=extra)

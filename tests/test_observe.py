@@ -119,6 +119,57 @@ def test_flush_ring_record_matches_cycle():
         srv.shutdown()
 
 
+def test_sink_route_stage_says_what_the_routing_did():
+    """Every sink's routing is one ``sink_flush.route`` stage under
+    ``sink_flush``, in the record's stages; its span and the record
+    carry how many live series have a ``veneursinkonly:`` tag, its
+    span how many sinks there were and how many were handed the
+    frame's blocks unrouted."""
+    cap = CaptureSink()
+
+    class Other(CaptureSink):
+        name = "other"
+    other = Other()
+    srv = Server(read_config(data={
+        "statsd_listen_addresses": [], "interval": "10s",
+        "hostname": "route-host"}), extra_sinks=[cap, other],
+        extra_span_sinks=[cap])
+    srv.start()
+    try:
+        sinks = len(srv.metric_sinks)
+        assert sinks >= 2
+        srv.handle_packet(b"rt.hits:3|c")
+        srv.flush_once()
+        srv.handle_packet(b"rt.hits:3|c")
+        srv.handle_packet(b"rt.only:1|c|#veneursinkonly:capture")
+        srv.flush_once()
+        recs = srv.flush_ring.records()
+        assert [r.sink_only_rows for r in recs] == [0, 1]
+        assert [r.to_dict()["sink_only_rows"] for r in recs] == [0, 1]
+        for rec, shared in zip(recs, (sinks, 0)):
+            assert 0 <= rec.stages["sink_flush.route"] \
+                <= rec.stages["sink_flush"]
+            assert _wait(lambda: {
+                "flush.sink_flush", "flush.sink_flush.route"} <= {
+                s["name"] for s in srv.trace_index.get(rec.trace_id)})
+            spans = {s["name"]: s
+                     for s in srv.trace_index.get(rec.trace_id)}
+            route = spans["flush.sink_flush.route"]
+            assert (route["parent_id"]
+                    == spans["flush.sink_flush"]["span_id"])
+            assert route["tags"]["stage"] == "sink_flush.route"
+            assert (route["tags"]["sinks"],
+                    route["tags"]["sink_only_rows"],
+                    route["tags"]["shared"]) == (
+                str(sinks), str(rec.sink_only_rows), str(shared))
+        assert _wait(lambda: len(cap.batches) == 2
+                     and len(other.batches) == 2)
+        assert "rt.only" in {m.name for m in cap.batches[1]}
+        assert "rt.only" not in {m.name for m in other.metrics}
+    finally:
+        srv.shutdown()
+
+
 def _host_events(trace_dir, prefixes):
     """name -> [(start_ns, duration_ns)] of the host planes' events
     whose name starts with one of ``prefixes``, read back from the

@@ -385,7 +385,8 @@ class Flusher:
             cycle = observe.NULL_CYCLE
         ts = int(now if now is not None else time.time())
         res = FlushResult(frame=MetricFrame(ts, self.hostname,
-                                            self.common_tags))
+                                            self.common_tags,
+                                            snap.sink_only_rows))
         pre = self._prefetch(snap, cycle)
         with cycle.stage("host_emit"):
             self._frame_counters(snap, res, pre)

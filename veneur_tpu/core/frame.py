@@ -28,7 +28,12 @@ goes through ``materialize()``, which builds it on first call and
 caches it; a cycle in which nobody asks builds none.  Per-sink
 routing (``veneursinkonly:`` whitelists + excluded-tag stripping,
 reference sinks/sinks.go:51) is evaluated once per POOL ROW, not once
-per metric — the masks broadcast to every block sharing the pool.
+per metric — the masks broadcast to every block sharing the pool —
+and only in a flush that has something to route: a row's whitelist is
+read off its tags when the row is made (``RowMeta.sink_only``), the
+table's indexes count the rows that have one, and the frame is told
+the count (``sink_only_rows``), so a frame of ordinary series goes to
+a sink without exclusions as it is, no pool looked at.
 """
 
 from __future__ import annotations
@@ -44,8 +49,6 @@ from veneur_tpu.core.metrics import InterMetric
 TYPE_GAUGE = 0
 TYPE_COUNTER = 1
 TYPE_NAMES = (im.GAUGE, im.COUNTER)
-
-_SINK_ONLY_PREFIX = "veneursinkonly:"
 
 
 @dataclass
@@ -69,10 +72,18 @@ class Block:
 
 class MetricFrame:
     def __init__(self, ts: int, hostname: str = "",
-                 common_tags: tuple[str, ...] = ()):
+                 common_tags: tuple[str, ...] = (),
+                 sink_only_rows: int = 0):
         self.ts = int(ts)
         self.hostname = hostname
         self.common_tags = tuple(common_tags)
+        # how many rows of the pools have a ``RowMeta.sink_only``
+        # (``Snapshot.sink_only_rows``; whoever builds a frame by hand
+        # over such rows says so here): with the common tags' own
+        # whitelist, all that decides whether acceptance can differ
+        # from sink to sink
+        self.sink_only_rows = int(sink_only_rows)
+        self._common_sink_only = im.parse_sink_only(self.common_tags)
         self.blocks: list[Block] = []
         # InterMetrics that ride along with a routed frame (the
         # sink's filtered slice of ``FlushResult.riders``: status
@@ -85,7 +96,6 @@ class MetricFrame:
         self._block_src: "MetricFrame | None" = None
         # (id(pool), sink_name, excluded) -> (accept bool[], tags list)
         self._route_cache: dict = {}
-        self._routing_needed: bool | None = None
 
     # ------------------------------------------------------------------
 
@@ -171,17 +181,16 @@ class MetricFrame:
         accept = np.ones(n, dtype=bool)
         tags_out: list = [()] * n
         common = self.common_tags
+        common_wl = self._common_sink_only
         for i, meta in enumerate(metas):
-            tags = meta.tags + common
-            wl = None
-            for t in tags:
-                if t.startswith(_SINK_ONLY_PREFIX):
-                    if wl is None:
-                        wl = set()
-                    wl.add(t[len(_SINK_ONLY_PREFIX):])
+            wl = meta.sink_only
+            if common_wl is not None:
+                # the common tags' whitelist joins every row's own
+                wl = common_wl if wl is None else wl | common_wl
             if wl is not None and sink_name not in wl:
                 accept[i] = False
                 continue
+            tags = meta.tags + common
             if excluded:
                 tags = tuple(t for t in tags
                              if t.split(":", 1)[0] not in excluded)
@@ -191,26 +200,11 @@ class MetricFrame:
         return out
 
     def _needs_routing(self) -> bool:
-        """True when any pool row carries a sink whitelist tag — the
-        only case where acceptance can differ per sink.  Scanned once
-        per frame (pools are immutable for the frame's lifetime)."""
-        if self._routing_needed is not None:
-            return self._routing_needed
-        self._routing_needed = self._scan_whitelists()
-        return self._routing_needed
-
-    def _scan_whitelists(self) -> bool:
-        seen = set()
-        for b in self.blocks:
-            if id(b.metas) in seen:
-                continue
-            seen.add(id(b.metas))
-            for meta in b.metas:
-                for t in meta.tags:
-                    if t.startswith(_SINK_ONLY_PREFIX):
-                        return True
-        return any(t.startswith(_SINK_ONLY_PREFIX)
-                   for t in self.common_tags)
+        """True when a pool row or the common tags carry a sink
+        whitelist — the only case where acceptance can differ per
+        sink."""
+        return (self.sink_only_rows > 0
+                or self._common_sink_only is not None)
 
     def route(self, sink_name: str, sink=None,
               extra: list[InterMetric] | None = None) -> "MetricFrame":
@@ -221,7 +215,8 @@ class MetricFrame:
         materialization across sinks."""
         excluded = frozenset(getattr(sink, "excluded_tags", ())
                              if sink is not None else ())
-        routed = MetricFrame(self.ts, self.hostname, self.common_tags)
+        routed = MetricFrame(self.ts, self.hostname, self.common_tags,
+                             self.sink_only_rows)
         routed.extra = list(extra or ())
         routed._route_cache = self._route_cache  # share pool work
         if not excluded and not self._needs_routing():

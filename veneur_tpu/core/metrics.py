@@ -17,6 +17,14 @@ STATUS = "status"
 _SINK_ONLY_PREFIX = "veneursinkonly:"
 
 
+def parse_sink_only(tags) -> frozenset[str] | None:
+    """The sinks a ``veneursinkonly:<sink>`` tag restricts these tags'
+    series to; None (every sink) where no tag has the prefix."""
+    names = [t[len(_SINK_ONLY_PREFIX):] for t in tags
+             if t.startswith(_SINK_ONLY_PREFIX)]
+    return frozenset(names) if names else None
+
+
 @dataclass(frozen=True)
 class InterMetric:
     name: str
@@ -30,8 +38,7 @@ class InterMetric:
     def sink_whitelist(self) -> frozenset[str]:
         """Sinks this metric is restricted to (empty = all sinks);
         reference sinks.IsAcceptableMetric (sinks/sinks.go:51)."""
-        return frozenset(t[len(_SINK_ONLY_PREFIX):] for t in self.tags
-                         if t.startswith(_SINK_ONLY_PREFIX))
+        return parse_sink_only(self.tags) or frozenset()
 
     def acceptable_for(self, sink_name: str) -> bool:
         wl = self.sink_whitelist()

@@ -2393,11 +2393,10 @@ class Server:
                 if split:
                     res.account_forward_split(split)
 
-        with cyc.stage("sink_flush"):
+        with cyc.stage("sink_flush") as sink_span:
             fanout_tasks = []
-            for sink in self.metric_sinks:
-                fn = self._sink_flush_fn(sink, res, events + checks,
-                                         cyc, led)
+            for sink, fn in self._route_sinks(res, events + checks,
+                                              cyc, led, sink_span):
                 if self._fanout is not None:
                     task = self._fanout.dispatch(sink.name, fn)
                     if task is not None:
@@ -2535,20 +2534,46 @@ class Server:
             log.exception("self-telemetry emission failed")
         return res
 
+    def _route_sinks(self, res, other, cyc, led, parent):
+        """Every metric sink with its flush closure, the routing of
+        all of them timed as the one stage ``sink_flush.route`` under
+        ``parent`` (the ``sink_flush`` span).  Its tags say what the
+        routing had to do: ``sink_only_rows`` is the count the frame
+        was given (series with a ``veneursinkonly:`` tag), ``shared``
+        the sinks that were handed the frame's blocks as they are."""
+        with cyc.stage("sink_flush.route", parent=parent) as sp:
+            routed, shared = [], 0
+            for sink in self.metric_sinks:
+                fn, unrouted = self._sink_flush_fn(sink, res, other,
+                                                   cyc, led)
+                routed.append((sink, fn))
+                shared += unrouted
+            cyc.record.sink_only_rows = res.frame.sink_only_rows
+            sp.add_tag("sinks", str(len(routed)))
+            sp.add_tag("sink_only_rows",
+                       str(res.frame.sink_only_rows))
+            sp.add_tag("shared", str(shared))
+        return routed
+
     def _sink_flush_fn(self, sink, res, other, cyc, led=None):
         """Build the flush closure for one sink: routing (whitelists +
-        excluded tags) happens HERE on the flush thread — vectorized
-        per pool row for the frame, per metric for the riders — so the
-        worker only encodes and POSTs.  A sink with ``flush_frame``
-        (every ``SinkBase``) gets the routed MetricFrame, the routed
-        riders as its ``extra``; a duck-typed sink that only has
-        ``flush`` gets the routed list of ``res.metrics``, which
-        materializes the frame (once, cached on it).  The closure
-        raises on failure so the fan-out worker can retry."""
+        excluded tags) happens HERE on the flush thread — per pool row
+        for the frame and only where a live series has a whitelist or
+        the sink excludes tags (``MetricFrame.route``), per metric for
+        the riders — so the worker only encodes and POSTs.  A sink
+        with ``flush_frame`` (every ``SinkBase``) gets the routed
+        MetricFrame, the routed riders as its ``extra``; a duck-typed
+        sink that only has ``flush`` gets the routed list of
+        ``res.metrics``, which materializes the frame (once, cached on
+        it).  Returns the closure, which raises on failure so the
+        fan-out worker can retry, and whether the sink got the
+        frame's blocks unrouted."""
         base = sink if isinstance(sink, sinks_base.SinkBase) else None
+        shared = False
         if hasattr(sink, "flush_frame"):
             extra = sinks_base.route(res.riders, sink.name, base)
             payload = res.frame.route(sink.name, sink, extra=extra)
+            shared = payload.blocks is res.frame.blocks
             n_routed = payload.total_len()
 
             def call():
@@ -2576,7 +2601,7 @@ class Server:
                     self._sink_durations[sink.name] = (
                         self._sink_durations.get(sink.name, 0) +
                         time.monotonic_ns() - t0)
-        return fn
+        return fn, shared
 
     def _guarded_sink_flush(self, fn) -> None:
         """Shared-pool wrapper (tpu_sink_workers=0): same

@@ -41,6 +41,7 @@ import numpy as np
 
 from veneur_tpu import native, observe
 from veneur_tpu.core import tiers as tiersmod
+from veneur_tpu.core.metrics import parse_sink_only
 from veneur_tpu.observe.ledger import ClassDropTally
 from veneur_tpu.ops import hll, segment, superbatch, tdigest
 from veneur_tpu.protocol import columnar, dogstatsd as dsd
@@ -278,7 +279,7 @@ class _PendingSwap:
     __slots__ = ("work", "state", "counter_meta", "counter_touched",
                  "gauge_meta", "gauge_touched", "histo_meta",
                  "histo_touched", "set_meta", "set_touched",
-                 "overflow", "ingested", "row_maps")
+                 "sink_only_rows", "overflow", "ingested", "row_maps")
 
     def staged_counts(self) -> dict[str, int]:
         """What the final apply is about to take, for the
@@ -340,6 +341,14 @@ class RowMeta:
     # number that passes to another series gets that series' own
     wire_ident: tuple[bytes, bytes] | None = field(
         default=None, repr=False, compare=False)
+    # the sinks its ``veneursinkonly:`` tags restrict it to, None for
+    # a series every sink gets: read off the tags once, here, so the
+    # flush's routing (frame.MetricFrame.route) never walks them
+    sink_only: frozenset[str] | None = field(
+        init=False, repr=False, compare=False)
+
+    def __post_init__(self):
+        self.sink_only = parse_sink_only(self.tags)
 
 
 class _ClassIndex:
@@ -349,6 +358,9 @@ class _ClassIndex:
         self.capacity = capacity
         self.rows: dict[tuple, int] = {}
         self.meta: list[RowMeta] = []
+        # live rows with a ``sink_only``: what the flush's routing
+        # reads in place of the pool
+        self.sink_only_rows = 0
         self.touched = np.zeros(capacity, dtype=bool)
         self.last_gen = np.zeros(capacity, dtype=np.int64)
         # centralized drop tally: every fast-path drop site goes
@@ -374,8 +386,9 @@ class _ClassIndex:
                 return None
             row = len(self.meta)
             self.rows[sample_key] = row
-            self.meta.append(RowMeta(name, tags, scope, mtype,
-                                     key_hash))
+            meta = RowMeta(name, tags, scope, mtype, key_hash)
+            self.meta.append(meta)
+            self.sink_only_rows += meta.sink_only is not None
         elif key_hash and not self.meta[row].key_hash:
             self.meta[row].key_hash = key_hash
         self.last_gen[row] = gen
@@ -400,15 +413,19 @@ class _ClassIndex:
         new_meta: list[RowMeta] = []
         new_gen = np.zeros(self.capacity, dtype=np.int64)
         mapping = np.full(self.capacity, -1, np.int32)
+        sink_only_rows = 0
         for key, row in self.rows.items():
             if self.last_gen[row] >= keep_gen:
                 new_row = len(new_meta)
                 new_rows[key] = new_row
                 new_gen[new_row] = self.last_gen[row]
-                new_meta.append(self.meta[row])
+                meta = self.meta[row]
+                new_meta.append(meta)
+                sink_only_rows += meta.sink_only is not None
                 mapping[row] = new_row
         self.rows = new_rows
         self.meta = new_meta
+        self.sink_only_rows = sink_only_rows
         self.last_gen = new_gen
         self.touched = np.zeros(self.capacity, dtype=bool)
         return mapping
@@ -482,6 +499,9 @@ class Snapshot:
     hll_regs: Any
     set_meta: list[RowMeta]
     set_touched: np.ndarray
+    # rows of the four pools above that have a ``RowMeta.sink_only``,
+    # counted by their indexes as the rows came and went
+    sink_only_rows: int
     # host-folded raw-set registers for the interval (None when the
     # plane exceeded host_set_plane_max_bytes) and whether anything
     # (imports, oversized-plane scatters) touched the DEVICE registers
@@ -2981,6 +3001,10 @@ class MetricTable:
         pend.histo_touched = self.histo_idx.touched.copy()
         pend.set_meta = list(self.set_idx.meta)
         pend.set_touched = self.set_idx.touched.copy()
+        pend.sink_only_rows = sum(
+            idx.sink_only_rows for idx in (
+                self.counter_idx, self.gauge_idx, self.histo_idx,
+                self.set_idx))
         pend.overflow = {
             "counter": self.counter_idx.overflow,
             "gauge": self.gauge_idx.overflow,
@@ -3115,6 +3139,7 @@ class MetricTable:
             hll_regs=st.hll_regs,
             set_meta=pend.set_meta,
             set_touched=pend.set_touched,
+            sink_only_rows=pend.sink_only_rows,
             hll_host_plane=st.hll_host_plane,
             hll_device_touched=st.hll_device_touched,
             hll_host_ez=st.hll_host_ez,

@@ -41,6 +41,10 @@ class FlushRecord:
     rows_block: int = 0
     rows_loose: int = 0
     ident_cached: int = 0
+    # live series with a ``veneursinkonly:`` tag, as the frame was
+    # told (stage ``sink_flush.route``): 0 is a cycle whose routing
+    # looked at no pool for a sink without excluded tags
+    sink_only_rows: int = 0
     # import wires this server folded since its previous cycle; their
     # handler durations are in ``stages`` under ``import[.<step>]``
     imports: int = 0
@@ -68,6 +72,7 @@ class FlushRecord:
                 "rows_block": self.rows_block,
                 "rows_loose": self.rows_loose,
                 "ident_cached": self.ident_cached,
+                "sink_only_rows": self.sink_only_rows,
                 "imports": self.imports,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,

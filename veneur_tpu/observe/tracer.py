@@ -17,6 +17,13 @@ stage before the dot:
       +- flush.device_wait  device_get — the d2h sync point
       +- flush.host_emit    InterMetric assembly from row metadata
       +- flush.sink_flush   per-sink fan-out + interval-budget wait
+      |    +- flush.sink_flush.route  every sink's routing, before
+      |                               the first dispatch; tags
+      |                               ``sinks``, ``sink_only_rows``
+      |                               (live series with a
+      |                               ``veneursinkonly:`` tag),
+      |                               ``shared`` (sinks handed the
+      |                               frame's blocks as they are)
       +- flush.sink.<name>  one sink's encode + delivery (its worker)
       +- flush.forward      upstream ship (local tier only)
            +- flush.forward.encode  rows -> MetricList bytes; tags

@@ -1112,6 +1112,10 @@ class ShardedTable:
             hll_regs=merged["hll"],
             set_meta=list(self.set_idx.meta),
             set_touched=self.set_idx.touched.copy(),
+            sink_only_rows=sum(
+                idx.sink_only_rows for idx in (
+                    self.counter_idx, self.gauge_idx, self.histo_idx,
+                    self.set_idx)),
             hll_host_plane=None,
             hll_device_touched=True,
             overflow={
