@@ -6,5 +6,5 @@ MOVES = "flush_lag_ms"
 
 
 def read(run):
-    lags = run["lags"]["local"]
+    lags = run["lags"][run["lag_of"]]
     return 1e3 * max(lags) if lags else None

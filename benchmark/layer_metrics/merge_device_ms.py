@@ -1,8 +1,9 @@
 """Device time of the t-digest merge in the traced interval: every
 operation named ``tdigest_merge_c<cap>_k<k>`` (the Pallas kernel, one
-name a bucket of staged samples a row) among the operations the trace
-reduction keeps, which are the ten with most time.  A trace without
-such an operation reads nothing."""
+name a bucket of staged samples a row) among all the device's
+operations of the slice (``device_ops_all``: not only the ten that the
+result line's breakdown ranks).  A trace without such an operation
+reads nothing."""
 import re
 
 LAYER = "device apply, kernels"
@@ -15,7 +16,8 @@ def read(run):
     t = run.get("trace")
     if not t:
         return None
-    times = [s for name, s in t["device_ops"] if KERNEL.search(name)]
+    times = [s for name, s in t.get("device_ops_all", ())
+             if KERNEL.search(name)]
     if not times:
         return None
     return 1e3 * sum(times)

@@ -28,7 +28,7 @@ def result_line(c: dict, res: dict, trace: bool) -> dict:
     run = res["run"]
     metrics = {}
     if not trace:
-        values = {"flush_lag_ms": _mean_ms(run["lags"]["local"]),
+        values = {"flush_lag_ms": _mean_ms(run["lags"][run["lag_of"]]),
                   "setup_s": run["setup_s"]}
         for m in c["end_to_end"]:
             if values.get(m["name"]) is not None:

@@ -127,8 +127,9 @@ def _module_name(name: str) -> str:
 
 def reduce(data: dict, anchors_unix: list[float],
            cycles: list[tuple[float, float]]) -> dict | None:
-    """Busy seconds, module and operation times and the longest idle
-    gaps of the slice between the two anchors.  ``cycles`` are the
+    """Busy seconds, module times, every device operation's total by
+    name (and the ten with most) and the longest idle gaps of the
+    slice between the two anchors.  ``cycles`` are the
     servers' flush cycles as wall-clock (start, end); a gap that one
     of them covers for more than half is the flush's, the rest is
     ingest's.  None where the trace holds no device plane or lacks
@@ -173,14 +174,18 @@ def reduce(data: dict, anchors_unix: list[float],
                       for x, y in flush)
         return "flush_cycle" if covered > 0.5 * (b - a) else "ingest"
     gaps.sort(key=lambda ab: ab[0] - ab[1])
+    ranked = sorted(([k, v] for k, v in ops.items()),
+                    key=lambda kv: -kv[1])
     return {
         "busy_s": sum(busy_s) / len(busy_s),
         "window_s": (w1 - w0) / 1e9,
         "devices": len(devices),
         "modules": {k: {"n": len(v), "total_s": sum(v) / 1e9}
                     for k, v in modules.items()},
-        "device_ops": sorted(([k, v] for k, v in ops.items()),
-                             key=lambda kv: -kv[1])[:10],
+        # the ten with most time go into the result line's breakdown;
+        # a reader that sums a kernel's operations takes them from all
+        "device_ops": ranked[:10],
+        "device_ops_all": ranked,
         "idle_gaps": [[owner(a, b), (b - a) / 1e9]
                       for a, b in gaps[:10]],
     }

@@ -124,10 +124,10 @@ def make_rounds(spec: dict, seed: int) -> list[list[bytes]]:
 
 def scaled(spec: dict, scale: dict) -> dict:
     """The traffic file with the CPU rehearsal's overrides: the keys
-    of its ``round`` and its top-level numbers.  A cell runs the file
-    as it is (``scale`` empty)."""
-    out = dict(spec)
-    out["round"] = {**spec["round"], **scale.get("round", {})}
-    out.update({k: v for k, v in scale.items()
-                if k not in ("round", "servers", "limits")})
+    of its ``round`` (where it has one) and its top-level numbers.  A
+    cell runs the file as it is (``scale`` empty)."""
+    out = {**spec, **{k: v for k, v in scale.items()
+                      if k not in ("round", "servers", "limits")}}
+    if "round" in spec:
+        out["round"] = {**spec["round"], **scale.get("round", {})}
     return out

@@ -3,26 +3,17 @@ through the Python entry; the result line's shape; ``run.py`` without
 a TPU; and the timed path broken underneath, which has to come out as
 not correct."""
 
-import json
 import os
 import subprocess
 import sys
 
 import pytest
 
-from bench_util import ROOT, TINY
+from bench_util import ROOT, TINY, run_py, topology
 
 from benchmark import harness  # noqa: E402
 
 RUN = os.path.join(ROOT, "benchmark", "run.py")
-
-
-def _run_py():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location("bench_run_py", RUN)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _body(name, trace=False, seed=3):
@@ -37,7 +28,7 @@ def test_cell_body_is_correct_at_tiny_scale(name):
     assert res["correct"], res["checks"]
     assert res["failed"] == 0 and res["attempted"] > 0
     assert all(v <= lim for v, lim in res["checks"].values())
-    line = _run_py().result_line(c, res, trace=False)
+    line = run_py().result_line(c, res, trace=False)
     assert list(line)[:5] == ["correct", "attempted", "failed",
                               "metrics", "device"]
     assert list(line)[-1] == "checks"
@@ -45,10 +36,13 @@ def test_cell_body_is_correct_at_tiny_scale(name):
     assert set(line["metrics"]) == want
     assert all(m["value"] > 0 for m in line["metrics"].values())
     run = res["run"]
-    # the rate is over the whole window, and every tick has its lag
+    # every tick has its lag on every server, and the cell's lag is
+    # the mean over the ticks of the server its topology names
     assert line["metrics"]["setup_s"]["value"] == run["setup_s"]
-    assert len(run["lags"]["local"]) == len(run["ticks"]) == 2
-    assert len(run["lags"]["global"]) == 2
+    assert len(run["ticks"]) == 2
+    assert all(len(lags) == 2 for lags in run["lags"].values())
+    assert line["metrics"]["flush_lag_ms"]["value"] == pytest.approx(
+        1e3 * sum(run["lags"][run["lag_of"]]) / 2)
     assert run["lines_received"] > 0
 
 
@@ -57,7 +51,7 @@ def test_traced_run_reports_layer_metrics_and_same_attempted_shape():
     something to read; on the CPU the device's have nothing."""
     c, res = _body("local-wide-paced", trace=True)
     assert res["correct"], res["checks"]
-    line = _run_py().result_line(c, res, trace=True)
+    line = run_py().result_line(c, res, trace=True)
     got = set(line["metrics"])
     listed = {m["name"] for m in c["per_layer"]}
     assert got <= listed
@@ -77,7 +71,7 @@ def test_the_sink_keeps_columns_and_the_lag_ends_at_delivery():
     import numpy as np
     from veneur_tpu.core.frame import TYPE_COUNTER, MetricFrame
     from veneur_tpu.core.table import RowMeta
-    sink = harness.make_sink()
+    sink = topology().make_sink()
     frame = MetricFrame(ts=1, common_tags=("c:1",))
     metas = [RowMeta("bench.count.0", ("a:1",), "", "counter"),
              RowMeta("bench.count.1", (), "", "counter")]

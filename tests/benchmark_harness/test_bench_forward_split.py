@@ -4,23 +4,12 @@ CPU reports the five metrics that read the local's ``forward.encode``
 their readers find nothing, without raising, in the rings of a
 program that has no such stage."""
 
-import os
-
-from bench_util import ROOT, TINY
+from bench_util import TINY, run_py
 
 from benchmark import harness  # noqa: E402
 
 NEW = ("forward_encode_ms", "forward_rpc_ms", "import_decode_ms",
        "import_lock_wait_ms", "import_apply_ms")
-
-
-def _run_py():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_run_py", os.path.join(ROOT, "benchmark", "run.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def test_traced_body_reports_the_forward_split():
@@ -29,7 +18,7 @@ def test_traced_body_reports_the_forward_split():
     res = harness.run_cell(c, seed=6, seconds=4.0, trace=True,
                            scale=TINY[name])
     assert res["correct"], res["checks"]
-    m = {k: v["value"] for k, v in _run_py().result_line(
+    m = {k: v["value"] for k, v in run_py().result_line(
         c, res, trace=True)["metrics"].items()}
     assert set(NEW) <= set(m)
     assert all(m[k] > 0 for k in NEW if k != "import_lock_wait_ms")

@@ -4,19 +4,26 @@ size, its body traced at the tiny scale on the CPU, the control at
 that shape, the merge kernel's bytes function, and the four readers
 it brought, each on a run that has its counter and on one without."""
 
-import json
-import os
 import types
 
 import pytest
 
-from bench_util import ROOT, TINY
+import structure
+from bench_util import ROOT, TINY, copy_benchmark, run_py
 
 from benchmark import control, harness, traffic  # noqa: E402
 
 CELL = "local-mixed-paced"
+WIDE = "local-wide-paced"
 NEW = ("reader_busy_pct", "merge_device_ms", "merge_roofline",
        "gc_pause_ms")
+# what PRs 25 and 26 brought for the wide cell, and the one of the
+# four that reads both
+WIDE_TWELVE = (
+    "sender_late_ms", "flush_readout_ms", "flush_lag_max_ms",
+    "host_emit_ms", "forward_ms", "device_idle_pct",
+    "forward_encode_ms", "forward_rpc_ms", "import_decode_ms",
+    "import_lock_wait_ms", "import_apply_ms", "gc_pause_ms")
 V5E = "TPU v5 lite"
 
 
@@ -31,16 +38,20 @@ def _kernel():
 # ----------------------------------------------------------------------
 # files and entries
 
-def test_cell_loads_with_its_four_metrics_and_not_the_other_eleven():
+def test_both_cells_still_list_what_they_brought_by_name():
+    """Subsets, by name: the mixed cell PR 29's four, the wide cell
+    its twelve, wherever they sit and whatever came since; and the
+    mixed cell the eleven terms of its lag that read its rings as
+    they read the wide cell's."""
     c = harness.cell(CELL)
-    assert c["chips"] == 1
-    assert [m["name"] for m in c["per_layer"]] == list(NEW)
-    assert [m["name"] for m in c["end_to_end"]] == ["flush_lag_ms",
-                                                    "setup_s"]
-    # the old cell gained the one metric that reads both
-    wide = harness.cell("local-wide-paced")
-    assert len(wide["per_layer"]) == 12
-    assert [m["name"] for m in wide["per_layer"]][-1] == "gc_pause_ms"
+    mixed = {m["name"] for m in c["per_layer"]}
+    assert set(NEW) <= mixed
+    assert {"flush_lag_ms", "setup_s"} <= {
+        m["name"] for m in c["end_to_end"]}
+    wide = {m["name"] for m in harness.cell(WIDE)["per_layer"]}
+    assert set(WIDE_TWELVE) <= wide
+    assert set(WIDE_TWELVE) <= mixed
+    assert "sink_route_ms" in wide & mixed
     assert c["traffic"]["mode"] == "paced"
 
 
@@ -78,7 +89,7 @@ def test_traffic_at_full_size_is_the_stated_mix():
     spec = harness.cell(CELL)["traffic"]
     assert (spec["rounds"], spec["rounds_per_interval"],
             spec["inflight"], spec["start_s"], spec["end_s"]) == (
-        32, 16, 32, 0.5, 8.5)
+        32, 16, 32, 0.1, 8.1)
     rounds = traffic.make_rounds(spec, seed=2900000011)
     assert len(rounds) == 32
     counters = []
@@ -100,15 +111,6 @@ def test_traffic_at_full_size_is_the_stated_mix():
 # ----------------------------------------------------------------------
 # the body, traced, at the tiny scale on the CPU
 
-def _run_py():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_run_py", os.path.join(ROOT, "benchmark", "run.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 def test_traced_rehearsal_is_correct_and_reads_what_the_cpu_can_show():
     c = harness.cell(CELL)
     res = harness.run_cell(c, seed=2900000012, seconds=4.0, trace=True,
@@ -122,13 +124,27 @@ def test_traced_rehearsal_is_correct_and_reads_what_the_cpu_can_show():
         "gauges", "set_members")) + int(
         TINY[CELL]["round"]["set_members"] * 0.05) + 20 * 6
     assert res["attempted"] >= 2 * 16 * per_round
-    m = {k: v["value"] for k, v in _run_py().result_line(
+    m = {k: v["value"] for k, v in run_py().result_line(
         c, res, trace=True)["metrics"].items()}
-    # no device plane on the CPU: the two device metrics are left out,
-    # never a 0
-    assert set(m) == {"reader_busy_pct", "gc_pause_ms"}
+    # no device plane on the CPU: the device's metrics are left out,
+    # never a 0; the lag's terms read this cell's rings
+    assert not {"merge_device_ms", "merge_roofline",
+                "device_idle_pct"} & set(m)
+    assert {"reader_busy_pct", "gc_pause_ms", "forward_encode_ms",
+            "forward_rpc_ms", "import_apply_ms", "import_decode_ms",
+            "flush_readout_ms", "host_emit_ms", "forward_ms",
+            "flush_lag_max_ms", "sender_late_ms",
+            "sink_route_ms"} <= set(m)
     assert 0 < m["reader_busy_pct"] < 100
     assert m["gc_pause_ms"] >= 0
+    assert 0 < m["sink_route_ms"] < m["host_emit_ms"]
+    assert m["flush_lag_max_ms"] >= 1e3 * max(run["lags"]["local"])
+    # readers and ``phase: cycles`` get the whole cycle record
+    from veneur_tpu.observe.flushring import FlushRecord
+    import dataclasses
+    fields = {f.name for f in dataclasses.fields(FlushRecord)}
+    assert all(set(r) == fields for ring in run["rings"].values()
+               for r in ring)
     # every cycle of both servers says that the program counts
     for ring in run["rings"].values():
         assert ring and all("gc" in r["stages"] for r in ring)
@@ -206,6 +222,7 @@ def _fixture_run():
         s = {"snapshot": 7 * MS, "swap_apply": 200 * MS,
              "dispatch": 9 * MS, "device_wait": 4 * MS,
              "host_emit": 150 * MS, "sink_flush": 900 * MS,
+             "sink_flush.route": 30_000,
              "forward": 880 * MS, "forward.encode": 450 * MS,
              "gc": pause}
         if pause:
@@ -216,6 +233,10 @@ def _fixture_run():
                       "gc.sink_flush": pause, "gc.swap_apply": 2 * MS,
                       "gc": pause + 2 * MS + 40 * MS})
         return s
+
+    ops = [["%tdigest_merge_c616_k256.1 tpu_custom_call", 0.046559301],
+           ["%tdigest_merge_c616_k96.1 tpu_custom_call", 0.045846175],
+           ["%sort.15", 0.016075443], ["%fusion", 0.006903836]]
 
     def registry(ns):
         return {"registry": {"kernels": {}, "readers": {
@@ -231,10 +252,8 @@ def _fixture_run():
             "global": []},
         "at_t0": {"t": 60.0, **registry(int(30e9))},
         "at_end": {"t": 100.0, **registry(int(30e9 + 0.43 * 40e9))},
-        "trace": {"busy_s": 0.1326, "window_s": 9.7124, "device_ops": [
-            ["%tdigest_merge_c616_k256.1 tpu_custom_call", 0.046559301],
-            ["%tdigest_merge_c616_k96.1 tpu_custom_call", 0.045846175],
-            ["%sort.15", 0.016075443], ["%fusion", 0.006903836]]}}
+        "trace": {"busy_s": 0.1326, "window_s": 9.7124,
+                  "device_ops": list(ops), "device_ops_all": list(ops)}}
 
 
 def _on_a_v5e(monkeypatch):
@@ -280,7 +299,7 @@ def test_reader_finds_nothing_on_a_run_without_its_counter(
                        if k != "gc" and not k.startswith("gc.")}
     run["at_t0"]["registry"].pop("readers")
     run["at_end"]["registry"]["readers"] = {}
-    run["trace"]["device_ops"] = run["trace"]["device_ops"][2:]
+    run["trace"]["device_ops_all"] = run["trace"]["device_ops_all"][2:]
     assert _reader(name)(run) is None
     run["trace"] = None
     assert _reader(name)(run) is None
@@ -294,19 +313,38 @@ def test_roofline_reader_raises_on_an_unknown_device(monkeypatch):
         _reader("merge_roofline")(_fixture_run())
 
 
-def test_new_entries_sit_at_the_end_of_their_lists():
-    with open(os.path.join(ROOT, "BENCHMARK.json"),
-              encoding="utf-8") as f:
-        bench = json.load(f)
-    assert bench["configs"][-1]["name"] == "local-defaults"
-    assert bench["workloads"][-1] == {
-        "name": CELL, "config": "local-defaults",
-        "traffic": "mixed-paced", "chips": 1,
-        "why": bench["workloads"][-1]["why"]}
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(NEW)
-    assert [m["workloads"] for m in bench["per_layer"][-4:]] == [
-        [CELL], [CELL], [CELL], ["local-wide-paced", CELL]]
-    # the eleven the benchmark had keep their lists
-    assert all(m["workloads"] == ["local-wide-paced"]
-               for m in bench["per_layer"][:11])
-    assert bench["run_seconds"] == 40
+def test_sink_route_reader_on_a_run_with_the_stage_and_without():
+    run = _fixture_run()
+    # 30 us a cycle over the window's four cycles
+    assert _reader("sink_route_ms")(run) == pytest.approx(0.03)
+    run["rings"]["local"][0]["stages"]["sink_flush.route"] = 4_030_000
+    assert _reader("sink_route_ms")(run) == pytest.approx(1.03)
+    # PR 31's program: no such stage in any cycle
+    for r in run["rings"]["local"]:
+        del r["stages"]["sink_flush.route"]
+    assert _reader("sink_route_ms")(run) is None
+
+
+def test_entries_are_found_by_name_wherever_they_sit(tmp_path,
+                                                     monkeypatch):
+    """The order of ``configs``, ``workloads``, ``per_layer`` and of a
+    metric's ``workloads`` carries no meaning: with every list of a
+    copy reversed the harness loads the same cells, and the file
+    keeps every property."""
+    bench = structure.load_bench(ROOT)
+    want = {n: harness.cell(n) for n in structure.cells(bench)}
+    for key in ("configs", "workloads", "end_to_end", "per_layer"):
+        bench[key].reverse()
+    for m in bench["per_layer"]:
+        m.get("workloads", []).reverse()
+    copy_benchmark(str(tmp_path), bench, monkeypatch)
+    structure.check_all(bench, str(tmp_path))
+    for name, c in want.items():
+        got = harness.cell(name)
+        assert (got["chips"], got["config"], got["traffic"]) == (
+            c["chips"], c["config"], c["traffic"])
+        for kind in ("end_to_end", "per_layer"):
+            def by_name(ms):
+                return {m["name"]: {**m, "workloads": sorted(
+                    m.get("workloads", []))} for m in ms}
+            assert by_name(got[kind]) == by_name(c[kind])

@@ -5,26 +5,27 @@ the files it names."""
 
 import json
 import os
-import re
 
 import pytest
 
-from bench_util import ROOT, TINY
+import structure
+from bench_util import ROOT, TINY, topology
 
 from benchmark import control, harness, reference, trace
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as f:
-    BENCH = json.load(f)
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-CELLS = [w["name"] for w in BENCH["workloads"]]
+BENCH = structure.load_bench(ROOT)
+CELLS = structure.cells(BENCH)
+# ``control.py`` is the control of the topology ``local-global``: its
+# cells, whichever they are
+CONTROL_CELLS = [n for n in CELLS if structure.topology_of(
+    BENCH, ROOT, n) == "local-global"]
 
 
 # ----------------------------------------------------------------------
 # the control
 
-@pytest.mark.parametrize("name", CELLS)
+@pytest.mark.parametrize("name", CONTROL_CELLS)
 @pytest.mark.parametrize("fault", control.FAULTS)
 def test_control_fails_and_faithful_reference_passes(name, fault):
     c = harness.cell(name)
@@ -72,6 +73,9 @@ def test_trace_reduction_on_the_recorded_fixture():
             m["total_s"])
     assert out["device_ops"][0][0] == want["top_op"]
     assert len(out["device_ops"]) <= 10 and len(out["idle_gaps"]) <= 10
+    # the ten are the head of the whole list, which no ranking cuts
+    assert out["device_ops"] == out["device_ops_all"][:10]
+    assert len(out["device_ops_all"]) >= len(out["device_ops"])
     assert out["idle_gaps"][0][0] == want["longest_gap_owner"]
     assert out["idle_gaps"][0][1] == pytest.approx(
         want["longest_gap_s"])
@@ -104,6 +108,31 @@ def test_union_and_hand_made_planes():
     assert out["device_ops"][0] == ["fusion.1", pytest.approx(0.3)]
     assert out["idle_gaps"][0] == ["ingest", pytest.approx(0.3)]
     assert ["flush_cycle", pytest.approx(0.2)] in out["idle_gaps"]
+
+
+def test_a_kernel_below_the_ten_longest_is_still_summed():
+    """Twelve operations, the merge kernel's two buckets the
+    shortest: the breakdown's ten do not hold them, the whole list
+    does, and ``merge_device_ms`` reads that."""
+    ops = [[f"%fusion.{i} = f32[8]{{0}} fusion(%p)", 1e6 * i, 1e5 * i]
+           for i in range(3, 13)]
+    ops += [['%tdigest_merge_c616_k96.1 = f32[8]{0} custom-call(%p), '
+             'custom_call_target="tpu_custom_call"', 2e8, 2e4],
+            ['%tdigest_merge_c616_k256.1 = f32[8]{0} custom-call(%p), '
+             'custom_call_target="tpu_custom_call"', 3e8, 1e4]]
+    data = {"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "t", "events": [
+            [trace.ANCHOR, 0.0, 1.0], [trace.ANCHOR, 1e9, 1.0]]}]},
+        {"name": "/device:TPU:0", "lines": [
+            {"name": trace.OPS_LINE, "events": ops}]}]}
+    out = trace.reduce(data, [1000.0, 1001.0], [])
+    assert len(out["device_ops"]) == 10
+    assert len(out["device_ops_all"]) == 12
+    assert not any("tdigest_merge" in n for n, _ in out["device_ops"])
+    read = harness.load_module("layer_metrics", "merge_device_ms").read
+    assert read({"trace": out}) == pytest.approx(1e3 * 3e-5)
+    # a trace reduced before the whole list was kept reads nothing
+    assert read({"trace": {"device_ops": out["device_ops"]}}) is None
 
 
 # ----------------------------------------------------------------------
@@ -148,91 +177,88 @@ _IMPORTS = [(90.0, 0), (100.4, 50), (100.7, 100), (110.3, 160),
 def test_tick_lag_ends_at_the_later_of_sink_and_forward(
         stamps, recs, want):
     srv = _Srv([_Rec(*r) for r in recs])
-    lags, missing = harness.tick_lags(srv, _Sink(stamps),
+    lags, missing = topology().tick_lags(srv, _Sink(stamps),
                                       [100.0, 110.0], 10.0, _IMPORTS)
     assert lags == pytest.approx(want[0]) and missing == want[1]
 
 
 def test_a_failed_cycle_is_a_missing_tick():
     srv = _Srv([_Rec(1, 100.01, 0, error="boom")])
-    assert harness.tick_lags(srv, _Sink([100.2]), [100.0], 10.0,
+    assert topology().tick_lags(srv, _Sink([100.2]), [100.0], 10.0,
                              _IMPORTS) == ([], 1)
 
 
 # ----------------------------------------------------------------------
-# BENCHMARK.json
+# BENCHMARK.json: every property by name (``structure.py``), on the
+# repo's own file here and on a copy with a later PR's additions in
+# ``test_bench_new_deployment.py``
 
 def test_benchmark_json_has_exactly_the_contracts_keys():
-    assert sorted(BENCH) == sorted([
-        "command", "paths", "run_seconds", "configs", "workloads",
-        "end_to_end", "per_layer"])
-    assert 1 <= BENCH["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
-    for m in BENCH["end_to_end"]:
-        assert sorted(set(m) - {"workloads"}) == [
-            "better", "bound", "name", "source", "unit"]
-        assert 0.01 <= m["bound"] <= 0.25
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in BENCH["per_layer"]:
-        assert sorted(set(m) - {"workloads"}) == [
-            "better", "layer", "moves", "name", "source", "unit"]
-        assert m["source"] in ("device_trace", "program_span",
-                               "program_counter", "host_clock")
+    structure.check_contract_keys(BENCH, ROOT)
+    assert BENCH["run_seconds"] == 40
 
 
 def test_every_name_and_unit_is_made_of_the_allowed_characters():
-    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-    names += [w["name"] for w in BENCH["workloads"]]
-    names += [w["traffic"] for w in BENCH["workloads"]]
-    names += [c["name"] for c in BENCH["configs"]]
-    names += [k for c in BENCH["configs"] for k in c["reduced"]]
-    assert all(NAME.match(n) for n in names), names
-    assert len(set(m["name"] for m in BENCH["end_to_end"]
-                   + BENCH["per_layer"])) == len(
-        BENCH["end_to_end"] + BENCH["per_layer"])
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-    for x in BENCH["workloads"] + BENCH["configs"]:
-        assert 1 <= len(x["why"]) <= 200 and "\n" not in x["why"]
-    for c in BENCH["configs"]:
-        assert 1 <= len(c["source"]) <= 200
+    structure.check_names_and_units(BENCH, ROOT)
+
+
+def test_configurations_are_used_and_four_chip_cells_are_the_fewer():
+    structure.check_configs_and_chips(BENCH, ROOT)
+
+
+@pytest.mark.parametrize("n_cells,n_four,ok", [
+    (1, 1, True), (2, 1, True), (3, 1, True), (3, 2, False),
+    (4, 2, True), (5, 3, False), (2, 2, False)])
+def test_four_chip_share_is_half_rounded_down_and_one_always(
+        n_cells, n_four, ok):
+    """The rule itself, on hand-made lists: the repo's own file has
+    no four-chip cell to show it on."""
+    bench = {"paths": ["benchmark"], "configs": [
+        {"name": f"c{i}", "file": f"benchmark/configs/c{i}.json"}
+        for i in range(n_cells)], "workloads": [
+        {"name": f"w{i}", "config": f"c{i}", "traffic": "t",
+         "chips": 4 if i < n_four else 1} for i in range(n_cells)]}
+    if ok:
+        structure.check_configs_and_chips(bench, ROOT)
+    else:
+        with pytest.raises(AssertionError):
+            structure.check_configs_and_chips(bench, ROOT)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"],
                          ids=lambda m: m["name"])
 def test_per_layer_metric_has_a_reader_and_moves_a_reported_metric(
         metric):
+    structure.check_metric(BENCH, ROOT, metric)
+    # the harness finds the same reader by the same name
     reader = harness.load_module("layer_metrics", metric["name"])
-    assert (reader.LAYER, reader.UNIT, reader.MOVES) == (
-        metric["layer"], metric["unit"], metric["moves"])
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    moved = e2e[metric["moves"]]
-    for cell_name in metric.get("workloads", CELLS):
-        assert cell_name in moved.get("workloads", CELLS)
-    if metric["name"].endswith("_roofline"):
-        assert metric["unit"] == "%"
+    assert reader.MOVES == metric["moves"]
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_cell_is_nothing_but_files_and_entries(name):
+    structure.check_cell(BENCH, ROOT, name)
+    # and the harness reads the same files
     c = harness.cell(name)
     entry = next(w for w in BENCH["workloads"] if w["name"] == name)
-    assert entry["chips"] == 1
-    cfg = next(x for x in BENCH["configs"]
-               if x["name"] == entry["config"])
-    assert cfg["file"] == f"benchmark/configs/{entry['config']}.json"
-    assert c["config"]["source"] == cfg["source"]
-    assert sorted(c["config"]["reduced"]) == sorted(cfg["reduced"])
+    assert c["chips"] == entry["chips"]
+    assert c["config"]["name"] == entry["config"]
     assert c["traffic"]["name"] == entry["traffic"]
-    assert os.path.exists(os.path.join(
-        ROOT, "benchmark", "modes", c["traffic"]["mode"] + ".py"))
-    names = {m["name"] for m in c["end_to_end"]}
-    assert "setup_s" in names and len(names) >= 2
-    assert c["per_layer"]
-    # every number compared has a limit in the configuration's file
-    assert set(c["config"]["limits"]) == {
-        "sums_off", "readings_missing", "p99_out", "p50_rank_err",
-        "p90_rank_err", "card_rel_err", "lines_unaccounted", "dropped",
-        "ticks_missing", "sender_blocked_pct"}
+    assert {m["name"] for m in c["per_layer"]} == {
+        m["name"] for m in BENCH["per_layer"]
+        if name in structure.reports(BENCH, m)}
+
+
+def test_a_limit_without_a_number_is_refused_before_any_result(
+        monkeypatch):
+    """``run_cell`` holds the comparison to the configuration's
+    limits name for name: a limit that nothing is compared with, or a
+    number without a limit, is a run that could not be checked."""
+    stub = type("Topo", (), {
+        "serve": staticmethod(lambda *a: {"lags": {}, "rings": {}}),
+        "compare": staticmethod(lambda s, limits: ({"a": 0}, 0))})
+    monkeypatch.setattr(harness, "load_module", lambda *a: stub)
+    c = {"name": "x", "traffic": {}, "config": {"limits": {
+        "a": 0, "b": 0}}}
+    with pytest.raises(reference.Failed, match="limits name"):
+        harness.run_cell(c, seed=1, seconds=1.0, trace=False)
