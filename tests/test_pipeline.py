@@ -145,6 +145,51 @@ def test_fused_merge_bit_identical_vs_perwire():
     assert float(sw.sum()) == float(lw.sum()) > 0
 
 
+def test_wire_digests_leave_staging_with_every_step():
+    """Forwarded wires' digests are folded by the next device step and
+    not held for the swap, where the last wires of a burst would merge
+    on the flush's path; raw samples still wait for theirs.  The
+    interval's result is the same as with every wire held to the end:
+    each step merges its wires in arrival order."""
+    def stage(table, wires):
+        rng = np.random.default_rng(11)
+        for _w in range(wires):
+            row = table.import_histo_row("lat", "timer", ())
+            x = np.sort(rng.gamma(3.0, 10.0, 20)).astype(np.float32)
+            table.import_histo_batch(
+                np.asarray([row], np.int32),
+                np.asarray([[20.0, x[0], x[-1], x.sum(), 1.0]],
+                           np.float32),
+                np.full(20, row, np.int32), x, np.ones(20, np.float32))
+
+    stepped = MetricTable(TableConfig())
+    stepped.collective_import_mode = "off"
+    stepped.ingest_buffer(b"raw:5|ms")
+    stage(stepped, 3)
+    work = stepped.take_staged()
+    assert [len(p[0]) for p in work.wire_parts] == [20, 20, 20]
+    assert work.histo is None and stepped._wire_digest_parts == []
+    assert len(stepped._histo_stage) == 1
+    stepped.apply_staged(work)
+    counts = stepped._state.import_counts
+    assert counts["steps_flat"] + counts["steps_stack"] == 1
+    assert counts["centroids"] == 60
+    stage(stepped, 1)
+    snap = stepped.swap()
+    assert snap.import_counts["centroids"] == 80
+    assert (snap.import_counts["steps_flat"]
+            + snap.import_counts["steps_stack"]) == 2
+
+    held = MetricTable(TableConfig())
+    held.collective_import_mode = "off"
+    held.ingest_buffer(b"raw:5|ms")
+    stage(held, 3)
+    stage(held, 1)
+    ref = held.swap()
+    assert float(np.asarray(snap.histo_weights).sum()) \
+        == float(np.asarray(ref.histo_weights).sum()) == 81.0
+
+
 @pytest.mark.slow
 def test_pipeline_and_serial_flush_outputs_agree():
     """Perf-smoke (CPU, small shapes): the overlapped pipeline and the

@@ -132,6 +132,9 @@ def test_http_chain_single_stitched_trace(make_server):
 
 IMPORT_STEPS = ("import.decode", "import.lock_wait", "import.apply",
                 "import.device_step")
+# the three parts of ``import.apply``, children of its span
+APPLY_STEPS = ("import.apply.resolve", "import.apply.digests",
+               "import.apply.sets")
 
 
 def test_grpc_chain_single_stitched_trace(make_server):
@@ -183,7 +186,7 @@ def test_grpc_chain_single_stitched_trace(make_server):
     # the global's tree, as /debug/trace/<id> serves it: import under
     # the local's forward.send, its steps under import, all with a
     # real extent
-    assert len(glob.trace_index.get(tid)) == 5
+    assert len(glob.trace_index.get(tid)) == 5 + len(APPLY_STEPS)
     d = json.loads(urllib.request.urlopen(
         f"http://127.0.0.1:{glob.http_port}/debug/trace/{tid}",
         timeout=5).read())
@@ -198,6 +201,11 @@ def test_grpc_chain_single_stitched_trace(make_server):
         assert spans[step]["parent_id"] == imp["span_id"]
         assert imp["start_ns"] <= spans[step]["start_ns"]
         assert spans[step]["end_ns"] <= imp["end_ns"]
+    app = spans["import.apply"]
+    for step in APPLY_STEPS:
+        assert spans[step]["parent_id"] == app["span_id"]
+        assert app["start_ns"] <= spans[step]["start_ns"]
+        assert spans[step]["end_ns"] <= app["end_ns"]
     # the import ran inside the local's send, on one wall clock
     assert send["start_ns"] <= imp["start_ns"]
     assert imp["end_ns"] <= send["end_ns"]
@@ -210,9 +218,22 @@ def test_grpc_chain_single_stitched_trace(make_server):
     steps = sum(grec.stages[k] for k in IMPORT_STEPS)
     assert grec.stages["import"] >= steps > 0
     assert grec.stages["import"] <= snd
+    # the fold's three parts inside ``import.apply``, and what the
+    # interval's one wire came to: one digest fold of its centroids,
+    # no sketch
+    parts = sum(grec.stages[k] for k in APPLY_STEPS)
+    assert grec.stages["import.apply"] >= parts > 0
+    assert grec.import_steps_flat + grec.import_steps_stack == 1
+    assert (grec.import_centroids, grec.import_spilled_centroids,
+            grec.import_set_planes) == (50, 0, 0)
+    assert json.loads(glob.flush_ring.to_json(1))[0][
+        "import_centroids"] == 50
     glob.flush_once()
     grec = glob.flush_ring.records()[-1]
     assert grec.imports == 0 and "import" not in grec.stages
+    assert (grec.import_steps_flat, grec.import_steps_stack,
+            grec.import_centroids, grec.import_set_planes) == (
+        0, 0, 0, 0)
 
 
 def test_forward_encode_span_counts_centroids(make_server):
@@ -302,6 +323,13 @@ def test_row_counts_by_class_on_encode_import_and_swap(make_server):
     assert int(gswap["histo_samples"]) >= 7 * 12
     assert int(gswap["histo_rows"]) >= 7
     assert int(gswap["set_rows"]) >= 5
+    # and its record what the interval's imports came to: as many
+    # register planes as sketches were sent
+    grec = glob.flush_ring.records()[-1]
+    assert (grec.imports, grec.import_set_planes,
+            grec.import_centroids) == (1, 5, 7 * 12)
+    assert grec.import_steps_flat + grec.import_steps_stack >= 1
+    assert all(grec.stages[k] > 0 for k in APPLY_STEPS)
 
     # nothing to forward: no forward span, so no counts
     local.handle_packet(b"rc.c.0:1|c")

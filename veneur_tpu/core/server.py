@@ -2325,6 +2325,10 @@ class Server:
                         table_overflow=snap.overflow,
                         kernel_drops=kdrops)
                     self._take_imports(cyc.record)
+        # the closed interval's last fold has run: its counts are
+        # final (a table without them leaves the record's at 0)
+        for k, v in getattr(snap, "import_counts", {}).items():
+            setattr(cyc.record, f"import_{k}", v)
         # dispatch / device_wait / host_emit stages happen inside the
         # flusher, against the same cycle
         res = self.flusher.flush(snap, cycle=cyc)

@@ -226,12 +226,22 @@ class _IntervalState:
                  "histo_weights", "hll_regs", "hll_host_plane",
                  "hll_host_ez", "hll_host_inv", "hll_device_touched",
                  "histo_compact", "set_sparse", "set_dense_overflow",
-                 "tier_frozen")
+                 "tier_frozen", "import_counts")
 
     def __init__(self, gen: int):
         self.gen = gen
         self.pending = 0
         self.fresh: set = set()
+        # what the imports folded into this interval came to, counted
+        # where the folds run (``_wire_digest_step``, the imported
+        # digests' ``_histo_device_step``, ``import_set_at``): digest
+        # folds that took the flat ranked merge and the stacked scan,
+        # centroids the stack left to the flat merge, centroids and
+        # register planes in all.  The snapshot carries it to the
+        # cycle's ``FlushRecord`` (``import_*``).
+        self.import_counts = dict.fromkeys(
+            ("steps_flat", "steps_stack", "spilled_centroids",
+             "centroids", "set_planes"), 0)
         self.hll_host_plane: np.ndarray | None = None
         self.hll_host_ez: np.ndarray | None = None
         self.hll_host_inv: np.ndarray | None = None
@@ -525,6 +535,10 @@ class Snapshot:
     # consumer that dispatches on it first falls through to today's
     # exact code paths when absent.
     tiers: Any = None
+    # what the interval's imports came to (``_IntervalState``'s
+    # ``import_counts``), final once ``complete_swap`` has applied the
+    # last staged work; empty where the table keeps no such count
+    import_counts: dict[str, int] = field(default_factory=dict)
 
     @property
     def host_only_sets(self) -> bool:
@@ -746,7 +760,6 @@ class MetricTable:
         # (rows, means, weights), stacked at apply time into one
         # (n_wires, rows, K) kernel call — see _wire_digest_step
         self._wire_digest_parts: list[tuple] = []
-        self._wire_digest_n = 0
         self.fused_import_mode = _fused_import_mode()
         # widest ladder bucket the stacked merge may use per wire;
         # rows deeper than this in one wire spill to the ranked path
@@ -1361,6 +1374,7 @@ class MetricTable:
         self.set_idx.last_gen[row] = self.gen
         self._staged_n += 1
         self._interval_ingested += 1
+        self._state.import_counts["set_planes"] += 1
 
     def import_counter(self, name: str, tags: tuple[str, ...],
                        value: float) -> bool:
@@ -1471,11 +1485,10 @@ class MetricTable:
                 # by within-row rank into one flat ranked merge
                 self._digest_stage.append(*part)
             else:
-                # one part per wire list: the apply stacks the whole
-                # cycle into a single (n_wires, rows, K) kernel call
-                # (_wire_digest_step)
+                # one part per wire list: the apply stacks what is
+                # staged at the next step into a single (n_wires,
+                # rows, K) kernel call (_wire_digest_step)
                 self._wire_digest_parts.append(part)
-                self._wire_digest_n += len(cent_rows)
             self._staged_n += len(cent_rows)
 
     def import_set(self, name: str, tags: tuple[str, ...],
@@ -1513,7 +1526,9 @@ class MetricTable:
         Histo/digest AND set staging only flush when ``final`` (the
         swap) or past ``histo_merge_samples`` — per-step digest merges
         multiply cluster work by the number of steps per interval, and
-        whole-interval set batches dedup into the register plane."""
+        whole-interval set batches dedup into the register plane.
+        Forwarded wires' digests go with every step: each is a batch
+        already (``_detach_staged``)."""
         w = self._detach_staged(final)
         if w.empty:
             return
@@ -1561,7 +1576,8 @@ class MetricTable:
         Mid-interval essentially all staged mass is host-side: dense
         counter/gauge accumulators only ship at the swap, and the
         list stagings detach early only past the histo_merge_samples
-        (4M-sample) / 64K-stat-row thresholds.  Whatever DID move to
+        (4M-sample) / 64K-stat-row thresholds, forwarded wires'
+        digests with any step.  Whatever DID move to
         device state early is counted in ``device_staged`` so the
         checkpoint names its blind spot instead of hiding it.
 
@@ -1664,11 +1680,17 @@ class MetricTable:
                 len(self._digest_stage) >= c.histo_merge_samples):
             w.digest = self._digest_stage
             self._digest_stage = _Staging()
-        if self._wire_digest_parts and (
-                final or self._wire_digest_n >= c.histo_merge_samples):
+        # a forwarded wire's digests are a batch already, and merging
+        # them alone costs no more than beside other wires (one merge
+        # a row a wire on the stack, one a row a step on the flat
+        # path), so every step takes the wires that are staged.  Held
+        # for the swap, the last wires of the locals' burst merge on
+        # the flush's path: 0.1 to 0.35 s of a global's 0.14 to 0.4 s
+        # lag on the v5e, by which locals happened to call last
+        # (PERF.md, finding 34.2)
+        if self._wire_digest_parts:
             w.wire_parts = self._wire_digest_parts
             self._wire_digest_parts = []
-            self._wire_digest_n = 0
         staged_sets = (len(self._set_rows) +
                        sum(len(r) for r in self._set_pos_rows))
         if (staged_sets and
@@ -1677,9 +1699,10 @@ class MetricTable:
                            self._set_pos_rows, self._set_pos)
             self._set_rows, self._set_members = [], []
             self._set_pos_rows, self._set_pos = [], []
-        # Import-side staging flushes at the swap like the digest
-        # stage: a global node receiving K wire lists per interval
-        # otherwise pays K small dispatches (and, for sets, ships
+        # The imports' statistics and register planes flush at the
+        # swap like the digest stage: a global node receiving K wire
+        # lists per interval otherwise pays K small dispatches of a
+        # few rows (and, for sets, ships
         # every list's register planes separately — the cross-list
         # dedup collapsed 64 MB/interval to ~2 MB once deferred).
         # Size gates bound host staging between swaps.
@@ -1745,6 +1768,10 @@ class MetricTable:
         if w.digest is not None:
             batch = w.digest.take()
             if batch is not None:
+                # imported centroids that came one digest at a time
+                # (or under the legacy fused-import mode)
+                st.import_counts["steps_flat"] += 1
+                st.import_counts["centroids"] += len(batch[0])
                 if self.tiers is None:
                     self._histo_device_step(st, *batch,
                                             with_stats=False)
@@ -2865,7 +2892,11 @@ class MetricTable:
                     if tdigest.resolved_merge_mode() == "pallas"
                     else "legacy")
 
+        counts = st.import_counts
+        counts["centroids"] += sum(len(p[0]) for p in parts)
+
         def _flat() -> None:
+            counts["steps_flat"] += 1
             self._histo_device_step(
                 st, np.concatenate([p[0] for p in parts]),
                 np.concatenate([p[1] for p in parts]),
@@ -2901,6 +2932,8 @@ class MetricTable:
         idx_dev = jnp.asarray(_pad_np(
             uniq.astype(np.int32), mb, c.histo_rows))
         self._ensure_fresh(st, "histo")
+        counts["steps_stack"] += 1
+        counts["spilled_centroids"] += len(spill)
         if mode == "stack":
             fold = self._collective_wire_fold()
             wb = _bucket_len(len(built), wide=True)
@@ -3148,6 +3181,7 @@ class MetricTable:
             overflow=pend.overflow,
             ingested=pend.ingested,
             tiers=snap_tiers,
+            import_counts=dict(st.import_counts),
         )
 
     def _tier_boundary(self, pend: _PendingSwap,

@@ -18,6 +18,7 @@ needed.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import struct
 from concurrent import futures
@@ -862,23 +863,39 @@ def _resolve_rows(table: MetricTable, data: bytes, cols: dict,
     return rows
 
 
-def apply_decoded(table: MetricTable, data: bytes,
-                  cols: dict) -> tuple[int, int]:
+def apply_decoded(table: MetricTable, data: bytes, cols: dict,
+                  step=contextlib.nullcontext) -> tuple[int, int]:
     """The LOCKED half: resolve rows through the plan/row caches and
     stage every value with vectorized batch appliers.  Value-level
     validity (finiteness, HLL codec) is re-checked per wire — only
     series IDENTITY is cached, so a gauge that is NaN this interval
-    and finite the next is not penalized."""
+    and finite the next is not penalized.  ``step(name)`` times the
+    three parts (``resolve``, ``digests``, ``sets``): the import
+    handler passes its ``import.apply`` span's."""
     nm = cols["n"]
     if nm == 0:
         return 0, 0
     kind = cols["kind"][:nm]
-    rows = _resolve_rows(table, data, cols, cols["khash"])
+    with step("resolve"):
+        rows = _resolve_rows(table, data, cols, cols["khash"])
+    valid = rows >= 0
+    dropped = int((~valid).sum())
+    with step("digests"):
+        acc, bad = _apply_scalars_and_digests(table, cols, kind, rows,
+                                              valid)
+    with step("sets"):
+        acc_s, bad_s = _apply_sets(table, data, cols, kind, rows, valid)
+    return acc + acc_s, dropped + bad + bad_s
+
+
+def _apply_scalars_and_digests(table: MetricTable, cols: dict,
+                               kind: np.ndarray, rows: np.ndarray,
+                               valid: np.ndarray) -> tuple[int, int]:
+    """Counters, gauges and histograms of a decoded wire, staged by
+    the batch appliers; (accepted, dropped for their values)."""
+    nm = cols["n"]
     dropped = 0
     accepted = 0
-
-    valid = rows >= 0
-    dropped += int((~valid).sum())
 
     # counters: += accumulate (no finiteness gate, matching
     # import_counter / reference Counter.Merge)
@@ -975,9 +992,17 @@ def apply_decoded(table: MetricTable, data: bytes,
                 rows[sel_ok].astype(np.int32), stats_mat,
                 rep_rows[live], cm[live], cw[live])
             accepted += int(ok_h.sum())
+    return accepted, dropped
 
-    # sets: the HLL codec decode stays per item (value-level), but
-    # row resolution and name/tag decode are skipped on cache hits
+
+def _apply_sets(table: MetricTable, data: bytes, cols: dict,
+                kind: np.ndarray, rows: np.ndarray,
+                valid: np.ndarray) -> tuple[int, int]:
+    """The sketches of a decoded wire, each decoded and unioned into
+    its row's host plane; (accepted, dropped).  The HLL codec decode
+    stays per item (value-level), but row resolution and name/tag
+    decode are skipped on cache hits."""
+    accepted = dropped = 0
     sels = np.nonzero(valid & (kind == 4))[0]
     for i in sels:
         ho, hl = int(cols["hll_off"][i]), int(cols["hll_len"][i])
@@ -1043,6 +1068,20 @@ def apply_metric_list_bytes(table: MetricTable,
 # ----------------------------------------------------------------------
 # server (importsrv equivalent)
 
+# Handler threads of one listener.  The reference gives each call a
+# goroutine; here a handler's fold runs under the server's ingest lock
+# and the interpreter's, so threads past the few that can decode ahead
+# of the lock only move a burst's wait from the executor's queue to
+# ``import.lock_wait``.  Measured on the v5e with 64 locals' calls of
+# 4.3 MB inside 0.75 s (PERF.md, finding 34.3): a pool of 64 against
+# this one lengthened an interval's longest call from 0.42-0.61 s to
+# 0.51-0.75 s and its last acknowledgement from tick + 1.25-1.44 s to
+# tick + 1.35-1.58 s, and left the flush where it was.  A call waits
+# for a worker at most as long as the burst takes to fold, which has
+# to stay under the locals' ``forward_timeout`` whatever the pool.
+IMPORT_WORKERS = 8
+
+
 class ImportServer:
     """gRPC listener merging forwarded MetricLists into a table.
 
@@ -1059,7 +1098,7 @@ class ImportServer:
             raise RuntimeError("grpcio unavailable")
         self._core = server
         self._grpc = grpc.server(
-            futures.ThreadPoolExecutor(max_workers=8),
+            futures.ThreadPoolExecutor(max_workers=IMPORT_WORKERS),
             options=[("grpc.max_receive_message_length",
                       64 * 1024 * 1024)])
         from veneur_tpu.protocol.gen import (dogstatsd_grpc_pb2,
@@ -1156,8 +1195,9 @@ class ImportServer:
                 if cols is None:
                     acc, dropped = apply_metric_list(core.table, pb)
                 else:
-                    acc, dropped = apply_decoded(core.table, request,
-                                                 cols)
+                    acc, dropped = apply_decoded(
+                        core.table, request, cols,
+                        step=lambda name: imp.step(f"apply.{name}", sp))
                 if ledger is not None:
                     # the overflow delta splits this wire's drops into
                     # overflow (the table counted them) vs invalid

@@ -48,6 +48,17 @@ class FlushRecord:
     # import wires this server folded since its previous cycle; their
     # handler durations are in ``stages`` under ``import[.<step>]``
     imports: int = 0
+    # what those wires' folds came to in the interval this cycle
+    # closed, counted where they run (``core/table.py``
+    # ``_IntervalState.import_counts``): digest folds through the
+    # flat ranked merge and through the stacked scan, centroids the
+    # stack left to the flat merge, centroids folded in all, and
+    # register planes unioned on the host
+    import_steps_flat: int = 0
+    import_steps_stack: int = 0
+    import_spilled_centroids: int = 0
+    import_centroids: int = 0
+    import_set_planes: int = 0
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
     # collector pauses that ended inside the cycle, on any thread
@@ -74,6 +85,12 @@ class FlushRecord:
                 "ident_cached": self.ident_cached,
                 "sink_only_rows": self.sink_only_rows,
                 "imports": self.imports,
+                "import_steps_flat": self.import_steps_flat,
+                "import_steps_stack": self.import_steps_stack,
+                "import_spilled_centroids":
+                    self.import_spilled_centroids,
+                "import_centroids": self.import_centroids,
+                "import_set_planes": self.import_set_planes,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
                 "gc_pause_ns": self.gc_pause_ns,

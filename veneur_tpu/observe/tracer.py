@@ -41,7 +41,13 @@ stage before the dot:
            |         +- import.lock_wait    asking for the ingest
            |         |                      lock to having it
            |         +- import.apply        fold + dedup + ledger
-           |         |                      credit, under the lock
+           |         |    |                 credit, under the lock
+           |         |    +- import.apply.resolve  wire items -> rows
+           |         |    +- import.apply.digests  scalars, digest
+           |         |    |                        stats and centroids
+           |         |    |                        staged
+           |         |    +- import.apply.sets     sketches decoded and
+           |         |                             unioned on the host
            |         +- import.device_step  the staged apply, if the
            |                                wire crossed the threshold
            +- flush.forward.shard   sharded path: one per destination
@@ -273,7 +279,10 @@ class ImportSpan:
 
     def _timed(self, span, name: str):
         return _traced(span, name, self._client, self._index,
-                       functools.partial(self.stages.__setitem__, name))
+                       functools.partial(self._add, name))
+
+    def _add(self, name: str, ns: int) -> None:
+        self.stages[name] = self.stages.get(name, 0) + ns
 
     def __enter__(self):
         self._whole = self._timed(self.span, "import")
@@ -286,10 +295,12 @@ class ImportSpan:
         finally:
             self._note(self.stages)
 
-    def step(self, name: str):
-        """Time ``import.<name>`` as a child of the import span."""
+    def step(self, name: str, parent=None):
+        """Time ``import.<name>`` as a child of the import span, or of
+        ``parent`` for a dotted step (``apply.sets`` under the
+        ``apply`` step's span); a step entered twice accumulates."""
         name = f"import.{name}"
-        sp = self.span.child(name)
+        sp = (parent or self.span).child(name)
         sp.add_tag("veneur.internal", "true")
         return self._timed(sp, name)
 
