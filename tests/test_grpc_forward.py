@@ -767,3 +767,191 @@ def test_decode_scratch_cap_and_shrink(monkeypatch):
                 gf._scratch_bytes.pop(tid, None)
             else:
                 gf._scratch_bytes[tid] = saved_bytes
+
+
+# ----------------------------------------------------------------------
+# a wire's dense sketches in one native pass (MetricTable.import_set_wire)
+# against the per-item decode + import_set_at
+
+def _sketch(seed, b=0, p=14, flag=0, size=hll.M // 2, body=hll.M // 2,
+            tail=b""):
+    """One dense axiomhq sketch as bytes, any header field or the
+    body's length made wrong on request."""
+    packed = np.random.default_rng(seed).integers(
+        0, 256, body, dtype=np.uint8)
+    return (bytes([1, p, b, flag]) + size.to_bytes(4, "big")
+            + packed.tobytes() + tail)
+
+
+def _sparse_sketch(n=40):
+    keys = sorted({_encode_sparse_key(int(h)) for h in hashing.hash64(
+        [f"loose-{i}".encode() for i in range(n)])})
+    body = bytearray([1, 14, 0, 1]) + len(keys).to_bytes(4, "big")
+    for k in keys:
+        body += int(k).to_bytes(4, "big")
+    return bytes(body) + bytes(12)  # an empty compressed list
+
+
+def _set_wire(items):
+    """(name, sketch bytes) pairs, and counters where the sketch is
+    None, as a MetricList on the wire."""
+    ms = []
+    for name, sk in items:
+        if sk is None:
+            m = metric_pb2.Metric(name=name, type=metric_pb2.Counter)
+            m.counter.value = 2
+        else:
+            m = metric_pb2.Metric(name=name, type=metric_pb2.Set)
+            m.set.hyper_log_log = sk
+        ms.append(m)
+    return forward_pb2.MetricList(metrics=ms).SerializeToString()
+
+
+class _PerItemTable(MetricTable):
+    """A table without the batch entry: ``_apply_sets`` runs its
+    per-item body for every sketch, as for a ``ShardedTable``."""
+    import_set_wire = None
+
+
+# name -> (items, sketches that go one by one, dropped)
+SET_WIRES = {
+    "950_distinct_rows": (
+        [(f"s{i}", _sketch(i)) for i in range(950)], 0, 0),
+    "one_row_three_times": (
+        [("s", _sketch(1)), ("t", _sketch(2)), ("s", _sketch(3)),
+         ("s", _sketch(4, b=2))], 0, 0),
+    "base_0_3_250": (
+        [("b0", _sketch(5, b=0)), ("b3", _sketch(6, b=3)),
+         ("b250", _sketch(7, b=250)), ("b3", _sketch(8, b=250))], 0, 0),
+    "bad_among_good": (
+        [("g0", _sketch(10)), ("sparse", _sparse_sketch()),
+         ("g1", _sketch(11, b=1)), ("short", _sketch(12, body=8191)),
+         ("g0", _sketch(13)), ("p12", _sketch(14, p=12)),
+         ("size", _sketch(15, size=4096, body=4096)),
+         ("stub", b"\x01\x0e"), ("flag2", _sketch(16, flag=2)),
+         ("tail", _sketch(17, tail=b"xyz")), ("c", None),
+         ("g2", _sketch(18))],
+        # sparse and flag2 decode one by one; short, p12, size, stub drop
+        6, 4),
+    "empty": ([], 0, 0),
+    "no_sets": ([("c0", None), ("c1", None)], 0, 0),
+}
+
+
+def _fold(table, wire):
+    from veneur_tpu.forward.grpc_forward import apply_decoded
+    return apply_decoded(table, wire, decode_metric_list(wire))
+
+
+def _import_state(t):
+    plane = t._set_import_plane
+    rows = t.config.set_rows
+    return {
+        "plane": (np.zeros((rows, hll.M), np.uint8) if plane is None
+                  else plane),
+        "plane_touched": (np.zeros(rows, bool)
+                          if t._set_import_touched is None
+                          else t._set_import_touched),
+        "touched": t.set_idx.touched, "last_gen": t.set_idx.last_gen,
+        "staged": t._staged_n, "ingested": t._interval_ingested,
+        "counts": {k: v for k, v in t._state.import_counts.items()
+                   if k != "set_planes_loose"}}
+
+
+def _assert_same_import(a, b):
+    sa, sb = _import_state(a), _import_state(b)
+    for k in ("plane", "plane_touched", "touched", "last_gen"):
+        np.testing.assert_array_equal(sa[k], sb[k], err_msg=k)
+    assert {k: sa[k] for k in ("staged", "ingested", "counts")} == {
+        k: sb[k] for k in ("staged", "ingested", "counts")}
+
+
+@pytest.mark.parametrize("case", SET_WIRES)
+def test_set_wire_native_pass_equals_per_item(case):
+    """One native pass over a wire's sketches leaves the table as the
+    per-item decode + import_set_at does, plane and bookkeeping; what
+    it hands back is counted as loose."""
+    from veneur_tpu import native
+    if native.load() is None:
+        pytest.skip("no native library")
+    items, loose, dropped = SET_WIRES[case]
+    wire = _set_wire(items)
+    cfg = TableConfig(counter_rows=64, gauge_rows=64, histo_rows=64,
+                      set_rows=1024 if len(items) > 100 else 16)
+    batch, single = MetricTable(cfg), _PerItemTable(cfg)
+    got, want = _fold(batch, wire), _fold(single, wire)
+    assert got == want == (len(items) - dropped, dropped)
+    _assert_same_import(batch, single)
+    sets = sum(sk is not None for _, sk in items)
+    counts = batch._state.import_counts
+    assert counts["set_planes"] == sets - dropped
+    assert counts["set_planes_loose"] == loose
+    assert single._state.import_counts["set_planes_loose"] == 0
+    if sets:
+        assert batch._set_import_touched.sum() == len(
+            {n for n, sk in items if sk is not None}) - dropped
+    # a second wire into the same interval goes on from there
+    assert _fold(batch, wire) == _fold(single, wire)
+    _assert_same_import(batch, single)
+    # and the swap reads the same registers out of both
+    np.testing.assert_array_equal(batch.swap().set_registers(),
+                                  single.swap().set_registers())
+
+
+def test_set_wire_without_native_library_goes_per_item():
+    """A table whose library is gone (the wire was decoded by a
+    process that had it) takes the per-item path whole and says so."""
+    from veneur_tpu import native
+    if native.load() is None:
+        pytest.skip("no native library")
+    items, _, dropped = SET_WIRES["bad_among_good"]
+    wire = _set_wire(items)
+    cfg = TableConfig(counter_rows=64, gauge_rows=64, histo_rows=64,
+                      set_rows=16)
+    bare, single = MetricTable(cfg), _PerItemTable(cfg)
+    bare._lib = None
+    assert _fold(bare, wire) == _fold(single, wire)
+    _assert_same_import(bare, single)
+    sets = sum(sk is not None for _, sk in items)
+    assert bare._state.import_counts["set_planes_loose"] == sets
+
+
+def test_set_wire_native_pass_matches_codec_registers():
+    """The plane a wire leaves is ``hll_codec.decode``'s registers,
+    maxed a row: the native unpack against the reference decoder
+    itself, tail-cut base and uint8 wrap included."""
+    from veneur_tpu import native
+    if native.load() is None:
+        pytest.skip("no native library")
+    items = SET_WIRES["base_0_3_250"][0]
+    t = MetricTable(TableConfig(set_rows=16))
+    _fold(t, _set_wire(items))
+    want: dict = {}
+    for name, sk in items:
+        regs = hll_codec.decode(sk)
+        want[name] = np.maximum(want.get(name, 0), regs)
+    for name, regs in want.items():
+        row = t.import_set_row(name, ())
+        np.testing.assert_array_equal(t._set_import_plane[row], regs)
+
+
+def test_apply_sets_span_tags_say_how_many_went_loose():
+    import contextlib
+
+    from veneur_tpu.forward.grpc_forward import apply_decoded
+
+    class Tags(dict):
+        add_tag = dict.__setitem__
+
+    spans: dict = {}
+    items, loose, _ = SET_WIRES["bad_among_good"]
+    wire = _set_wire(items)
+    apply_decoded(
+        MetricTable(TableConfig(set_rows=16)), wire,
+        decode_metric_list(wire),
+        step=lambda name: contextlib.nullcontext(
+            spans.setdefault(name, Tags())))
+    sets = sum(sk is not None for _, sk in items)
+    assert spans["sets"] == {"planes": str(sets),
+                             "planes_loose": str(loose)}
+    assert spans["resolve"] == spans["digests"] == {}

@@ -361,3 +361,55 @@ def test_sharded_import_validates_before_staging():
     with pytest.raises(ValueError, match="register plane"):
         t.import_set("s", (), np.zeros(7, np.uint8))
     assert t.staged() == 0
+
+
+def test_sharded_import_of_a_wire_with_sets_goes_sketch_by_sketch(
+        monkeypatch):
+    """A ``ShardedTable`` has no host import plane: the sketches of a
+    natively decoded wire still go one by one through
+    ``hll_codec.decode`` and its own ``import_set_at`` (register
+    positions staged to a shard), not through ``MetricTable``'s one
+    native pass."""
+    import numpy as np
+
+    from veneur_tpu.core.flusher import Flusher
+    from veneur_tpu.forward import hll_codec
+    from veneur_tpu.forward.gen import forward_pb2, metric_pb2
+    from veneur_tpu.forward.grpc_forward import (apply_metric_list_bytes,
+                                                 decode_metric_list)
+    from veneur_tpu.ops import hll
+    from veneur_tpu.parallel import (ShardedConfig, ShardedTable,
+                                     make_mesh)
+    from veneur_tpu.utils import hashing
+
+    ms = []
+    for s, n in (("u.a", 300), ("u.b", 40), ("u.a", 200)):
+        idx, rank = hashing.hll_position(hashing.hash64(
+            [f"{s}-{n}-{i}".encode() for i in range(n)]))
+        regs = np.zeros(hll.M, np.uint8)
+        np.maximum.at(regs, idx, rank.astype(np.uint8))
+        m = metric_pb2.Metric(name=s, type=metric_pb2.Set)
+        m.set.hyper_log_log = hll_codec.encode_dense(regs)
+        ms.append(m)
+    bad = metric_pb2.Metric(name="u.bad", type=metric_pb2.Set)
+    bad.set.hyper_log_log = b"\x01\x0e\x00\x00"
+    wire = forward_pb2.MetricList(
+        metrics=ms + [bad]).SerializeToString()
+    if decode_metric_list(wire) is None:
+        pytest.skip("no native library")
+
+    mesh = make_mesh(jax.devices()[:4])
+    t = ShardedTable(mesh, ShardedConfig(rows=32, set_rows=8,
+                                         slots=16, batch=128))
+    assert not hasattr(t, "import_set_wire")
+    calls = []
+    one = t.import_set_at
+    monkeypatch.setattr(
+        t, "import_set_at",
+        lambda row, regs: (calls.append(int(row)), one(row, regs))[1])
+    assert apply_metric_list_bytes(t, wire) == (3, 1)
+    assert len(calls) == 3 and calls[0] == calls[2] != calls[1]
+    res = Flusher(is_local=False, percentiles=()).flush(t.swap())
+    m = {x.name: x.value for x in res.metrics}
+    assert m["u.a"] == pytest.approx(500, rel=0.05)
+    assert m["u.b"] == pytest.approx(40, rel=0.05)

@@ -328,6 +328,13 @@ def test_row_counts_by_class_on_encode_import_and_swap(make_server):
     grec = glob.flush_ring.records()[-1]
     assert (grec.imports, grec.import_set_planes,
             grec.import_centroids) == (1, 5, 7 * 12)
+    # all of them dense, so all in the one native pass: none went
+    # through the per-item decode, by the record and by the span
+    assert grec.import_set_planes_loose == 0
+    assert json.loads(glob.flush_ring.to_json(1))[0][
+        "import_set_planes_loose"] == 0
+    sets = _forward_span(glob, tid, "import.apply.sets")["tags"]
+    assert (sets["planes"], sets["planes_loose"]) == ("5", "0")
     assert grec.import_steps_flat + grec.import_steps_stack >= 1
     assert all(grec.stages[k] > 0 for k in APPLY_STEPS)
 

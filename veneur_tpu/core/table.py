@@ -234,14 +234,16 @@ class _IntervalState:
         self.fresh: set = set()
         # what the imports folded into this interval came to, counted
         # where the folds run (``_wire_digest_step``, the imported
-        # digests' ``_histo_device_step``, ``import_set_at``): digest
-        # folds that took the flat ranked merge and the stacked scan,
-        # centroids the stack left to the flat merge, centroids and
-        # register planes in all.  The snapshot carries it to the
-        # cycle's ``FlushRecord`` (``import_*``).
+        # digests' ``_histo_device_step``, ``import_set_at``,
+        # ``import_set_wire``): digest folds that took the flat ranked
+        # merge and the stacked scan, centroids the stack left to the
+        # flat merge, centroids and register planes in all, and the
+        # sketches of decoded wires that the one native pass handed
+        # back to the per-item decode.  The snapshot carries it to
+        # the cycle's ``FlushRecord`` (``import_*``).
         self.import_counts = dict.fromkeys(
             ("steps_flat", "steps_stack", "spilled_centroids",
-             "centroids", "set_planes"), 0)
+             "centroids", "set_planes", "set_planes_loose"), 0)
         self.hll_host_plane: np.ndarray | None = None
         self.hll_host_ez: np.ndarray | None = None
         self.hll_host_inv: np.ndarray | None = None
@@ -1355,6 +1357,16 @@ class MetricTable:
         self._staged_n += len(rows)
         self._interval_ingested += len(rows)
 
+    def _set_import_rows(self) -> np.ndarray:
+        """The interval's host import plane, made with its touched
+        mask at the first imported sketch."""
+        if self._set_import_plane is None:
+            c = self.config
+            self._set_import_plane = np.zeros((c.set_rows, hll.M),
+                                              np.uint8)
+            self._set_import_touched = np.zeros(c.set_rows, bool)
+        return self._set_import_plane
+
     def import_set_at(self, row: int, regs: np.ndarray) -> None:
         """import_set's staging half for a pre-resolved row: one
         16 KiB register max into the host import plane (Set.Merge,
@@ -1362,12 +1374,7 @@ class MetricTable:
         regs = np.asarray(regs, np.uint8)
         if regs.shape != (hll.M,):
             raise ValueError(f"bad register plane shape {regs.shape}")
-        if self._set_import_plane is None:
-            c = self.config
-            self._set_import_plane = np.zeros((c.set_rows, hll.M),
-                                              np.uint8)
-            self._set_import_touched = np.zeros(c.set_rows, bool)
-        prow = self._set_import_plane[row]
+        prow = self._set_import_rows()[row]
         np.maximum(prow, regs, out=prow)
         self._set_import_touched[row] = True
         self.set_idx.touched[row] = True
@@ -1375,6 +1382,48 @@ class MetricTable:
         self._staged_n += 1
         self._interval_ingested += 1
         self._state.import_counts["set_planes"] += 1
+
+    def import_set_wire(self, data: bytes, offs: np.ndarray,
+                        lens: np.ndarray,
+                        rows: np.ndarray) -> np.ndarray:
+        """import_set_at for every dense sketch of one decoded wire:
+        sketch i is the ``lens[i]`` bytes of ``data`` at ``offs[i]``
+        (axiomhq form, forward/hll_codec.py) for the resolved row
+        ``rows[i]``.  One native call (vtpu_hll_union_dense)
+        validates, unpacks and maxes them into the host import plane
+        in wire order, without the interpreter lock; the bookkeeping
+        of that many import_set_at calls follows once, as arrays.
+        Returns a status an item: 0 unioned, non-zero left untouched
+        (sparse, a header the dense decode refuses, short; all of
+        them without the native library) for the caller to put
+        through ``hll_codec.decode`` + ``import_set_at`` one by one,
+        counted in ``import_counts["set_planes_loose"]``."""
+        n = len(rows)
+        status = np.full(n, 255, np.uint8)
+        counts = self._state.import_counts
+        if n and self._lib is not None:
+            import ctypes as ct
+            plane = self._set_import_rows()
+            buf = np.frombuffer(data, np.uint8)
+            offs = np.ascontiguousarray(offs, np.int64)
+            lens = np.ascontiguousarray(lens, np.int32)
+            rows = np.ascontiguousarray(rows, np.int64)
+            u8p = ct.POINTER(ct.c_uint8)
+            i64p = ct.POINTER(ct.c_int64)
+            self._lib.vtpu_hll_union_dense(
+                buf.ctypes.data_as(u8p), len(buf),
+                offs.ctypes.data_as(i64p),
+                lens.ctypes.data_as(ct.POINTER(ct.c_int32)),
+                rows.ctypes.data_as(i64p), n, len(plane),
+                plane.ctypes.data_as(u8p), status.ctypes.data_as(u8p))
+            done = rows[status == 0]
+            self._set_import_touched[done] = True
+            self.set_idx.touch_rows(done, self.gen)
+            self._staged_n += len(done)
+            self._interval_ingested += len(done)
+            counts["set_planes"] += len(done)
+        counts["set_planes_loose"] += int((status != 0).sum())
+        return status
 
     def import_counter(self, name: str, tags: tuple[str, ...],
                        value: float) -> bool:

@@ -864,14 +864,17 @@ def _resolve_rows(table: MetricTable, data: bytes, cols: dict,
 
 
 def apply_decoded(table: MetricTable, data: bytes, cols: dict,
-                  step=contextlib.nullcontext) -> tuple[int, int]:
+                  step=lambda name: contextlib.nullcontext()
+                  ) -> tuple[int, int]:
     """The LOCKED half: resolve rows through the plan/row caches and
     stage every value with vectorized batch appliers.  Value-level
     validity (finiteness, HLL codec) is re-checked per wire — only
     series IDENTITY is cached, so a gauge that is NaN this interval
-    and finite the next is not penalized.  ``step(name)`` times the
-    three parts (``resolve``, ``digests``, ``sets``): the import
-    handler passes its ``import.apply`` span's."""
+    and finite the next is not penalized; a wire's dense sketches are
+    checked and unioned in one native pass (``_apply_sets``).
+    ``step(name)`` times the three parts (``resolve``, ``digests``,
+    ``sets``) and yields the part's span or None: the import handler
+    passes its ``import.apply`` span's."""
     nm = cols["n"]
     if nm == 0:
         return 0, 0
@@ -883,8 +886,9 @@ def apply_decoded(table: MetricTable, data: bytes, cols: dict,
     with step("digests"):
         acc, bad = _apply_scalars_and_digests(table, cols, kind, rows,
                                               valid)
-    with step("sets"):
-        acc_s, bad_s = _apply_sets(table, data, cols, kind, rows, valid)
+    with step("sets") as sp:
+        acc_s, bad_s = _apply_sets(table, data, cols, kind, rows, valid,
+                                   span=sp)
     return acc + acc_s, dropped + bad + bad_s
 
 
@@ -997,14 +1001,28 @@ def _apply_scalars_and_digests(table: MetricTable, cols: dict,
 
 def _apply_sets(table: MetricTable, data: bytes, cols: dict,
                 kind: np.ndarray, rows: np.ndarray,
-                valid: np.ndarray) -> tuple[int, int]:
-    """The sketches of a decoded wire, each decoded and unioned into
-    its row's host plane; (accepted, dropped).  The HLL codec decode
-    stays per item (value-level), but row resolution and name/tag
-    decode are skipped on cache hits."""
+                valid: np.ndarray, span=None) -> tuple[int, int]:
+    """The sketches of a decoded wire, unioned into their rows' host
+    planes; (accepted, dropped).  A ``MetricTable`` takes the wire's
+    dense sketches in one native pass (``import_set_wire``: validated,
+    unpacked and maxed into the import plane with no interpreter
+    lock held); the HLL codec decode stays per item (value-level)
+    for what that pass hands back (a sparse sketch from a Go local,
+    a malformed one) and for a table without the batch entry
+    (``ShardedTable`` stages register positions, it has no host
+    plane).  Row resolution and name/tag decode are skipped on cache
+    hits either way.  ``span`` (``import.apply.sets``) is told how
+    many sketches there were and how many went one by one."""
     accepted = dropped = 0
     sels = np.nonzero(valid & (kind == 4))[0]
-    for i in sels:
+    loose = sels
+    wire = getattr(table, "import_set_wire", None)
+    if wire is not None and len(sels):
+        status = wire(data, cols["hll_off"][sels], cols["hll_len"][sels],
+                      rows[sels])
+        loose = sels[status != 0]
+        accepted = len(sels) - len(loose)
+    for i in loose:
         ho, hl = int(cols["hll_off"][i]), int(cols["hll_len"][i])
         try:
             regs = hll_codec.decode(data[ho:ho + hl])
@@ -1013,6 +1031,9 @@ def _apply_sets(table: MetricTable, data: bytes, cols: dict,
         except (ValueError, hll_codec.HLLCodecError) as e:
             log.warning("dropping bad gRPC import item: %s", e)
             dropped += 1
+    if span is not None:
+        span.add_tag("planes", str(len(sels)))
+        span.add_tag("planes_loose", str(len(loose)))
     return accepted, dropped
 
 

@@ -20,6 +20,16 @@ Encoding out we always emit the dense form with b=0 and
 ``min(register, 15)`` nibbles — exactly the state an axiomhq sketch
 holds after the same inserts while its base never rebased (b stays 0
 while any register is 0, which at p=14 is essentially always).
+
+``decode`` is the reference decoder and the per-item path (HTTP
+``/import``, the protobuf fallback, sparse sketches, a
+``ShardedTable``).  The dense sketches of a natively decoded gRPC
+wire do not come through here: ``native/dsd_parse.cpp``
+``vtpu_hll_union_dense`` accepts what the dense branch below accepts
+(sparse flag 0) and maxes ``b + nibble`` straight into the import
+plane (``MetricTable.import_set_wire``); what it refuses comes back
+to ``decode``, so the two must agree byte for byte
+(tests/test_grpc_forward.py).
 """
 
 from __future__ import annotations

@@ -159,6 +159,9 @@ def _bind(lib: ctypes.CDLL) -> None:
     lib.vtpu_hll_plane.restype = None
     lib.vtpu_hll_plane.argtypes = [
         i32p, i32p, i64, ctypes.c_int32, ctypes.c_int32, u8p]
+    lib.vtpu_hll_union_dense.restype = i64
+    lib.vtpu_hll_union_dense.argtypes = [
+        u8p, i64, i64p, i32p, i64p, i64, i64, u8p, u8p]
     lib.vtpu_sb_gather_i32.restype = None
     lib.vtpu_sb_gather_i32.argtypes = [
         ctypes.POINTER(i32p), i64p, ctypes.c_int32, i32p, i64,

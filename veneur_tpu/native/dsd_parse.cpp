@@ -1490,6 +1490,61 @@ void vtpu_hll_plane(const int32_t* rows, const int32_t* packed,
   }
 }
 
+// Union the dense axiomhq sketches of one forwarded wire into the
+// host import plane (n_rows, 16384): what forward/hll_codec.decode's
+// dense branch and MetricTable.import_set_at do a sketch, for the
+// whole wire in one call without the interpreter lock.  Item i is
+// the hll_len[i] bytes at buf + hll_off[i] for row rows[i]:
+//   [version][p][b][sparse][m/2 be32][m/2 nibble-packed registers]
+// reg = (nibble + b) & 0xFF, even register in the HIGH nibble,
+// plane[row][j] = max(plane[row][j], reg).  Accepted is exactly
+// what the dense branch accepts with the sparse flag 0 (the version
+// byte is not looked at, trailing bytes are let be); everything else
+// gets a non-zero status and its row is left untouched, for the
+// caller's per-item path: 1 sparse flag set, 2 a header the dense
+// branch refuses (precision, size), 3 too short or outside buf,
+// 4 row outside the plane.  Items run in wire order, so a row that
+// occurs twice needs no special case.  Returns the items done.
+int64_t vtpu_hll_union_dense(const uint8_t* buf, int64_t buf_len,
+                             const int64_t* hll_off,
+                             const int32_t* hll_len,
+                             const int64_t* rows, int64_t n,
+                             int64_t n_rows, uint8_t* plane,
+                             uint8_t* status) {
+  constexpr int64_t kM = 1 << 14, kHalf = kM / 2;
+  int64_t done = 0;
+  for (int64_t i = 0; i < n; i++) {
+    int64_t off = hll_off[i], len = hll_len[i], row = rows[i];
+    if (off < 0 || len < 4 || len > buf_len - off) {
+      status[i] = 3; continue;
+    }
+    const uint8_t* s = buf + off;
+    if (s[1] != 14) { status[i] = 2; continue; }
+    if (s[3] != 0) { status[i] = s[3] == 1 ? 1 : 2; continue; }
+    if (len < 8) { status[i] = 3; continue; }
+    if (s[4] != 0 || s[5] != 0 || s[6] != (kHalf >> 8) ||
+        s[7] != (kHalf & 0xFF)) {
+      status[i] = 2; continue;
+    }
+    if (len < 8 + kHalf) { status[i] = 3; continue; }
+    if (row < 0 || row >= n_rows) { status[i] = 4; continue; }
+    const uint8_t* __restrict src = s + 8;
+    uint8_t* __restrict dst = plane + row * kM;
+    const uint8_t b = s[2];
+    for (int64_t j = 0; j < kHalf; j++) {
+      uint8_t v = src[j];
+      uint8_t hi = (uint8_t)((v >> 4) + b);
+      uint8_t lo = (uint8_t)((v & 0x0F) + b);
+      uint8_t d0 = dst[2 * j], d1 = dst[2 * j + 1];
+      dst[2 * j] = d0 > hi ? d0 : hi;
+      dst[2 * j + 1] = d1 > lo ? d1 : lo;
+    }
+    status[i] = 0;
+    done++;
+  }
+  return done;
+}
+
 // Superbatch segment gather: concatenate k staged part arrays
 // directly into one int32 buffer segment and sentinel-fill the
 // bucket-padded tail.  The parse path stages one packed-position

@@ -52,13 +52,16 @@ class FlushRecord:
     # closed, counted where they run (``core/table.py``
     # ``_IntervalState.import_counts``): digest folds through the
     # flat ranked merge and through the stacked scan, centroids the
-    # stack left to the flat merge, centroids folded in all, and
-    # register planes unioned on the host
+    # stack left to the flat merge, centroids folded in all,
+    # register planes unioned on the host, and of a decoded wire's
+    # sketches those that went through the per-item decode and not
+    # the one native pass (sparse or malformed; 0 for dense wires)
     import_steps_flat: int = 0
     import_steps_stack: int = 0
     import_spilled_centroids: int = 0
     import_centroids: int = 0
     import_set_planes: int = 0
+    import_set_planes_loose: int = 0
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
     # collector pauses that ended inside the cycle, on any thread
@@ -91,6 +94,8 @@ class FlushRecord:
                     self.import_spilled_centroids,
                 "import_centroids": self.import_centroids,
                 "import_set_planes": self.import_set_planes,
+                "import_set_planes_loose":
+                    self.import_set_planes_loose,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
                 "gc_pause_ns": self.gc_pause_ns,

@@ -47,7 +47,11 @@ stage before the dot:
            |         |    |                        stats and centroids
            |         |    |                        staged
            |         |    +- import.apply.sets     sketches decoded and
-           |         |                             unioned on the host
+           |         |                             unioned on the host;
+           |         |                             tags ``planes``,
+           |         |                             ``planes_loose``
+           |         |                             (those decoded one
+           |         |                             by one)
            |         +- import.device_step  the staged apply, if the
            |                                wire crossed the threshold
            +- flush.forward.shard   sharded path: one per destination
