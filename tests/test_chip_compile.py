@@ -126,3 +126,89 @@ def test_quantile_readout_three_percentiles(shape):
     tdigest._quantile_interp.lower(
         shape((ROWS, CAP)), shape((ROWS, CAP)), shape((3,)),
         shape((ROWS,)), shape((ROWS,))).compile()
+
+
+# ----------------------------------------------------------------------
+# the mesh table's two SPMD programs on the four described chips: the
+# cell ``global-64-locals-mesh2x2``'s mesh, widths and batch
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from veneur_tpu.parallel import sharded
+    mesh = sharded.make_mesh(topo.devices, n_shard=2)
+    assert dict(mesh.shape) == {"shard": 2, "series": 2}
+    return mesh
+
+
+def _mesh_programs(mesh, monkeypatch):
+    """(update, merge, state shapes, batch shapes) at the default
+    widths and the server's batch (four staging thresholds), 1,024
+    rows a class standing for 16,384."""
+    from jax.sharding import NamedSharding
+
+    from veneur_tpu.parallel import sharded
+    monkeypatch.setattr(tdigest, "_MERGE_MODE", "pallas")
+    monkeypatch.setattr(pallas_merge, "_INTERPRET", False)
+    cfg = sharded.ShardedConfig(
+        rows=ROWS, set_rows=64, counter_rows=ROWS, gauge_rows=ROWS,
+        compression=COMPRESSION, slots=512, batch=4 * 65536)
+    s = mesh.shape["shard"]
+    dims = {"counters": ((s, ROWS), jnp.float32),
+            "gauges": ((s, ROWS), jnp.float32),
+            "gauge_ticket": ((s, ROWS), jnp.int32),
+            "histo_stats": ((s, ROWS, segment.HISTO_STAT_COLS),
+                            jnp.float32),
+            "histo_import_stats": ((s, ROWS, segment.HISTO_STAT_COLS),
+                                   jnp.float32),
+            "histo_means": ((s, ROWS, CAP), jnp.float32),
+            "histo_weights": ((s, ROWS, CAP), jnp.float32),
+            "hll": ((s, 64, hll.M), jnp.uint8)}
+    specs = sharded._specs(mesh)
+    assert set(dims) == set(specs)
+    state = {k: jax.ShapeDtypeStruct(d, dt, sharding=NamedSharding(
+        mesh, specs[k])) for k, (d, dt) in dims.items()}
+    batch = {k: jax.ShapeDtypeStruct(
+        (s, cfg.batch), dt, sharding=NamedSharding(mesh, spec))
+        for (k, spec), dt in zip(sharded.batch_specs().items(),
+                                 sharded.ShardedAggregator
+                                 ._DTYPES.values())}
+    return (sharded.make_update_step(mesh, cfg),
+            sharded.make_merge_step(mesh, cfg), state, batch)
+
+
+_COLLECTIVES = ("all-reduce", "all-gather", "collective-permute",
+                "reduce-scatter", "all-to-all")
+
+
+def _collectives(text: str) -> dict:
+    import re
+    return {op: len(re.findall(rf"\s{op}(?:-start)?\(", text))
+            for op in _COLLECTIVES}
+
+
+def test_mesh_update_step_on_2x2_has_the_kernel_and_no_collective(
+        mesh_2x2, monkeypatch):
+    update, _, state, batch = _mesh_programs(mesh_2x2, monkeypatch)
+    compiled = update.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    # 616 slots of state beside 512 of a batch: the 2,048-lane kernel
+    assert f"tdigest_merge_c{CAP}_k512" in text
+    assert sum(_collectives(text).values()) == 0
+    assert "jit_shard_update" in update.lower(state, batch).as_text()[:200]
+
+
+def test_mesh_merge_step_on_2x2_gathers_inside_the_kernels_lanes(
+        mesh_2x2, monkeypatch):
+    _, merge, state, _ = _mesh_programs(mesh_2x2, monkeypatch)
+    assert tdigest.merge_path(CAP, 2 * CAP) == "pallas"
+    assert tdigest.merge_path(CAP, 4 * CAP) == tdigest._FALLBACK_MODE
+    compiled = merge.lower(state).compile()
+    text = compiled.as_text()
+    assert _has_kernel(compiled)
+    # two shards' slots gathered a row beside an empty state: 1,232
+    assert f"tdigest_merge_c{CAP}_k{2 * CAP}" in text
+    found = _collectives(text)
+    assert found["all-gather"] >= 1 and found["all-reduce"] >= 1
+    # the module name the benchmark's reader sums by
+    assert "jit_shard_merge" in merge.lower(state).as_text()[:200]

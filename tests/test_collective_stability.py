@@ -70,7 +70,7 @@ def test_update_step_has_no_collectives():
     mesh = make_mesh(devs, n_shard=4)
     state = empty_state(mesh, _CFG)
     import numpy as np
-    from veneur_tpu.parallel.sharded import batch_specs  # noqa: F401
+    from veneur_tpu.parallel import sharded as sharded_mod
     update = make_update_step(mesh, _CFG)
     batch = {
         "counter_rows": np.zeros((4, 8), np.int32),
@@ -82,8 +82,10 @@ def test_update_step_has_no_collectives():
         "histo_rows": np.zeros((4, 8), np.int32),
         "histo_vals": np.zeros((4, 8), np.float32),
         "histo_wts": np.ones((4, 8), np.float32),
-        "rsum_rows": np.zeros((4, 8), np.int32),
-        "rsum_vals": np.zeros((4, 8), np.float32),
+        "histo_imp": np.zeros((4, 8), np.int32),
+        "istat_rows": np.zeros((4, 8), np.int32),
+        **{k: np.zeros((4, 8), np.float32)
+           for k in sharded_mod.ISTAT_COLS},
         "set_rows": np.zeros((4, 8), np.int32),
         "set_idx": np.zeros((4, 8), np.int32),
         "set_rank": np.zeros((4, 8), np.int32),

@@ -253,8 +253,10 @@ def test_sharded_table_server_path_production_rows():
         exact = float(np.quantile(vals, 0.99))
         got = m[f"lat.{s}.99percentile"].value
         errs.append(abs(got - exact) / exact)
-        assert m[f"lat.{s}.count"].value == pytest.approx(
-            len(vals), rel=1e-5)
+        # a mixed-scope row's count is what this node sampled itself
+        # (samplers.go LocalWeight): the forwarded digest's 500 are
+        # in the percentiles and in the import plane, as on one chip
+        assert m[f"lat.{s}.count"].value == pytest.approx(256, rel=1e-5)
     assert max(errs) < 0.02, max(errs)
 
 
@@ -307,25 +309,23 @@ def test_sharded_swap_resets_interval():
     assert float(np.asarray(merged2["counters"]).sum()) == 0.0
 
 
-def test_sharded_import_preserves_reciprocal_sum():
-    """A forwarded digest's hmean depends on the exact reciprocal
-    sum; the mesh import stages an RSUM correction so the merged plane
-    matches the forwarded value (centroid means alone would misstate
-    it for wide-range data)."""
+def test_sharded_import_keeps_the_forwarded_statistics_exact():
+    """A forwarded digest's statistics land exact in the import
+    plane, whatever its centroids say (one wide centroid's mean
+    wildly misstates sum(1/x)), and the plane of what this node
+    sampled itself stays empty: a global emits no aggregate of a
+    mixed-scope row it did not sample, as on one chip."""
     import numpy as np
 
     from veneur_tpu.core.flusher import Flusher
+    from veneur_tpu.ops import segment
     from veneur_tpu.parallel import (ShardedConfig, ShardedTable,
                                      make_mesh)
     from veneur_tpu.protocol import dogstatsd as dsd
 
-    # raw values with a huge spread: a merged centroid's mean wildly
-    # misrepresents sum(1/x)
     vals = np.asarray([1.0, 100.0, 1.0, 100.0, 2.0], np.float32)
-    exact_rsum = float((1.0 / vals).sum())
-    exact_hmean = len(vals) / exact_rsum
     stats = np.asarray([len(vals), vals.min(), vals.max(),
-                        vals.sum(), exact_rsum], np.float32)
+                        vals.sum(), (1.0 / vals).sum()], np.float32)
     # one wide centroid (as a lossy local might forward)
     means = np.asarray([float(vals.mean())], np.float32)
     weights = np.asarray([float(len(vals))], np.float32)
@@ -333,13 +333,21 @@ def test_sharded_import_preserves_reciprocal_sum():
     mesh = make_mesh(jax.devices()[:4])
     t = ShardedTable(mesh, ShardedConfig(rows=32, set_rows=8,
                                          slots=16, batch=128))
-    assert t.import_histo("lat", dsd.TIMER, (), stats, means, weights)
-    res = Flusher(is_local=False, percentiles=(),
-                  aggregates=("hmean", "count")).flush(t.swap())
-    m = {x.name: x for x in res.metrics}
-    assert m["lat.hmean"].value == pytest.approx(exact_hmean,
-                                                 rel=1e-3)
-    assert m["lat.count"].value == pytest.approx(len(vals), rel=1e-5)
+    for _ in range(2):          # two locals: one to each shard pair
+        assert t.import_histo("lat", dsd.TIMER, (), stats, means,
+                              weights)
+    snap = t.swap()
+    imp = np.asarray(snap.histo_import_stats)[0]
+    assert imp[segment.STAT_WEIGHT] == 2 * len(vals)
+    assert imp[segment.STAT_MIN] == 1.0 and imp[segment.STAT_MAX] == 100.0
+    assert imp[segment.STAT_SUM] == pytest.approx(2 * vals.sum())
+    assert imp[segment.STAT_RSUM] == pytest.approx(2 * stats[4], rel=1e-6)
+    assert np.asarray(snap.histo_stats)[0, segment.STAT_WEIGHT] == 0
+    res = Flusher(is_local=False, percentiles=(0.5,),
+                  aggregates=("hmean", "count", "max")).flush(snap)
+    m = {x.name: x.value for x in res.metrics}
+    assert set(m) == {"lat.50percentile"}
+    assert m["lat.50percentile"] == pytest.approx(vals.mean())
 
 
 def test_sharded_import_validates_before_staging():
@@ -363,27 +371,187 @@ def test_sharded_import_validates_before_staging():
     assert t.staged() == 0
 
 
-def test_sharded_import_of_a_wire_with_sets_goes_sketch_by_sketch(
-        monkeypatch):
-    """A ``ShardedTable`` has no host import plane: the sketches of a
-    natively decoded wire still go one by one through
-    ``hll_codec.decode`` and its own ``import_set_at`` (register
-    positions staged to a shard), not through ``MetricTable``'s one
-    native pass."""
-    import numpy as np
+# ----------------------------------------------------------------------
+# the cell ``global-64-locals-mesh2x2`` at a small size: the fleet's
+# wires, made with the program's own encoder as the topology
+# ``fleet-global`` makes them, folded into one chip's table and into
+# a mesh table on an explicit 2 x 2 mesh, against the plain reference
 
+_FLEET = {"clients": 16, "timers": 48, "sets": 12, "global_counters": 16,
+          "locals_per_series": 8, "samples_per_digest": 128,
+          "members_per_set": 320, "set_pool": 1280, "rounds": 1,
+          "start_s": 0.2, "end_s": 0.95}
+
+
+@pytest.fixture(scope="module")
+def fleet_wires():
+    """(fleet, its round, every local's wire, the cell's limits)."""
+    from benchmark import fleet as fleet_mod
+    from benchmark import harness
+    cfg = harness.cell("global-64-locals-mesh2x2")["config"]
+    fl = fleet_mod.Fleet(_FLEET, seed=3600000007)
+    rnd = fl.round(0)
+    bodies = harness.load_module("topologies", "fleet-global").Bodies(
+        fl, cfg["sizes"]["compression"])
+    pool = bodies.hashed_pool(rnd)
+    wires = [bodies.body(rnd, l, pool)[0] for l in range(fl.clients)]
+    return fl, rnd, wires, cfg["limits"]
+
+
+def _mesh_table(batch=2048):
+    from veneur_tpu.parallel import ShardedConfig, ShardedTable
+    mesh = make_mesh(jax.devices()[:4], n_shard=2)
+    assert dict(mesh.shape) == {"shard": 2, "series": 2}
+    return ShardedTable(mesh, ShardedConfig(
+        rows=64, set_rows=16, counter_rows=64, gauge_rows=64,
+        compression=100.0, slots=512, batch=batch))
+
+
+def _fold_and_flush(table, wires, order):
+    """The wires through the import's decoded path in ``order``, a
+    device step after each as the handler makes one, then the flush:
+    (numbers against the reference's keys, the snapshot)."""
+    from benchmark import reference
+    from veneur_tpu.core.flusher import Flusher
+    from veneur_tpu.forward.grpc_forward import apply_metric_list_bytes
+    for l in order:
+        acc, dropped = apply_metric_list_bytes(table, wires[l])
+        assert dropped == 0 and acc > 0
+        table.device_step()
+    snap = table.swap()
+    res = Flusher(is_local=False, percentiles=(0.5, 0.9, 0.99),
+                  aggregates=("count",)).flush(snap)
+    return reference.sink_values(
+        (m.name, m.tags, m.value) for m in res.metrics), snap
+
+
+def _against_reference(fl, rnd, got, limits):
+    from benchmark import fleet_reference
+    ref = fleet_reference.interval(
+        fl, [rnd], [(l, 0) for l in range(fl.clients)])
+    numbers = fleet_reference.compare_interval(ref, got)["numbers"]
+    assert all(v <= limits[k] for k, v in numbers.items()), numbers
+    return numbers
+
+
+def test_fleet_wires_fold_alike_on_one_chip_and_on_a_2x2_mesh(
+        fleet_wires):
+    from veneur_tpu.core.table import MetricTable, TableConfig
+    from veneur_tpu.forward.grpc_forward import decode_metric_list
+    fl, rnd, wires, limits = fleet_wires
+    if decode_metric_list(wires[0]) is None:
+        pytest.skip("no native library")
+    order = list(range(fl.clients))
+    one, _ = _fold_and_flush(MetricTable(TableConfig(
+        counter_rows=64, gauge_rows=64, histo_rows=64, set_rows=16)),
+        wires, order)
+    table = _mesh_table()
+    mesh, snap = _fold_and_flush(table, wires, order)
+    # the sums are exact, on both and alike; the rest inside the
+    # cell's limits against the plain reference, on both
+    assert _against_reference(fl, rnd, one, limits)["sums_off"] == 0
+    assert _against_reference(fl, rnd, mesh, limits)["sums_off"] == 0
+    exact = [k for k in one if "gcount" in k[0]]
+    assert len(exact) == fl.n["gcount"]
+    assert all(one[k] == mesh[k] for k in exact)
+    assert set(one) == set(mesh)
+    assert len(one) == fl.n["gcount"] + fl.n["set"] + 3 * fl.n["timer"]
+    # a wire went whole to one shard, the next to the other; each
+    # took two update calls of 2,048 (24 digests of 128 centroids and
+    # a statistics row each), and the swap says so
+    counts = snap.mesh_counts
+    assert counts["mesh"] == "2x2" and len(counts["shard_staged"]) == 2
+    assert counts["shard_staged"][0] == counts["shard_staged"][1] > 0
+    assert counts["shard_steps"] == 2 * fl.clients
+    assert counts["merge_path"] == "scatter"     # the CPU's path
+    assert snap.import_counts == {
+        "centroids": fl.clients * 24 * 128,
+        "set_planes": fl.clients * 6, "set_planes_loose": 0}
+    assert table.agg.merge_info["gathered_slots"] == 2 * 616
+    # the next interval starts from nothing, the host planes too
+    assert not table.agg._set_touched.any()
+    assert not table.agg._set_planes.any()
+    assert table.agg.steps == 0 and table.agg.staged == [0, 0]
+
+
+@pytest.mark.parametrize("perm", [1, 2])
+def test_whatever_shard_a_wire_lands_on_the_flush_is_the_same(
+        fleet_wires, perm):
+    """Two orders of the same calls put every wire but a few on the
+    other shard and in another update call: sums and counts do not
+    move, percentiles and cardinalities stay inside the limits."""
+    fl, rnd, wires, limits = fleet_wires
+    base, _ = _fold_and_flush(_mesh_table(), wires,
+                              list(range(fl.clients)))
+    order = np.random.default_rng(perm).permutation(fl.clients)
+    assert list(order) != sorted(order)
+    got, _ = _fold_and_flush(_mesh_table(), wires, order.tolist())
+    _against_reference(fl, rnd, got, limits)
+    exact = [k for k in base if "gcount" in k[0]]
+    assert len(exact) == fl.n["gcount"]
+    assert all(base[k] == got[k] for k in exact)
+    # a register max commutes: the unions are the same bytes
+    sets = [k for k in base if ".set." in k[0]]
+    assert len(sets) == fl.n["set"]
+    assert all(base[k] == got[k] for k in sets)
+
+
+def test_http_import_bodies_take_the_shards_in_turn():
+    """An HTTP import body is a wire too: its batched counters, gauges
+    and digests go whole to one shard and the next body's to the
+    other, as the gRPC path's do (``begin_wire`` in both)."""
+    from veneur_tpu.core.flusher import ForwardRow, Flusher
+    from veneur_tpu.core.table import RowMeta
+    from veneur_tpu.forward import http_import
+    from veneur_tpu.ops import segment
+    from veneur_tpu.protocol import dogstatsd as dsd
+
+    def meta(name, mtype):
+        return RowMeta(name=name, tags=("env:t",),
+                       scope=dsd.SCOPE_DEFAULT, type=mtype)
+    rng = np.random.default_rng(5)
+    stats = np.zeros(5, np.float32)
+    vals = np.sort(rng.uniform(1, 9, 32)).astype(np.float32)
+    stats[segment.STAT_WEIGHT] = len(vals)
+    stats[segment.STAT_MIN], stats[segment.STAT_MAX] = vals[0], vals[-1]
+    stats[segment.STAT_SUM] = vals.sum()
+    stats[segment.STAT_RSUM] = (1.0 / vals).sum()
+    rows = [ForwardRow(meta(f"h.c{i}", dsd.COUNTER), "counter",
+                       value=float(i + 1)) for i in range(6)]
+    rows += [ForwardRow(meta("h.g", dsd.GAUGE), "gauge", value=3.5),
+             ForwardRow(meta("h.t", dsd.TIMER), "histo", stats=stats,
+                        means=vals, weights=np.ones(32, np.float32))]
+    body, headers = http_import.encode_rows_reference(rows,
+                                                      deflate=False)
+    items = http_import.decode_body(body)
+    t = _mesh_table(batch=128)
+    for _ in range(4):
+        assert http_import.apply_import(t, items) == (len(rows), 0)
+    # four bodies, two to each shard: what each staged is the same
+    assert t.agg.staged[0] == t.agg.staged[1] > 0
+    res = Flusher(is_local=False, percentiles=(0.5,),
+                  aggregates=("count",)).flush(t.swap())
+    m = {x.name: x.value for x in res.metrics}
+    assert m["h.c5"] == 4 * 6.0 and m["h.g"] == 3.5
+    assert m["h.t.50percentile"] == pytest.approx(
+        float(np.median(vals)), rel=0.05)
+
+
+def test_sharded_import_set_wire_gives_the_per_item_paths_planes():
+    """``import_set_wire`` on the mesh table: a wire's dense sketches
+    in one native pass into the host plane of the wire's shard, byte
+    for byte what ``hll_codec.decode`` + ``import_set_at`` give item
+    by item; a sparse and a malformed sketch are handed back."""
     from veneur_tpu.core.flusher import Flusher
     from veneur_tpu.forward import hll_codec
     from veneur_tpu.forward.gen import forward_pb2, metric_pb2
     from veneur_tpu.forward.grpc_forward import (apply_metric_list_bytes,
                                                  decode_metric_list)
     from veneur_tpu.ops import hll
-    from veneur_tpu.parallel import (ShardedConfig, ShardedTable,
-                                     make_mesh)
     from veneur_tpu.utils import hashing
 
-    ms = []
-    for s, n in (("u.a", 300), ("u.b", 40), ("u.a", 200)):
+    ms, planes = [], []
+    for s, n in (("u.a", 300), ("u.b", 40), ("u.a", 200), ("u.c", 900)):
         idx, rank = hashing.hll_position(hashing.hash64(
             [f"{s}-{n}-{i}".encode() for i in range(n)]))
         regs = np.zeros(hll.M, np.uint8)
@@ -391,25 +559,44 @@ def test_sharded_import_of_a_wire_with_sets_goes_sketch_by_sketch(
         m = metric_pb2.Metric(name=s, type=metric_pb2.Set)
         m.set.hyper_log_log = hll_codec.encode_dense(regs)
         ms.append(m)
+        planes.append((s, regs))
+    from test_grpc_forward import _sparse_sketch
+    sparse = metric_pb2.Metric(name="u.sparse", type=metric_pb2.Set)
+    sparse.set.hyper_log_log = _sparse_sketch()
+    planes.append(("u.sparse", hll_codec.decode(_sparse_sketch())))
     bad = metric_pb2.Metric(name="u.bad", type=metric_pb2.Set)
     bad.set.hyper_log_log = b"\x01\x0e\x00\x00"
     wire = forward_pb2.MetricList(
-        metrics=ms + [bad]).SerializeToString()
+        metrics=ms + [sparse, bad]).SerializeToString()
     if decode_metric_list(wire) is None:
         pytest.skip("no native library")
 
-    mesh = make_mesh(jax.devices()[:4])
-    t = ShardedTable(mesh, ShardedConfig(rows=32, set_rows=8,
-                                         slots=16, batch=128))
-    assert not hasattr(t, "import_set_wire")
-    calls = []
-    one = t.import_set_at
-    monkeypatch.setattr(
-        t, "import_set_at",
-        lambda row, regs: (calls.append(int(row)), one(row, regs))[1])
-    assert apply_metric_list_bytes(t, wire) == (3, 1)
-    assert len(calls) == 3 and calls[0] == calls[2] != calls[1]
-    res = Flusher(is_local=False, percentiles=()).flush(t.swap())
+    t = _mesh_table(batch=128)
+    assert apply_metric_list_bytes(t, wire) == (5, 1)
+    # four dense in the one pass; the sparse and the malformed one
+    # handed back, the sparse one then unioned by itself
+    assert t.import_counts == {"centroids": 0, "set_planes": 5,
+                               "set_planes_loose": 2}
+    # the wire's dense sketches all on one shard's plane
+    assert sorted(t.agg._set_touched.sum(axis=1)) == [1, 3]
+    by_wire = t.agg._set_planes.copy()
+
+    item = _mesh_table(batch=128)
+    for s, regs in planes:
+        row = item.import_set_row(s, ())
+        item.import_set_at(row, hll_codec.decode(
+            hll_codec.encode_dense(regs)))
+    assert item.import_counts["set_planes"] == 5
+    # item by item each sketch takes the next shard: the planes are
+    # the same bytes once the shards are unioned, as the merge does
+    assert np.array_equal(by_wire.max(axis=0),
+                          item.agg._set_planes.max(axis=0))
+    a = t.swap()
+    b = item.swap()
+    assert np.array_equal(np.asarray(a.hll_regs), np.asarray(b.hll_regs))
+    assert np.asarray(a.hll_regs).any()
+    res = Flusher(is_local=False, percentiles=()).flush(a)
     m = {x.name: x.value for x in res.metrics}
     assert m["u.a"] == pytest.approx(500, rel=0.05)
     assert m["u.b"] == pytest.approx(40, rel=0.05)
+    assert m["u.c"] == pytest.approx(900, rel=0.05)

@@ -174,7 +174,22 @@ class Server:
                 gauge_rows=config.tpu_gauge_rows,
                 compression=config.tpu_compression,
                 slots=config.tpu_histo_slots,
-                batch=max(1024, config.tpu_stage_flush_samples)))
+                # ``_maybe_device_step_locked`` says when to step (at
+                # the staging threshold); an update call costs a merge
+                # of the whole table whatever it holds, and a forwarded
+                # wire stages several thresholds' worth at once
+                # (160,000 centroids against 65,536): wide enough to
+                # take a wire in one (ROADMAP B-i 5: measured in one
+                # cell, the DogStatsD path on a mesh unmeasured)
+                batch=4 * max(1024, config.tpu_stage_flush_samples)))
+            # no config key asks for this: a first-sight compile on a
+            # mesh takes tens of seconds, and would run under the
+            # ingest lock inside the first import handler and flush
+            t0 = time.monotonic()
+            self.table.agg.compile()
+            log.info("mesh %s: update and merge programs compiled in "
+                     "%.2fs", self.table.agg.merge_info["mesh"],
+                     time.monotonic() - t0)
             self._init_after_table(config, extra_sinks, extra_plugins,
                                    extra_span_sinks)
             return
@@ -2311,9 +2326,15 @@ class Server:
                     sp.add_tag(k, str(v))
                 snap = self.table.complete_swap(pend)
         else:
-            with cyc.stage("snapshot"):
+            with cyc.stage("snapshot") as sp:
                 with self.lock:
-                    snap = self.table.swap()
+                    # the mesh table times its swap's parts under
+                    # this stage's span
+                    snap = (self.table.swap(
+                        stage=lambda name: cyc.stage(
+                            f"snapshot.{name}", sp))
+                        if self.config.tpu_mesh_shards
+                        else self.table.swap())
                     events = self.events
                     checks = self.checks
                     self.events, self.checks = [], []
@@ -2329,6 +2350,8 @@ class Server:
         # final (a table without them leaves the record's at 0)
         for k, v in getattr(snap, "import_counts", {}).items():
             setattr(cyc.record, f"import_{k}", v)
+        for k, v in getattr(snap, "mesh_counts", {}).items():
+            setattr(cyc.record, k, v)
         # dispatch / device_wait / host_emit stages happen inside the
         # flusher, against the same cycle
         res = self.flusher.flush(snap, cycle=cyc)

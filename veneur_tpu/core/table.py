@@ -541,6 +541,10 @@ class Snapshot:
     # ``import_counts``), final once ``complete_swap`` has applied the
     # last staged work; empty where the table keeps no such count
     import_counts: dict[str, int] = field(default_factory=dict)
+    # what a mesh table's swap did, under the ``FlushRecord``'s field
+    # names (``shard_steps``, ``shard_staged``, ``mesh``,
+    # ``merge_path``); empty from one chip's table
+    mesh_counts: dict[str, Any] = field(default_factory=dict)
 
     @property
     def host_only_sets(self) -> bool:
@@ -597,6 +601,37 @@ class Snapshot:
             regs = np.maximum(regs, self.hll_host_plane)
         return regs
 
+
+
+def union_dense_sketches(lib, data: bytes, offs, lens, rows,
+                         plane_of) -> np.ndarray:
+    """The dense sketches of one decoded wire maxed into a host
+    register plane u8[n_rows, M] in one native call
+    (vtpu_hll_union_dense: validated and unpacked in wire order, no
+    interpreter lock held): sketch i is the ``lens[i]`` bytes of
+    ``data`` at ``offs[i]`` for row ``rows[i]``.  ``plane_of()`` gives
+    the plane, asked only where there is something to union.  Returns
+    a status an item: 0 unioned, non-zero left untouched (sparse, a
+    header the dense decode refuses, short; all of them without the
+    native library)."""
+    n = len(rows)
+    status = np.full(n, 255, np.uint8)
+    if n and lib is not None:
+        import ctypes as ct
+        plane = plane_of()
+        buf = np.frombuffer(data, np.uint8)
+        offs = np.ascontiguousarray(offs, np.int64)
+        lens = np.ascontiguousarray(lens, np.int32)
+        rows = np.ascontiguousarray(rows, np.int64)
+        u8p = ct.POINTER(ct.c_uint8)
+        i64p = ct.POINTER(ct.c_int64)
+        lib.vtpu_hll_union_dense(
+            buf.ctypes.data_as(u8p), len(buf),
+            offs.ctypes.data_as(i64p),
+            lens.ctypes.data_as(ct.POINTER(ct.c_int32)),
+            rows.ctypes.data_as(i64p), n, len(plane),
+            plane.ctypes.data_as(u8p), status.ctypes.data_as(u8p))
+    return status
 
 class MetricTable:
     def __init__(self, config: TableConfig | None = None):
@@ -1398,25 +1433,11 @@ class MetricTable:
         them without the native library) for the caller to put
         through ``hll_codec.decode`` + ``import_set_at`` one by one,
         counted in ``import_counts["set_planes_loose"]``."""
-        n = len(rows)
-        status = np.full(n, 255, np.uint8)
         counts = self._state.import_counts
-        if n and self._lib is not None:
-            import ctypes as ct
-            plane = self._set_import_rows()
-            buf = np.frombuffer(data, np.uint8)
-            offs = np.ascontiguousarray(offs, np.int64)
-            lens = np.ascontiguousarray(lens, np.int32)
-            rows = np.ascontiguousarray(rows, np.int64)
-            u8p = ct.POINTER(ct.c_uint8)
-            i64p = ct.POINTER(ct.c_int64)
-            self._lib.vtpu_hll_union_dense(
-                buf.ctypes.data_as(u8p), len(buf),
-                offs.ctypes.data_as(i64p),
-                lens.ctypes.data_as(ct.POINTER(ct.c_int32)),
-                rows.ctypes.data_as(i64p), n, len(plane),
-                plane.ctypes.data_as(u8p), status.ctypes.data_as(u8p))
-            done = rows[status == 0]
+        status = union_dense_sketches(self._lib, data, offs, lens, rows,
+                                      self._set_import_rows)
+        done = np.asarray(rows, np.int64)[status == 0]
+        if len(done):
             self._set_import_touched[done] = True
             self.set_idx.touch_rows(done, self.gen)
             self._staged_n += len(done)

@@ -879,6 +879,8 @@ def apply_decoded(table: MetricTable, data: bytes, cols: dict,
     if nm == 0:
         return 0, 0
     kind = cols["kind"][:nm]
+    # a mesh table takes a wire whole on one shard, the next in turn
+    getattr(table, "begin_wire", lambda: None)()
     with step("resolve"):
         rows = _resolve_rows(table, data, cols, cols["khash"])
     valid = rows >= 0
@@ -1006,11 +1008,11 @@ def _apply_sets(table: MetricTable, data: bytes, cols: dict,
     planes; (accepted, dropped).  A ``MetricTable`` takes the wire's
     dense sketches in one native pass (``import_set_wire``: validated,
     unpacked and maxed into the import plane with no interpreter
-    lock held); the HLL codec decode stays per item (value-level)
-    for what that pass hands back (a sparse sketch from a Go local,
-    a malformed one) and for a table without the batch entry
-    (``ShardedTable`` stages register positions, it has no host
-    plane).  Row resolution and name/tag decode are skipped on cache
+    lock held), and so does a ``ShardedTable``, into the host plane
+    of the wire's shard; the HLL codec decode stays per item
+    (value-level) for what that pass hands back (a sparse sketch from
+    a Go local, a malformed one) and for a table without the batch
+    entry.  Row resolution and name/tag decode are skipped on cache
     hits either way.  ``span`` (``import.apply.sets``) is told how
     many sketches there were and how many went one by one."""
     accepted = dropped = 0
@@ -1237,7 +1239,13 @@ class ImportServer:
                         ledger.recover(f"incarnation:{inc}", acc)
                     if handoff:
                         ledger.credit_reshard_received(acc)
-                work = core._maybe_device_step_locked()
+                work = (core._maybe_device_step_locked()
+                        if core.pipeline else None)
+            if not core.pipeline:
+                # a table that steps inline (the mesh table) does so
+                # under the lock: the same step, timed as the same
+                with imp.step("device_step"):
+                    core._maybe_device_step_locked()
         finally:
             core.lock.release()
         with imp.step("device_step"):

@@ -367,7 +367,9 @@ def _ref_row_plan(table: MetricTable, items: list[dict]) -> tuple[
     cache = getattr(table, "_http_plan_cache", None)
     if cache is None:
         cache = table._http_plan_cache = {}
-    epoch = table._reindex_epoch
+    # a mesh table never re-indexes: it has no epoch (as
+    # grpc_forward's plan cache reads it)
+    epoch = getattr(table, "_reindex_epoch", 0)
     hit = cache.get(key)
     if hit is not None and hit[0] == epoch:
         return hit[1], hit[2]
@@ -540,6 +542,8 @@ def apply_import(table: MetricTable, items: list[dict]) -> tuple[int, int]:
     # single staged part (fused global merge), everything else stages
     # as before
     batch = _WireBatch(table)
+    # a mesh table takes a wire whole on one shard, the next in turn
+    getattr(table, "begin_wire", lambda: None)()
     # reference-schema items batch into one columnar decode; within a
     # mixed-schema body they apply after the native-schema items (gauge
     # last-write-wins order is preserved within each schema)

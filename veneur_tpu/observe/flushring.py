@@ -62,6 +62,16 @@ class FlushRecord:
     import_centroids: int = 0
     import_set_planes: int = 0
     import_set_planes_loose: int = 0
+    # a mesh table's interval (``parallel/sharded.py``; left empty by
+    # one chip's table): SPMD update calls, the items staged to each
+    # shard, the mesh as ``<shards>x<series>``, and the digest path
+    # its flush merge took over the gathered slots (``pallas``, or
+    # the fallback past the kernel's lanes).  The swap's parts are in
+    # ``stages`` under ``snapshot.final_step|shard_merge|state_reset``
+    shard_steps: int = 0
+    shard_staged: list[int] = field(default_factory=list)
+    mesh: str = ""
+    merge_path: str = ""
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
     # collector pauses that ended inside the cycle, on any thread
@@ -96,6 +106,10 @@ class FlushRecord:
                 "import_set_planes": self.import_set_planes,
                 "import_set_planes_loose":
                     self.import_set_planes_loose,
+                "shard_steps": self.shard_steps,
+                "shard_staged": list(self.shard_staged),
+                "mesh": self.mesh,
+                "merge_path": self.merge_path,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
                 "gc_pause_ns": self.gc_pause_ns,

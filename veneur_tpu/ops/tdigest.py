@@ -89,6 +89,21 @@ def resolved_merge_mode() -> str:
     backend that cannot start raises here; it does not pick a mode."""
     return resolve_merge_mode_for(jax.default_backend())
 
+
+def merge_path(cap: int, batch_width: int) -> str:
+    """The path ``_merge_impl`` takes for a ``cap``-slot state and a
+    ``batch_width``-slot batch: the mode in effect, or the fallback
+    where that is the fused kernel and the pair sorts past its lanes.
+    Callers that gather wide (the mesh merge) say so with this; a
+    fallback is theirs to report."""
+    mode = resolved_merge_mode()
+    if mode == "pallas":
+        from veneur_tpu.ops import pallas_merge
+        if not pallas_merge.supported(cap, batch_width):
+            return _FALLBACK_MODE
+    return mode
+
+
 DEFAULT_COMPRESSION = 100.0
 
 _EPS = 1e-30
@@ -252,21 +267,20 @@ def _merge_impl(means: Array, weights: Array, new_means: Array,
             f"into the last slot (use empty_state(R, capacity_for(c)))")
     delta = _SCALE_MULT * compression  # internal scale, see module docstring
 
-    mode = resolved_merge_mode()
+    # past the fused kernel's 2048-lane bound the fallback runs —
+    # none of the one-chip table's own shapes get there (widest: 616
+    # state + 616 union); a mesh merge that gathers over more than
+    # two shards does, and says so (parallel/sharded.py).  Scatter by
+    # default: routing wide ingest chunks through dfcumsum was
+    # measured to cost the timer config ~45% end-to-end (1.02s vs
+    # 0.55s intervals).
+    mode = merge_path(cap, new_means.shape[1])
     if mode == "pallas":
         from veneur_tpu.ops import pallas_merge
-        if pallas_merge.supported(cap, new_means.shape[1]):
-            return pallas_merge.merge_planes(
-                means, weights, new_means, new_weights, delta=delta,
-                tail_coeff=_TAIL_MULT * compression,
-                tail_q0=_TAIL_Q0, tail_qmin=_TAIL_QMIN)
-        # width exceeds the fused kernel's 2048-lane bound — none of
-        # the table's own shapes do (widest: 616 state + 616 union),
-        # so this is the escape hatch for exotic compressions only.
-        # Scatter by default: routing wide ingest chunks through
-        # dfcumsum was measured to cost the timer config ~45%
-        # end-to-end (1.02s vs 0.55s intervals).
-        mode = _FALLBACK_MODE
+        return pallas_merge.merge_planes(
+            means, weights, new_means, new_weights, delta=delta,
+            tail_coeff=_TAIL_MULT * compression,
+            tail_q0=_TAIL_Q0, tail_qmin=_TAIL_QMIN)
 
     m = jnp.concatenate([means, new_means], axis=1)
     w = jnp.concatenate([weights, new_weights], axis=1)

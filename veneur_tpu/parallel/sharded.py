@@ -27,6 +27,8 @@ State planes (leading axis = shard, rows sharded over series):
   gauges        f32[S, R]        merge: value at pmax arrival ticket
   gauge_ticket  i32[S, R]
   histo_stats   f32[S, R, 5]     merge: psum / pmin / pmax per column
+  histo_import_stats  (same)     the forwarded digests' statistics, a
+                                 plane of their own as on one chip
   histo_means   f32[S, R, C]     merge: all_gather slots + one k-scale
   histo_weights f32[S, R, C]            re-cluster (ops.tdigest)
   hll           u8[S, R, M]      merge: pmax over shard (register max,
@@ -39,6 +41,8 @@ communication, and the flush-time collectives ride ICI.
 
 from __future__ import annotations
 
+import contextlib
+import logging
 from dataclasses import dataclass
 from functools import partial
 
@@ -54,6 +58,8 @@ from veneur_tpu.ops import tdigest
 from veneur_tpu.ops.segment import (HISTO_STAT_COLS, STAT_MAX, STAT_MIN,
                                     STAT_MAX_EMPTY, STAT_MIN_EMPTY,
                                     STAT_RSUM, STAT_SUM, STAT_WEIGHT)
+
+log = logging.getLogger("veneur_tpu.parallel.sharded")
 
 SHARD = "shard"
 SERIES = "series"
@@ -107,6 +113,7 @@ def _specs(mesh: Mesh):
     return {
         "counters": st, "gauges": st, "gauge_ticket": st,
         "histo_stats": P(SHARD, SERIES, None),
+        "histo_import_stats": P(SHARD, SERIES, None),
         "histo_means": P(SHARD, SERIES, None),
         "histo_weights": P(SHARD, SERIES, None),
         "hll": P(SHARD, SERIES, None),
@@ -133,6 +140,7 @@ def empty_state(mesh: Mesh, cfg: ShardedConfig) -> dict:
         "gauge_ticket": dev("gauge_ticket",
                             np.full((s, rg), -1, np.int32)),
         "histo_stats": dev("histo_stats", stats),
+        "histo_import_stats": dev("histo_import_stats", stats),
         "histo_means": dev("histo_means",
                            np.zeros((s, r, cap), np.float32)),
         "histo_weights": dev("histo_weights",
@@ -141,17 +149,55 @@ def empty_state(mesh: Mesh, cfg: ShardedConfig) -> dict:
     }
 
 
+# a forwarded digest's statistics, staged as a row of their own in
+# the stat columns' order
+ISTAT_COLS = ("istat_weight", "istat_min", "istat_max", "istat_sum",
+              "istat_rsum")
+
+# the staged batch, column by column: its dtype and the group whose
+# rows it belongs to (a group's columns are staged together and share
+# a length)
+BATCH_COLS = {
+    "counter_rows": (np.int32, "counter"),
+    "counter_vals": (np.float32, "counter"),
+    "counter_wts": (np.float32, "counter"),
+    "gauge_rows": (np.int32, "gauge"),
+    "gauge_vals": (np.float32, "gauge"),
+    "gauge_ticket": (np.int32, "gauge"),
+    "histo_rows": (np.int32, "histo"),
+    "histo_vals": (np.float32, "histo"),
+    "histo_wts": (np.float32, "histo"),
+    "histo_imp": (np.int32, "histo"),    # 1: a forwarded centroid
+    "istat_rows": (np.int32, "istat"),
+    **dict.fromkeys(ISTAT_COLS, (np.float32, "istat")),
+    "set_rows": (np.int32, "set"),
+    "set_idx": (np.int32, "set"),
+    "set_rank": (np.int32, "set"),
+}
+
+
 def batch_specs():
     """Batch arrays are [S, N]: split over shard, replicated over
     series (each series-device sees the full batch and keeps only the
     row ids that fall in its block)."""
-    b = P(SHARD, None)
-    return {k: b for k in (
-        "counter_rows", "counter_vals", "counter_wts",
-        "gauge_rows", "gauge_vals", "gauge_ticket",
-        "histo_rows", "histo_vals", "histo_wts",
-        "rsum_rows", "rsum_vals",
-        "set_rows", "set_idx", "set_rank")}
+    return dict.fromkeys(BATCH_COLS, P(SHARD, None))
+
+
+def _scatter_stats(hs, rows, incoming):
+    """Stat rows ``incoming`` f32[N, 5] into the plane ``hs`` at the
+    block-local ``rows`` (the drop sentinel leaves it alone)."""
+    return jnp.stack([
+        hs[:, STAT_WEIGHT].at[rows].add(incoming[:, STAT_WEIGHT],
+                                        mode="drop"),
+        hs[:, STAT_MIN].at[rows].min(incoming[:, STAT_MIN],
+                                     mode="drop"),
+        hs[:, STAT_MAX].at[rows].max(incoming[:, STAT_MAX],
+                                     mode="drop"),
+        hs[:, STAT_SUM].at[rows].add(incoming[:, STAT_SUM],
+                                     mode="drop"),
+        hs[:, STAT_RSUM].at[rows].add(incoming[:, STAT_RSUM],
+                                      mode="drop"),
+    ], axis=1)
 
 
 def _localize(rows, n_local, axis):
@@ -180,12 +226,13 @@ def make_update_step(mesh: Mesh, cfg: ShardedConfig):
             cfg.c_rows() % n_series or cfg.g_rows() % n_series):
         raise ValueError("rows must divide by the series axis size")
 
-    def step(state, batch):
+    def shard_update(state, batch):
         # every local plane has leading shard dim 1 — squeeze it
         cnt = state["counters"][0]
         g = state["gauges"][0]
         gt = state["gauge_ticket"][0]
         hs = state["histo_stats"][0]
+        his = state["histo_import_stats"][0]
         hm = state["histo_means"][0]
         hw = state["histo_weights"][0]
         regs = state["hll"][0]
@@ -213,29 +260,21 @@ def make_update_step(mesh: Mesh, cfg: ShardedConfig):
         hrow = _localize(batch["histo_rows"][0], r_local, SERIES)
         hv = batch["histo_vals"][0]
         hwt = batch["histo_wts"][0]
-        incoming = jnp.stack([
-            hwt, jnp.where(hwt > 0, hv, STAT_MIN_EMPTY),
-            jnp.where(hwt > 0, hv, STAT_MAX_EMPTY), hv * hwt,
-            jnp.where(hv != 0, hwt / hv, 0.0)], axis=1)
-        hs = jnp.stack([
-            hs[:, STAT_WEIGHT].at[hrow].add(incoming[:, STAT_WEIGHT],
-                                            mode="drop"),
-            hs[:, STAT_MIN].at[hrow].min(incoming[:, STAT_MIN],
-                                         mode="drop"),
-            hs[:, STAT_MAX].at[hrow].max(incoming[:, STAT_MAX],
-                                         mode="drop"),
-            hs[:, STAT_SUM].at[hrow].add(incoming[:, STAT_SUM],
-                                         mode="drop"),
-            hs[:, STAT_RSUM].at[hrow].add(incoming[:, STAT_RSUM],
-                                          mode="drop"),
-        ], axis=1)
-
-        # forwarded-digest reciprocal-sum corrections land directly
-        # in the RSUM column (centroid means alone misstate it; the
-        # import path stages the exact delta)
-        rrow = _localize(batch["rsum_rows"][0], r_local, SERIES)
-        hs = hs.at[rrow, STAT_RSUM].add(batch["rsum_vals"][0],
-                                        mode="drop")
+        # a forwarded centroid feeds the digest alone: its digest's
+        # statistics come exact as a row of their own, into their own
+        # plane (a global emits a mixed-scope row's aggregates from
+        # what it sampled itself, samplers.go:530-621)
+        lw = jnp.where(batch["histo_imp"][0] > 0, 0.0, hwt)
+        hs = jax.lax.cond(
+            jnp.any(lw > 0),     # a batch of forwarded centroids: none
+            lambda: _scatter_stats(hs, hrow, jnp.stack([
+                lw, jnp.where(lw > 0, hv, STAT_MIN_EMPTY),
+                jnp.where(lw > 0, hv, STAT_MAX_EMPTY), hv * lw,
+                jnp.where(hv != 0, lw / hv, 0.0)], axis=1)),
+            lambda: hs)
+        irow = _localize(batch["istat_rows"][0], r_local, SERIES)
+        his = _scatter_stats(his, irow, jnp.stack(
+            [batch[k][0] for k in ISTAT_COLS], axis=1))
 
         dense_v, dense_w = tdigest.densify(hrow, hv, hwt, r_local,
                                            cfg.slots)
@@ -249,21 +288,31 @@ def make_update_step(mesh: Mesh, cfg: ShardedConfig):
         return {
             "counters": cnt[None], "gauges": g[None],
             "gauge_ticket": gt[None], "histo_stats": hs[None],
+            "histo_import_stats": his[None],
             "histo_means": hm[None], "histo_weights": hw[None],
             "hll": regs[None],
         }
 
-    mapped = jax.shard_map(step, mesh=mesh,
-                       in_specs=(state_specs, batch_specs()),
-                       out_specs=state_specs, check_vma=False)
+    mapped = jax.shard_map(shard_update, mesh=mesh,
+                           in_specs=(state_specs, batch_specs()),
+                           out_specs=state_specs, check_vma=False)
     return jax.jit(mapped, donate_argnums=jitopts.donate(0))
+
+
+def _union_registers(regs):
+    """The register max over the shard axis, inside the merge's
+    shard_map.  Widened for the all-reduce: a TPU reduces a u8 plane
+    four rows to a 32-bit word and keeps the whole word of the shard
+    whose word is largest, dropping the other shards' registers."""
+    return jax.lax.pmax(regs.astype(jnp.int32), SHARD).astype(jnp.uint8)
 
 
 def make_merge_step(mesh: Mesh, cfg: ShardedConfig):
     """Jitted SPMD flush merge: partial per-shard state -> one merged
     table, via ICI collectives.
 
-    counter psum / gauge ticket-pmax / stat psum+pmin+pmax / t-digest
+    counter psum / gauge ticket-pmax / stat psum+pmin+pmax (the
+    sampled and the forwarded plane alike) / t-digest
     all_gather+re-cluster / HLL register pmax — the device-side
     equivalent of the reference's import-merge semantics
     (samplers.go:208 Counter.Merge, :423 Set.Merge, :726 Histo.Merge).
@@ -272,12 +321,22 @@ def make_merge_step(mesh: Mesh, cfg: ShardedConfig):
     merged_specs = {
         "counters": P(SERIES), "gauges": P(SERIES),
         "histo_stats": P(SERIES, None),
+        "histo_import_stats": P(SERIES, None),
         "histo_means": P(SERIES, None),
         "histo_weights": P(SERIES, None),
         "hll": P(SERIES, None),
     }
 
-    def merge(state):
+    def union_stats(hs):
+        return jnp.stack([
+            jax.lax.psum(hs[:, STAT_WEIGHT], SHARD),
+            jax.lax.pmin(hs[:, STAT_MIN], SHARD),
+            jax.lax.pmax(hs[:, STAT_MAX], SHARD),
+            jax.lax.psum(hs[:, STAT_SUM], SHARD),
+            jax.lax.psum(hs[:, STAT_RSUM], SHARD),
+        ], axis=1)
+
+    def shard_merge(state):
         cnt = jax.lax.psum(state["counters"][0], SHARD)
 
         ticket = state["gauge_ticket"][0]
@@ -287,14 +346,8 @@ def make_merge_step(mesh: Mesh, cfg: ShardedConfig):
                       state["gauges"][0], -jnp.inf), SHARD)
         gauges = jnp.where(best >= 0, gv, 0.0)
 
-        hs = state["histo_stats"][0]
-        stats = jnp.stack([
-            jax.lax.psum(hs[:, STAT_WEIGHT], SHARD),
-            jax.lax.pmin(hs[:, STAT_MIN], SHARD),
-            jax.lax.pmax(hs[:, STAT_MAX], SHARD),
-            jax.lax.psum(hs[:, STAT_SUM], SHARD),
-            jax.lax.psum(hs[:, STAT_RSUM], SHARD),
-        ], axis=1)
+        stats = union_stats(state["histo_stats"][0])
+        istats = union_stats(state["histo_import_stats"][0])
 
         # digest union: gather every shard's centroid slots along the
         # slot axis, then one batched re-cluster into fresh planes
@@ -307,18 +360,19 @@ def make_merge_step(mesh: Mesh, cfg: ShardedConfig):
         mm, mw = tdigest._merge_impl(zm, zw, gm, gw,
                                      compression=cfg.compression)
 
-        # widened for the all-reduce: a TPU reduces a u8 plane four
-        # rows to a 32-bit word and keeps the whole word of the shard
-        # whose word is largest, dropping the other shards' registers
-        regs = jax.lax.pmax(state["hll"][0].astype(jnp.int32),
-                            SHARD).astype(jnp.uint8)
+        regs = _union_registers(state["hll"][0])
 
         return {"counters": cnt, "gauges": gauges, "histo_stats": stats,
+                "histo_import_stats": istats,
                 "histo_means": mm, "histo_weights": mw, "hll": regs}
 
-    mapped = jax.shard_map(merge, mesh=mesh, in_specs=(state_specs,),
-                       out_specs=merged_specs, check_vma=False)
+    mapped = jax.shard_map(shard_merge, mesh=mesh,
+                           in_specs=(state_specs,),
+                           out_specs=merged_specs, check_vma=False)
     return jax.jit(mapped)
+
+
+_max_planes = jax.jit(jnp.maximum)
 
 
 def readout(merged: dict, qs: np.ndarray) -> dict:
@@ -328,8 +382,10 @@ def readout(merged: dict, qs: np.ndarray) -> dict:
     quant = tdigest.quantile(
         merged["histo_means"], merged["histo_weights"],
         jnp.asarray(qs, jnp.float32),
-        merged["histo_stats"][:, STAT_MIN],
-        merged["histo_stats"][:, STAT_MAX])
+        jnp.minimum(merged["histo_stats"][:, STAT_MIN],
+                    merged["histo_import_stats"][:, STAT_MIN]),
+        jnp.maximum(merged["histo_stats"][:, STAT_MAX],
+                    merged["histo_import_stats"][:, STAT_MAX]))
     est = hll_ops.estimate(merged["hll"])
     return {"quantiles": quant, "hll_estimate": est}
 
@@ -521,15 +577,45 @@ class ShardedAggregator:
         self._merge = make_merge_step(mesh, self.cfg)
         self._ticket = 0
         self._stage = [self._empty_stage() for _ in range(self.n_shard)]
+        self._batch_sharding = {
+            k: NamedSharding(mesh, spec)
+            for k, spec in batch_specs().items()}
+        # what pads a batch column: the drop sentinel for rows
+        self._fill = {"counter_rows": self.cfg.c_rows(),
+                      "gauge_rows": self.cfg.g_rows(),
+                      "histo_rows": self.cfg.rows,
+                      "istat_rows": self.cfg.rows,
+                      "set_rows": self.cfg.set_rows, "gauge_ticket": -1}
+        # what the merge does on this mesh, for the flush's record and
+        # its ``shard_merge`` span: every shard's slots gathered a
+        # row, and the digest path that width takes
+        cap = self.cfg.capacity()
+        self.merge_info = {
+            "mesh": f"{self.n_shard}x{mesh.shape[SERIES]}",
+            "shards": self.n_shard, "series": mesh.shape[SERIES],
+            "gathered_slots": cap * self.n_shard,
+            "merge_path": tdigest.merge_path(cap, cap * self.n_shard)}
+        if self.merge_info["merge_path"] != tdigest.resolved_merge_mode():
+            log.warning(
+                "mesh %s gathers %d slots a digest row, past the fused "
+                "merge kernel's lanes: the flush merge takes the %s "
+                "path", self.merge_info["mesh"],
+                self.merge_info["gathered_slots"],
+                self.merge_info["merge_path"])
+        # the interval's SPMD update calls and the items staged to
+        # each shard (every staged column entry, every unioned sketch)
+        self.steps = 0
+        self.staged = [0] * self.n_shard
+        # forwarded sketches: a host register plane a shard, unioned
+        # natively as the wires arrive and shipped whole at the merge;
+        # made with its touched mask at the first imported sketch
+        self._set_planes: np.ndarray | None = None
+        self._set_touched = np.zeros(
+            (self.n_shard, self.cfg.set_rows), bool)
 
     @staticmethod
     def _empty_stage():
-        return {k: [] for k in (
-            "counter_rows", "counter_vals", "counter_wts",
-            "gauge_rows", "gauge_vals", "gauge_ticket",
-            "histo_rows", "histo_vals", "histo_wts",
-            "rsum_rows", "rsum_vals",
-            "set_rows", "set_idx", "set_rank")}
+        return {k: [] for k in BATCH_COLS}
 
     def next_ticket(self, n: int = 1) -> np.ndarray:
         t = np.arange(self._ticket, self._ticket + n, dtype=np.int32)
@@ -537,18 +623,32 @@ class ShardedAggregator:
         return t
 
     def stage(self, shard: int, **cols) -> None:
-        st = self._stage[shard % self.n_shard]
+        shard %= self.n_shard
+        st = self._stage[shard]
+        if "histo_rows" in cols and "histo_imp" not in cols:
+            # samples of this node's own: not forwarded
+            cols["histo_imp"] = np.zeros(np.size(cols["histo_rows"]),
+                                         np.int32)
         for k, v in cols.items():
-            st[k].append(np.asarray(v))
+            v = np.asarray(v)
+            st[k].append(v)
+            if k in self._COUNTED:
+                self.staged[shard] += v.size
 
-    _DTYPES = {"counter_rows": np.int32, "counter_vals": np.float32,
-               "counter_wts": np.float32, "gauge_rows": np.int32,
-               "gauge_vals": np.float32, "gauge_ticket": np.int32,
-               "histo_rows": np.int32, "histo_vals": np.float32,
-               "histo_wts": np.float32,
-               "rsum_rows": np.int32, "rsum_vals": np.float32,
-               "set_rows": np.int32,
-               "set_idx": np.int32, "set_rank": np.int32}
+    # one column a staged group: its length is the group's items
+    _COUNTED = ("counter_rows", "gauge_rows", "histo_rows", "istat_rows",
+                "set_rows")
+
+    def set_plane(self, shard: int) -> tuple[np.ndarray, np.ndarray]:
+        """(register plane u8[set_rows, M], touched rows) of the
+        host-side union of ``shard``'s forwarded sketches."""
+        if self._set_planes is None:
+            self._set_planes = np.zeros(
+                (self.n_shard, self.cfg.set_rows, hll_ops.M), np.uint8)
+        shard %= self.n_shard
+        return self._set_planes[shard], self._set_touched[shard]
+
+    _DTYPES = {k: dt for k, (dt, _) in BATCH_COLS.items()}
 
     def step(self) -> None:
         """Push staged samples through SPMD updates.
@@ -617,40 +717,27 @@ class ShardedAggregator:
                     sels.append(idx[off:off + n])
             return sels
 
-        group_of = {"counter_rows": "counter", "counter_vals": "counter",
-                    "counter_wts": "counter", "gauge_rows": "gauge",
-                    "gauge_vals": "gauge", "gauge_ticket": "gauge",
-                    "histo_rows": "histo", "histo_vals": "histo",
-                    "histo_wts": "histo",
-                    "rsum_rows": "rsum", "rsum_vals": "rsum",
-                    "set_rows": "set",
-                    "set_idx": "set", "set_rank": "set"}
         sels: dict[tuple[str, int], list[np.ndarray]] = {}
         n_calls = 0
         for si in range(self.n_shard):
             sels[("histo", si)] = _histo_sels(cols["histo_rows"][si])
             for grp, key in (("counter", "counter_rows"),
                              ("gauge", "gauge_rows"),
-                             ("rsum", "rsum_rows"),
+                             ("istat", "istat_rows"),
                              ("set", "set_rows")):
                 sels[(grp, si)] = _pos_sels(len(cols[key][si]))
             n_calls = max(n_calls, *(len(sels[(g, si)]) for g in
                                      ("histo", "counter", "gauge",
-                                      "rsum", "set")), 0)
+                                      "istat", "set")), 0)
 
-        specs = batch_specs()
+        self.steps += n_calls
         for ci in range(n_calls):
             batch = {}
             for key, dt in self._DTYPES.items():
-                fill = {"counter_rows": self.cfg.c_rows(),
-                        "gauge_rows": self.cfg.g_rows(),
-                        "histo_rows": self.cfg.rows,
-                        "rsum_rows": self.cfg.rows,
-                        "set_rows": self.cfg.set_rows,
-                        "gauge_ticket": -1}.get(key, 0)
+                fill = self._fill.get(key, 0)
                 planes = []
                 for si in range(self.n_shard):
-                    grp_sels = sels[(group_of[key], si)]
+                    grp_sels = sels[(BATCH_COLS[key][1], si)]
                     col = (cols[key][si][grp_sels[ci]]
                            if ci < len(grp_sels) else
                            cols[key][si][:0])
@@ -658,36 +745,79 @@ class ShardedAggregator:
                     plane[:len(col)] = col
                     planes.append(plane)
                 batch[key] = np.stack(planes)
-            jbatch = {k: jax.device_put(
-                jnp.asarray(v), NamedSharding(self.mesh, specs[k]))
-                for k, v in batch.items()}
-            self.state = self._update(self.state, jbatch)
+            self.state = self._update(
+                self.state, jax.device_put(batch, self._batch_sharding))
+
+    def compile(self) -> None:
+        """Compile the update and merge programs before traffic
+        arrives: an all-padding batch (every row the drop sentinel)
+        through the update step and the state through the merge,
+        whose result is dropped.  A first-sight compile takes tens of seconds
+        on a mesh and would otherwise run inside an import handler or
+        a flush, under the server's ingest lock."""
+        batch = {k: np.full((self.n_shard, self.cfg.batch),
+                            self._fill.get(k, 0), dt)
+                 for k, dt in self._DTYPES.items()}
+        # a batch of padding leaves the state as it was (and a build
+        # that donates the state has the new one in its place)
+        self.state = self._update(
+            self.state, jax.device_put(batch, self._batch_sharding))
+        self.set_plane(0)       # the sketches' union compiles as well
+        jax.block_until_ready(self._merge({
+            **self.state,
+            "hll": self._with_set_planes(self.state["hll"])}))
+
+    def _with_set_planes(self, regs):
+        """``regs`` with the host planes of the forwarded sketches
+        maxed in, shard by shard."""
+        planes = jax.device_put(
+            self._set_planes, NamedSharding(self.mesh,
+                                            _specs(self.mesh)["hll"]))
+        return _max_planes(regs, planes)
+
+    def merge(self) -> dict:
+        """Union the shards' partials with collectives.  FENCED before
+        returning: the collectives must finish while no other device
+        program can be dispatched.  On an oversubscribed host (virtual
+        CPU mesh, or a shared-core TPU host under ingest load) a
+        partition of an in-flight collective can starve past XLA's
+        40s rendezvous termination — which aborts the whole process —
+        if later-dispatched programs compete for the executor pool.
+        One synchronous point per flush interval costs ~nothing next
+        to what it rules out."""
+        state = self.state
+        if self._set_touched.any():
+            state = {**state,
+                     "hll": self._with_set_planes(state["hll"])}
+        merged = self._merge(state)
+        jax.block_until_ready(merged)
+        return merged
+
+    def reset(self) -> None:
+        """Fresh partial state for the next interval (the
+        double-buffer swap the single-chip table does at flush,
+        worker.go:498).  The host planes are cleared where the closed
+        interval wrote them; the merge that read them is fenced."""
+        self.state = empty_state(self.mesh, self.cfg)
+        if self._set_planes is not None:
+            self._set_planes[self._set_touched] = 0
+            self._set_touched[:] = False
+        self.steps = 0
+        self.staged = [0] * self.n_shard
 
     def flush(self, qs=(0.5, 0.9, 0.99)) -> dict:
         """Merge partial shards with collectives and read out."""
-        merged = self._merge(self.state)
+        merged = self.merge()
         out = readout(merged, np.asarray(qs, np.float32))
         merged.update(out)
         return merged
 
     def swap(self) -> dict:
         """Interval boundary: push any staged work, merge, and reset
-        the partial state for the next interval (the double-buffer
-        swap the single-chip table does at flush, worker.go:498).
-
-        The merge is FENCED before returning: its collectives must
-        finish while no other device program can be dispatched.  On
-        an oversubscribed host (virtual CPU mesh, or a shared-core
-        TPU host under ingest load) a partition of an in-flight
-        collective can starve past XLA's 40s rendezvous termination
-        — which aborts the whole process — if later-dispatched
-        programs compete for the executor pool.  One synchronous
-        point per flush interval costs ~nothing next to what it
-        rules out."""
+        the partial state for the next interval."""
         self.step()
-        merged = self._merge(self.state)
-        jax.block_until_ready(merged)
-        self.state = empty_state(self.mesh, self.cfg)
+        merged = self.merge()
+        self.reset()
         return merged
 
 
@@ -729,12 +859,27 @@ class ShardedTable:
         # cross-checks it against site-credited staged totals
         self._interval_ingested = 0
         self._rr = 0  # round-robin shard cursor
+        # what the interval's imports came to, under the names the
+        # single-chip table counts them by (the snapshot carries it to
+        # the cycle's ``FlushRecord``, ``import_*``)
+        self.import_counts = dict.fromkeys(
+            ("centroids", "set_planes", "set_planes_loose"), 0)
+        from veneur_tpu import native
+        self._lib = native.load()
 
     # -- ingest (the slow-path Sample surface the Server uses) --------
 
     def _next_shard(self) -> int:
         self._rr = (self._rr + 1) % self.agg.n_shard
         return self._rr
+
+    def begin_wire(self) -> None:
+        """A decoded wire goes whole to one shard, the next in turn:
+        its batch appliers stage to the cursor where this leaves it.
+        Both import paths call it once a wire (``grpc_forward.
+        apply_decoded``, ``http_import.apply_import``).  Items that
+        come one by one each take the next shard."""
+        self._next_shard()
 
     def ingest(self, s) -> bool:
         from veneur_tpu.protocol import dogstatsd as dsd
@@ -842,7 +987,7 @@ class ShardedTable:
 
     def import_counter_batch(self, rows, values) -> None:
         rows = np.ascontiguousarray(rows, np.int64)
-        self.agg.stage(self._next_shard(),
+        self.agg.stage(self._rr,
                        counter_rows=rows.astype(np.int32),
                        counter_vals=np.asarray(values, np.float32),
                        counter_wts=np.ones(len(rows), np.float32))
@@ -852,33 +997,59 @@ class ShardedTable:
 
     def import_gauge_batch(self, rows, values) -> None:
         # one ticket per write preserves last-write-wins in wire
-        # order across the whole mesh (stage() takes one ticket per
-        # call, so gauges stage individually)
+        # order across the whole mesh
         rows = np.ascontiguousarray(rows, np.int64)
-        values = np.asarray(values, np.float64)
-        for r, v in zip(rows, values):
-            self.agg.stage(self._next_shard(), gauge_rows=[int(r)],
-                           gauge_vals=[float(v)],
-                           gauge_ticket=self.agg.next_ticket())
+        self.agg.stage(self._rr, gauge_rows=rows.astype(np.int32),
+                       gauge_vals=np.asarray(values, np.float32),
+                       gauge_ticket=self.agg.next_ticket(len(rows)))
         self.gauge_idx.touch_rows(rows, self.gen)
         self._staged_n += len(rows)
         self._interval_ingested += len(rows)
 
     def import_set_at(self, row, regs) -> None:
+        """One forwarded sketch for a resolved row: a 16 KiB register
+        max into the host plane of the next shard in turn (Set.Merge,
+        samplers/samplers.go:423)."""
         regs = np.asarray(regs, np.uint8)
         if regs.shape != (hll_ops.M,):
             raise ValueError(f"bad register plane shape {regs.shape}")
-        nz = np.nonzero(regs)[0]
-        if len(nz):
-            self.agg.stage(self._next_shard(),
-                           set_rows=np.full(len(nz), int(row),
-                                            np.int32),
-                           set_idx=nz.astype(np.int32),
-                           set_rank=regs[nz].astype(np.int32))
+        plane, touched = self.agg.set_plane(self._next_shard())
+        np.maximum(plane[row], regs, out=plane[row])
+        touched[row] = True
+        self.agg.staged[self._rr] += 1
         self.set_idx.touched[row] = True
         self.set_idx.last_gen[row] = self.gen
-        self._staged_n += max(1, len(nz))
+        self._staged_n += 1
         self._interval_ingested += 1
+        self.import_counts["set_planes"] += 1
+
+    def import_set_wire(self, data: bytes, offs: np.ndarray,
+                        lens: np.ndarray,
+                        rows: np.ndarray) -> np.ndarray:
+        """``MetricTable.import_set_wire`` for the mesh: the dense
+        sketches of one decoded wire, validated, unpacked and maxed in
+        one native call (vtpu_hll_union_dense, no interpreter lock
+        held) into the host plane of the wire's shard, which reaches
+        the device whole at the flush merge.  Returns a status an
+        item: 0 unioned, non-zero left for ``hll_codec.decode`` +
+        ``import_set_at`` one by one (sparse, malformed; all of them
+        without the native library), counted as
+        ``import_counts["set_planes_loose"]``."""
+        from veneur_tpu.core import table as core_table
+        plane, touched = self.agg.set_plane(self._rr)
+        status = core_table.union_dense_sketches(
+            self._lib, data, offs, lens, rows, lambda: plane)
+        done = np.asarray(rows, np.int64)[status == 0]
+        if len(done):
+            touched[done] = True
+            self.agg.staged[self._rr] += len(done)
+            self.set_idx.touch_rows(done, self.gen)
+            self._staged_n += len(done)
+            self._interval_ingested += len(done)
+            self.import_counts["set_planes"] += len(done)
+        self.import_counts["set_planes_loose"] += int(
+            (status != 0).sum())
+        return status
 
     def import_counter(self, name, tags, value) -> bool:
         from veneur_tpu.protocol import dogstatsd as dsd
@@ -915,19 +1086,17 @@ class ShardedTable:
 
     def import_histo(self, name, mtype, tags, stats, means, weights,
                      scope=None) -> bool:
-        """Forwarded digest: centroids re-enter as weighted samples
-        (a centroid IS a weighted sample; min/max ride separately as
-        two weight-epsilon anchor samples so the merged stats keep the
-        true extremes, and the reciprocal-sum delta lands in a direct
-        RSUM correction — centroid means alone misstate it)."""
-        import numpy as _np
+        """Forwarded digest: its centroids re-enter the row's digest
+        as weighted samples (a centroid IS a weighted sample) marked
+        forwarded, its statistics as one exact row of the import
+        plane (``import_histo_batch``, one digest)."""
         from veneur_tpu.ops import segment
         # shapes validated BEFORE anything stages, matching the
         # single-chip contract (table.py import_histo): a malformed
         # item must not leave half its state staged
-        stats = _np.asarray(stats, _np.float32)
-        means = _np.asarray(means, _np.float32)
-        weights = _np.asarray(weights, _np.float32)
+        stats = np.asarray(stats, np.float32)
+        means = np.asarray(means, np.float32)
+        weights = np.asarray(weights, np.float32)
         if stats.shape != (segment.HISTO_STAT_COLS,):
             raise ValueError(f"bad stats shape {stats.shape}")
         if means.shape != weights.shape or means.ndim != 1:
@@ -937,120 +1106,50 @@ class ShardedTable:
         row = self.import_histo_row(name, mtype, tags, scope)
         if row is None:
             return False
+        self._next_shard()
         live = weights > 0
-        n_live = int(live.sum())
-        sh = self._next_shard()
-        eps = _np.float32(1e-6)
-        rsum_from_samples = 0.0
-        if n_live:
-            self.agg.stage(sh,
-                           histo_rows=_np.full(n_live, row, _np.int32),
-                           histo_vals=means[live],
-                           histo_wts=weights[live])
-            nz = live & (means != 0)
-            rsum_from_samples = float(
-                (weights[nz] / means[nz]).sum())
-        w = float(stats[segment.STAT_WEIGHT])
-        if w > 0:
-            # zero-ish-weight anchors carry the forwarded min/max into
-            # the stat plane without perturbing sums
-            mn = float(stats[segment.STAT_MIN])
-            mx = float(stats[segment.STAT_MAX])
-            self.agg.stage(sh, histo_rows=[row, row],
-                           histo_vals=[mn, mx], histo_wts=[eps, eps])
-            if mn != 0:
-                rsum_from_samples += float(eps) / mn
-            if mx != 0:
-                rsum_from_samples += float(eps) / mx
-        # exact forwarded rsum minus what the staged samples will add
-        corr = float(stats[segment.STAT_RSUM]) - rsum_from_samples
-        if corr:
-            self.agg.stage(sh, rsum_rows=[row], rsum_vals=[corr])
-        # count every ACTUALLY staged item: the staging-memory bound
-        # that triggers device_step rides on this counter (table.py:694)
-        self._staged_n += (n_live + (2 if w > 0 else 0) +
-                           (1 if corr else 0))
-        self._interval_ingested += 1
+        self.import_histo_batch(
+            np.asarray([row]), stats[None],
+            np.full(int(live.sum()), row, np.int32), means[live],
+            weights[live])
         return True
 
     def import_histo_batch(self, rows, stats, cent_rows, cent_means,
                            cent_weights) -> None:
-        """Columnar sibling of import_histo with the SAME fidelity:
-        min/max eps anchors and an exact per-row RSUM correction (the
-        gRPC import fast path must not diverge from the scalar
-        path)."""
-        import numpy as _np
-        from veneur_tpu.ops import segment
-        rows = _np.ascontiguousarray(rows, _np.int64)
-        sh = self._next_shard()
-        n_staged = 0
-        nrows = self.cfg.rows
-        # per-row rsum contribution of the staged centroids
-        rsum_samples = _np.zeros(nrows, _np.float64)
+        """Forwarded digests, columnar, to the wire's shard: the
+        centroids staged as weighted samples marked forwarded (they
+        feed the digests alone), each digest's statistics staged
+        exact as a row for the import plane."""
+        rows = np.ascontiguousarray(rows, np.int64)
+        sh = self._rr
         if len(cent_rows):
             self.agg.stage(sh, histo_rows=cent_rows,
                            histo_vals=cent_means,
-                           histo_wts=cent_weights)
-            n_staged += len(cent_rows)
-            cr = _np.ascontiguousarray(cent_rows, _np.int64)
-            nz = cent_means != 0
-            rsum_samples += _np.bincount(
-                cr[nz], weights=cent_weights[nz] / cent_means[nz],
-                minlength=nrows)[:nrows]
-        live = stats[:, segment.STAT_WEIGHT] > 0
-        if live.any():
-            eps = _np.float32(1e-6)
-            r = rows[live]
-            mns = stats[live, segment.STAT_MIN]
-            mxs = stats[live, segment.STAT_MAX]
-            self.agg.stage(
-                sh,
-                histo_rows=_np.concatenate([r, r]).astype(_np.int32),
-                histo_vals=_np.concatenate([mns, mxs]),
-                histo_wts=_np.full(2 * len(r), eps, _np.float32))
-            n_staged += 2 * len(r)
-            for vals in (mns, mxs):
-                vnz = vals != 0
-                rsum_samples += _np.bincount(
-                    r[vnz], weights=float(eps) / vals[vnz],
-                    minlength=nrows)[:nrows]
-        # exact forwarded rsum per row minus what the samples will add
-        rsum_true = _np.bincount(
-            rows, weights=stats[:, segment.STAT_RSUM].astype(
-                _np.float64), minlength=nrows)[:nrows]
-        corr = rsum_true - rsum_samples
-        crows = _np.nonzero(corr)[0]
-        if len(crows):
-            self.agg.stage(sh, rsum_rows=crows.astype(_np.int32),
-                           rsum_vals=corr[crows].astype(_np.float32))
-            n_staged += len(crows)
+                           histo_wts=cent_weights,
+                           histo_imp=np.ones(len(cent_rows), np.int32))
+        stats = np.asarray(stats, np.float32)
+        self.agg.stage(sh, istat_rows=rows.astype(np.int32), **{
+            k: stats[:, i] for i, k in enumerate(ISTAT_COLS)})
         # rows may arrive cache-resolved (no lookup ran): touch them
         # so flush emission sees the series
         self.histo_idx.touch_rows(rows, self.gen)
-        self._staged_n += n_staged
+        # every staged item counts: the staging-memory bound that
+        # triggers device_step rides on this counter
+        self._staged_n += len(cent_rows) + len(rows)
         self._interval_ingested += len(rows)
+        self.import_counts["centroids"] += len(cent_rows)
 
     def import_set(self, name, tags, regs, scope=None) -> bool:
-        """Forwarded HLL plane: registers convert to (idx, rank)
-        positions (a register IS the max rank seen at that index)."""
-        import numpy as _np
+        """Forwarded HLL plane by name: the row resolved, then
+        ``import_set_at``."""
         from veneur_tpu.protocol import dogstatsd as dsd
-        regs = _np.asarray(regs, _np.uint8)
+        regs = np.asarray(regs, np.uint8)
         if regs.shape != (hll_ops.M,):
             raise ValueError(f"bad register plane shape {regs.shape}")
-        scope = scope or dsd.SCOPE_DEFAULT
-        row = self.set_idx.lookup((name, dsd.SET, tags, scope), name,
-                                  tags, scope, dsd.SET, self.gen)
+        row = self.import_set_row(name, tags, scope)
         if row is None:
             return False
-        nz = _np.nonzero(regs)[0]
-        if len(nz):
-            self.agg.stage(self._next_shard(),
-                           set_rows=_np.full(len(nz), row, _np.int32),
-                           set_idx=nz.astype(_np.int32),
-                           set_rank=regs[nz].astype(_np.int32))
-        self._staged_n += max(1, len(nz))
-        self._interval_ingested += 1
+        self.import_set_at(row, regs)
         return True
 
     # -- lifecycle -----------------------------------------------------
@@ -1073,7 +1172,10 @@ class ShardedTable:
                 for name, a in self.agg.state.items()}
 
     def device_step(self, final: bool = False) -> None:
-        if final or self._staged_n >= self.cfg.batch:
+        """Whatever is staged through SPMD updates.  When to step is
+        the caller's to say (the server's staging threshold,
+        ``_maybe_device_step_locked``; the swap's ``final``)."""
+        if final or self._staged_n:
             self.agg.step()
             self._staged_n = 0
 
@@ -1082,19 +1184,35 @@ class ShardedTable:
         self.status = {}
         return out
 
-    def swap(self):
+    def swap(self, stage=lambda name: contextlib.nullcontext()):
         """Interval boundary -> a core-table Snapshot the Flusher
         consumes unchanged: merged planes land in the same fields the
-        single-chip table fills, with the merged stat plane serving as
-        the local-stats plane and an identity import plane."""
+        single-chip table fills.
+
+        ``stage(name)`` times the three parts and yields the part's
+        span or None (the server passes its ``snapshot`` stage's):
+        ``final_step``, the staged remainder through SPMD updates;
+        ``shard_merge``, the collective merge up to its fence, tagged
+        with what it did; ``state_reset``, the fresh state on the
+        mesh."""
         from veneur_tpu.core import table as core_table
-        from veneur_tpu.ops import segment
-        self.device_step(final=True)
-        merged = self.agg.swap()
-        rows, set_rows = self.cfg.rows, self.cfg.set_rows
-        imp = np.zeros((rows, segment.HISTO_STAT_COLS), np.float32)
-        imp[:, segment.STAT_MIN] = segment.STAT_MIN_EMPTY
-        imp[:, segment.STAT_MAX] = segment.STAT_MAX_EMPTY
+        with stage("final_step"):
+            self.device_step(final=True)
+        info = self.agg.merge_info
+        with stage("shard_merge") as sp:
+            merged = self.agg.merge()
+            if sp is not None:
+                for k, v in {**info, "histo_rows_live": int(
+                        self.histo_idx.touched.sum()),
+                        "set_rows_live": int(
+                            self.set_idx.touched.sum())}.items():
+                    sp.add_tag(k, str(v))
+        mesh_counts = {"shard_steps": self.agg.steps,
+                       "shard_staged": list(self.agg.staged),
+                       "mesh": info["mesh"],
+                       "merge_path": info["merge_path"]}
+        with stage("state_reset"):
+            self.agg.reset()
         snap = core_table.Snapshot(
             gen=self.gen,
             counters=merged["counters"],
@@ -1104,7 +1222,7 @@ class ShardedTable:
             gauge_meta=list(self.gauge_idx.meta),
             gauge_touched=self.gauge_idx.touched.copy(),
             histo_stats=merged["histo_stats"],
-            histo_import_stats=imp,
+            histo_import_stats=merged["histo_import_stats"],
             histo_means=merged["histo_means"],
             histo_weights=merged["histo_weights"],
             histo_meta=list(self.histo_idx.meta),
@@ -1124,7 +1242,10 @@ class ShardedTable:
                 "histo": self.histo_idx.overflow,
                 "set": self.set_idx.overflow,
             },
-            ingested=self._interval_ingested)
+            ingested=self._interval_ingested,
+            import_counts=dict(self.import_counts),
+            mesh_counts=mesh_counts)
+        self.import_counts = dict.fromkeys(self.import_counts, 0)
         self._interval_ingested = 0
         self.gen += 1
         for idx in (self.counter_idx, self.gauge_idx, self.histo_idx,
