@@ -8,38 +8,40 @@ profiled at ~60% of the merge (round-2 note in ops/tdigest.py).  This
 kernel does the whole per-row merge in VMEM in one pass:
 
   HBM read (means,weights) -> bitonic sort (lanes) -> log-step cumsum
-  -> k-scale cluster ids -> per-row one-hot matmul segment sums (MXU)
-  -> compact (second bitonic) -> HBM write
+  -> k-scale cluster ids -> run ends -> compensated prefix sums
+  -> compact the run ends to the front -> difference -> HBM write
 
 so the planes cross HBM exactly once each way and the serial scatter
 disappears entirely.  Cluster semantics mirror _merge_impl exactly
 (same scale constants are passed in by ops/tdigest so the two paths
 can never drift): sort by mean with empty slots keyed to +inf,
 ``q_left`` from the cumulative weight, ``floor(k(q)-k(0))`` cluster
-ids clipped to the plane capacity, weighted per-cluster means.  The
-only numeric difference is the q cumsum running in plain f32 (the XLA
-scatter path sums clusters in scatter order; dfcumsum compensates a
-boundary-difference scheme).  Here per-cluster sums are DIRECT masked
-dot products — each weight is summed exactly once into its own
-cluster, so no compensation is needed; the f32 cumsum feeds only the
-cluster-id floor, where a 1e-7 relative error can at most move a
-boundary-straddling centroid into the adjacent cluster (both
-assignments are valid t-digests).
+ids clipped to the plane capacity, weighted per-cluster means.  The q
+cumsum runs in plain f32: it feeds only the cluster-id floor, where a
+1e-7 relative error can at most move a boundary-straddling centroid
+into the adjacent cluster (both assignments are valid t-digests).
 
-Bitonic compare-exchange and the Hillis-Steele cumsum use static
-slice+concat rotations only (no dynamic gathers, no lane reshapes),
-which Mosaic lowers without relayout surprises; the one transpose per
-row (cluster ids to the sublane axis for the one-hot mask) is what
-buys the MXU segment reduction.
+After the sort the ids are non-decreasing along the lanes (a prefix
+max makes that hold at f32 rounding too), so each cluster is one run
+of lanes and its sums are double-float prefix sums (ops/tdigest
+``_df_add``) differenced at consecutive run ends (``_df_diff``): the
+compensation keeps a weight-1 tail cluster exact beside a heavy bulk,
+where a plain f32 difference was measured to corrupt p999.  The run
+ends are compacted to the front by a log-step shift network, so the
+clusters come out packed and in ascending mean with no second sort.
+
+Bitonic compare-exchange, the scans and the compaction use static
+slice+concat rotations only (no dynamic gathers, no lane reshapes,
+no transposes, no matmuls), which Mosaic lowers without relayout
+surprises.
 
 This is the third merge strategy, selected with VENEUR_TPU_MERGE=
 pallas and the "auto" default on TPU backends (see
 ops/tdigest._MERGE_MODE).  It handles combined plane widths up to
 _MAX_WIDTH = 2048, which covers every shape the table emits: the
 timer ingest chunks (616 + up to 512 slots), and the global tier's
-digest-vs-digest union (616 + 616).  The one-hot mask is built in
-column chunks of _MASK_CHUNK so VMEM holds N x 512, not N^2; only
-genuinely wider calls fall back to the XLA path.
+digest-vs-digest union (616 + 616); only genuinely wider calls fall
+back to the XLA path.
 
 Reference analog: tdigest/merging_digest.go:140 ``mergeAllTemps`` /
 :229 ``mergeOne`` — the serial greedy pass this kernel replaces with
@@ -57,12 +59,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from veneur_tpu.ops.tdigest import _df_add, _df_diff
+
 Array = jax.Array
 
 _BLOCK_ROWS = 8      # f32 sublane tile; rows per grid step
-_MAX_WIDTH = 2048    # pow2 sort width bound (mask is column-chunked,
-#                      so VMEM holds N*_MASK_CHUNK, not N*N)
-_MASK_CHUNK = 512    # one-hot mask column chunk (N x 512 bf16 = 2 MB)
+_MAX_WIDTH = 2048    # pow2 sort width bound: the table's widest
+#                      shapes (616 + 512, 616 + 616) sort at 2048
 _EPS = 1e-30
 
 # Interpret-mode gate for CPU testing: the kernel runs through the
@@ -157,16 +160,45 @@ def _asin(x: Array) -> Array:
     return jnp.where(x < 0, -r, r)
 
 
-def _cumsum_lanes(w: Array, n: int) -> Array:
-    """Hillis-Steele inclusive prefix sum along lanes (log2(n) adds)."""
-    c = w
+def _shift_in(x: Array, s: int, fill) -> Array:
+    """x[i] <- x[i-s] along lanes, the first s lanes ``fill`` (static s)."""
+    return jnp.concatenate([jnp.full_like(x[:, :s], fill), x[:, :-s]],
+                           axis=1)
+
+
+def _scan_lanes(x, n: int, op, fill=0):
+    """Hillis-Steele inclusive scan along lanes (log2(n) steps) of an
+    associative ``op`` whose identity is ``fill``; ``x`` may be a tuple
+    of planes that ``op`` combines as one (a double-float pair)."""
     s = 1
     while s < n:
-        shifted = jnp.concatenate(
-            [jnp.zeros_like(c[:, :s]), c[:, :-s]], axis=1)
-        c = c + shifted
+        x = op(jax.tree.map(lambda a: _shift_in(a, s, fill), x), x)
         s <<= 1
-    return c
+    return x
+
+
+def _compact(sel: Array, vals: list, n: int) -> tuple[Array, list]:
+    """Move the ``sel`` lanes of each plane in ``vals`` to the front,
+    in order: a lane goes left by the count of unselected lanes before
+    it, one bit of that distance a step from the least significant.
+    The distances do not decrease along the selected lanes, so no two
+    ever land on one lane, and a mover never wraps (its target is >=
+    0).  Static rotations and selects only.  Returns the occupancy of
+    the result (a prefix) and the moved planes; lanes past it hold
+    stale values."""
+    li = jax.lax.broadcasted_iota(jnp.int32, sel.shape, 1)
+    one = jnp.where(sel, 1, 0)
+    before = _scan_lanes(one, n, jnp.add) - one
+    # n marks a lane that carries nothing: no bit below n is set
+    d = jnp.where(sel, li - before, n)
+    s = 1
+    while s < n:
+        rd = _rot_left(d, s)
+        take = (rd & s) != 0
+        d = jnp.where(take, rd, jnp.where((d & s) != 0, n, d))
+        vals = [jnp.where(take, _rot_left(v, s), v) for v in vals]
+        s <<= 1
+    return d < n, vals
 
 
 @functools.lru_cache(maxsize=None)
@@ -178,7 +210,7 @@ def _build(cap: int, batch_width: int, num_rows: int, delta: float,
     ``delta`` is the internal scale (tdigest._SCALE_MULT *
     compression); ``tail_coeff`` is _TAIL_MULT * compression (0 with
     the refinement gated off).  Scale constants arrive as arguments so
-    this module never imports ops/tdigest (which imports us).
+    they are part of the compiled kernel's cache key.
     """
     n = _pow2_at_least(cap + batch_width)
     if n > _MAX_WIDTH:
@@ -194,9 +226,10 @@ def _build(cap: int, batch_width: int, num_rows: int, delta: float,
         w = w_ref[:]
         key = jnp.where(w > 0, m, jnp.inf)
         key, w = _bitonic(key, w, n)
-        m = jnp.where(w > 0, key, 0.0)
+        live = w > 0               # a prefix of the lanes after the sort
+        m = jnp.where(live, key, 0.0)
 
-        cum = _cumsum_lanes(w, n)
+        cum = _scan_lanes(w, n, jnp.add)
         total = jnp.sum(w, axis=1, keepdims=True)
         q = (cum - w) / jnp.maximum(total, _EPS)
         body = (delta / (2.0 * jnp.pi)) * _asin(
@@ -208,60 +241,35 @@ def _build(cap: int, batch_width: int, num_rows: int, delta: float,
         else:
             kv = body - k0
         cluster = jnp.clip(jnp.floor(kv), 0, cap - 1).astype(jnp.int32)
+        # non-decreasing by construction (neither the asin polynomial
+        # nor the f32 cumsum is proven monotone at rounding), so every
+        # cluster is one run of lanes and there are at most cap runs
+        cluster = _scan_lanes(cluster, n, jnp.maximum)
 
-        wm = w * m
-        chunk = min(_MASK_CHUNK, n)
-        # cluster ids are < cap, so only the chunks covering [0, cap)
-        # can receive weight; lanes past them stay zero
-        live_chunks = -(-cap // chunk)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+        # a run ends at a live lane whose next lane is empty or holds
+        # another id; its sums are prefix sums differenced at run ends,
+        # compensated (a plain f32 cumsum difference loses the tail
+        # clusters' contents against the bulk, ops/tdigest.py)
+        li = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+        end = live & ((li == n - 1) | (_rot_left(w, 1) <= 0) |
+                      (_rot_left(cluster, 1) != cluster))
+        zero = jnp.zeros_like(w)
+        cw = _scan_lanes((w, zero), n, _df_add)
+        cwm = _scan_lanes((w * m, zero), n, _df_add)
+        occ, (wh, wl, mh, ml) = _compact(end, [*cw, *cwm], n)
 
-        def _dot_exact(vec: Array, mask_b16: Array) -> Array:
-            # the TPU dot runs bf16 x bf16 -> f32; a plain cast of the
-            # weight vector quantizes it (~0.2% rel — measured to push
-            # quantile deltas to 5.8e-2 on device), while f32 HIGHEST
-            # precision OOMs VMEM on the unrolled f32 masks.  The
-            # 0/1 mask is EXACT in bf16, so splitting only the vector
-            # into hi+lo bf16 terms gives ~2^-16 relative accuracy
-            # for two MXU passes and half the mask footprint.
-            hi = vec.astype(jnp.bfloat16)
-            lo = (vec - hi.astype(jnp.float32)).astype(jnp.bfloat16)
-            return (jnp.dot(hi, mask_b16,
-                            preferred_element_type=jnp.float32) +
-                    jnp.dot(lo, mask_b16,
-                            preferred_element_type=jnp.float32))
-        rows_w = []
-        rows_wm = []
-        tail_w = n - live_chunks * chunk
-        tail = ([jnp.zeros((1, tail_w), jnp.float32)] if tail_w
-                else [])
-        for i in range(b):
-            # cluster ids to the sublane axis -> one-hot matmul puts
-            # the segment reduction on the MXU: out[c] = sum_i w[i] *
-            # (cluster[i] == c), each weight counted exactly once.
-            # The mask is built per column chunk so VMEM holds
-            # (n, chunk), not (n, n) — what bounds _MAX_WIDTH.
-            cl_t = jnp.swapaxes(cluster[i:i + 1, :], 0, 1)  # (n, 1)
-            pw = []
-            pwm = []
-            for c0 in range(live_chunks):
-                mask = (cl_t == (col + c0 * chunk)).astype(
-                    jnp.bfloat16)                           # (n, chunk)
-                pw.append(_dot_exact(w[i:i + 1, :], mask))
-                pwm.append(_dot_exact(wm[i:i + 1, :], mask))
-            rows_w.append(jnp.concatenate(pw + tail, axis=1))
-            rows_wm.append(jnp.concatenate(pwm + tail, axis=1))
-        out_w = jnp.concatenate(rows_w, axis=0)
-        out_wm = jnp.concatenate(rows_wm, axis=0)
-        out_m = jnp.where(out_w > 0,
-                          out_wm / jnp.maximum(out_w, _EPS), 0.0)
+        def prev(x):
+            return _shift_in(x, 1, 0.0)
 
-        # compact: occupied clusters (ids < cap) to the front, mean-
-        # sorted — the same contract as _merge_impl's pack sort
-        key2 = jnp.where(out_w > 0, out_m, jnp.inf)
-        key2, out_w = _bitonic(key2, out_w, n)
-        om_ref[:] = jnp.where(out_w > 0, key2, 0.0)
-        ow_ref[:] = out_w
+        out_w = _df_diff((wh, wl), (prev(wh), prev(wl)))
+        out_wm = _df_diff((mh, ml), (prev(mh), prev(ml)))
+        out_m = out_wm / jnp.maximum(out_w, _EPS)
+        # a value that straddles a boundary can round one cluster's
+        # mean an ulp past the next one's: keep the ascending contract
+        out_m = _scan_lanes(jnp.where(occ, out_m, -jnp.inf), n,
+                            jnp.maximum, -jnp.inf)
+        om_ref[:] = jnp.where(occ, out_m, 0.0)
+        ow_ref[:] = jnp.where(occ, out_w, 0.0)
 
     grid = (num_rows // b,)
     spec = pl.BlockSpec((b, n), lambda r: (r, 0),

@@ -58,9 +58,10 @@ Array = jax.Array
 # the compensation keeps per-cluster sums exact-in-practice (~2^-48
 # relative; a plain f32 cumsum-diff was measured to corrupt p999 by
 # perturbing tail cluster contents).  "pallas": the whole merge
-# (sort + cluster + segment sums + pack) fused into one Pallas TPU
-# kernel (ops/pallas_merge.py) — one HBM pass each way, no scatter,
-# no second sort pass; falls back to _FALLBACK_MODE where the fused
+# (sort + cluster ids + the same compensated prefix sums, differenced
+# at run ends + compaction) fused into one Pallas TPU kernel
+# (ops/pallas_merge.py) — one HBM pass each way, no scatter, no
+# second sort, no matmul; falls back to _FALLBACK_MODE where the fused
 # kernel doesn't apply (combined width > its 2048-lane bound, which
 # no table-emitted shape exceeds).  The default, "auto",
 # resolves to pallas on a TPU backend and scatter elsewhere (an A/B
