@@ -600,3 +600,220 @@ def test_sharded_import_set_wire_gives_the_per_item_paths_planes():
     assert m["u.a"] == pytest.approx(500, rel=0.05)
     assert m["u.b"] == pytest.approx(40, rel=0.05)
     assert m["u.c"] == pytest.approx(900, rel=0.05)
+
+
+# ----------------------------------------------------------------------
+# the interval reset: the next interval's state made on the chips, the
+# closed interval's host register planes cleared after its flush
+
+
+def _host_built_empty_state(mesh, cfg):
+    """The fresh state as it was once made: numpy planes on the host,
+    each put on the mesh in its sharding."""
+    from jax.sharding import NamedSharding
+
+    from veneur_tpu.ops import hll
+    from veneur_tpu.ops.segment import (HISTO_STAT_COLS, STAT_MAX,
+                                        STAT_MAX_EMPTY, STAT_MIN,
+                                        STAT_MIN_EMPTY)
+    from veneur_tpu.parallel import sharded
+    s = mesh.shape["shard"]
+    r, cap = cfg.rows, cfg.capacity()
+    stats = np.zeros((s, r, HISTO_STAT_COLS), np.float32)
+    stats[:, :, STAT_MIN] = STAT_MIN_EMPTY
+    stats[:, :, STAT_MAX] = STAT_MAX_EMPTY
+    host = {
+        "counters": np.zeros((s, cfg.c_rows()), np.float32),
+        "gauges": np.zeros((s, cfg.g_rows()), np.float32),
+        "gauge_ticket": np.full((s, cfg.g_rows()), -1, np.int32),
+        "histo_stats": stats, "histo_import_stats": stats,
+        "histo_means": np.zeros((s, r, cap), np.float32),
+        "histo_weights": np.zeros((s, r, cap), np.float32),
+        "hll": np.zeros((s, cfg.set_rows, hll.M), np.uint8)}
+    specs = sharded._specs(mesh)
+    return {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+            for k, v in host.items()}
+
+
+@pytest.mark.parametrize("n_shard", [2, 4])
+def test_empty_state_made_on_the_mesh_is_the_host_built_one(n_shard):
+    from veneur_tpu.parallel import sharded
+    mesh = make_mesh(jax.devices(), n_shard=n_shard)
+    cfg = ShardedConfig(rows=32, set_rows=8, counter_rows=64,
+                        gauge_rows=16, slots=16, batch=128)
+    got = sharded.empty_state(mesh, cfg)
+    want = _host_built_empty_state(mesh, cfg)
+    assert set(got) == set(want)
+    for k, w in want.items():
+        g = got[k]
+        assert (g.shape, g.dtype) == (w.shape, w.dtype), k
+        assert g.sharding.spec == w.sharding.spec, k
+        assert g.sharding.mesh == mesh, k
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # every plane a buffer of its own (the update step may donate them)
+    ptrs = [sh.data.unsafe_buffer_pointer()
+            for a in got.values() for sh in a.addressable_shards]
+    assert len(set(ptrs)) == len(ptrs)
+
+
+def _interval(table, series, value):
+    """One interval's imports of every class into ``table``: one call
+    a series, as many calls as ``series`` has entries."""
+    from veneur_tpu.ops import hll
+    from veneur_tpu.protocol import dogstatsd as dsd
+    rng = np.random.default_rng(int(value))
+    for name in series:
+        kind = name[0]
+        if kind == "c":
+            assert table.import_counter(name, (), value)
+        elif kind == "g":
+            assert table.import_gauge(name, (), value + 0.5)
+        elif kind == "h":
+            vals = np.sort(rng.uniform(1, value + 2, 40)).astype(
+                np.float32)
+            stats = np.asarray([len(vals), vals[0], vals[-1],
+                                vals.sum(), (1.0 / vals).sum()],
+                               np.float32)
+            assert table.import_histo(name, dsd.TIMER, (), stats, vals,
+                                      np.ones(len(vals), np.float32))
+        else:
+            regs = rng.integers(0, 20, hll.M).astype(np.uint8)
+            assert table.import_set(name, (), regs)
+    table.device_step()
+
+
+_PLANES = ("counters", "gauges", "histo_stats", "histo_import_stats",
+           "histo_means", "histo_weights", "hll_regs")
+
+
+@pytest.mark.parametrize("cleared_after_flush", [True, False])
+def test_no_register_or_centroid_survives_a_reset(cleared_after_flush):
+    """Intervals of sets, digests, counters and gauges, then the last
+    interval's imports alone: its merged planes are bit for bit those
+    of the same imports into a fresh table.  Three intervals, so the
+    last writes the host register buffer the first one wrote; the
+    server's cycle cleared the closed buffer (``clear_closed``), or
+    the next reset had to, which then counts the bytes."""
+    from veneur_tpu.ops import hll
+    last = ["c0", "c1", "g0", "h0", "h1", "s0", "s1", "s2"]
+    # the last interval's series first (the same rows on both tables),
+    # and an even count of calls: the shard cursor is where a fresh
+    # table's is when the last interval starts
+    earlier = last + ["c7", "g7", "h7", "s7", "s8", "s9"]
+    used = _mesh_table()
+    snaps = []
+    for i, series in enumerate((earlier, earlier, last)):
+        _interval(used, series, 3.0 + i)
+        snaps.append(used.swap())
+        if cleared_after_flush:
+            assert used.clear_closed() == snaps[-1].mesh_counts[
+                "reset_deferred_bytes"]
+    fresh = _mesh_table()
+    _interval(fresh, last, 5.0)
+    want = fresh.swap()
+    for k in _PLANES:
+        np.testing.assert_array_equal(np.asarray(getattr(snaps[-1], k)),
+                                      np.asarray(getattr(want, k)), k)
+    sets_of = {"earlier": 6, "last": 3}
+    deferred = [s.mesh_counts["reset_deferred_bytes"] for s in snaps]
+    assert deferred == [sets_of["earlier"] * hll.M,
+                        sets_of["earlier"] * hll.M,
+                        sets_of["last"] * hll.M]
+    host = [s.mesh_counts["reset_host_bytes"] for s in snaps]
+    # uncleared, each reset from the second clears the buffer the
+    # interval before the last wrote
+    assert host == ([0, 0, 0] if cleared_after_flush else
+                    [0, sets_of["earlier"] * hll.M,
+                     sets_of["earlier"] * hll.M])
+
+
+def test_a_flush_cycle_leaves_both_host_plane_buffers_clean():
+    """Through the server: the cycle clears the closed interval's host
+    register planes once it has delivered, so the reset ships, builds
+    and clears nothing on the host, and the next interval's first
+    ``set_plane`` finds zeros.  The record and the ``state_reset``
+    span say so."""
+    import time
+
+    from veneur_tpu.core.config import read_config
+    from veneur_tpu.core.server import Server
+    from veneur_tpu.ops import hll
+    from veneur_tpu.sinks.simple import CaptureSink
+
+    cap = CaptureSink()
+    srv = Server(read_config(data={
+        "statsd_listen_addresses": [], "interval": "10s",
+        "tpu_mesh_shards": 2, "tpu_histo_rows": 64, "tpu_set_rows": 16,
+        "tpu_counter_rows": 64, "tpu_gauge_rows": 64}),
+        extra_sinks=[cap])
+    srv.start()
+    try:
+        agg = srv.table.agg
+        rng = np.random.default_rng(41)
+        for n in (5, 3):
+            for i in range(n):
+                assert srv.table.import_set(
+                    f"u.{i}", (), rng.integers(1, 30, hll.M).astype(
+                        np.uint8))
+            srv.flush_once()
+            assert not agg._set_touched.any()
+            assert not agg._closed_touched.any()
+            assert not agg._set_planes.any()
+            assert not agg._closed_planes.any()
+        plane, touched = agg.set_plane(0)
+        assert not plane.any() and not touched.any()
+        recs = srv.flush_ring.records()[-2:]
+        assert [r.reset_host_bytes for r in recs] == [0, 0]
+        assert [r.reset_deferred_bytes for r in recs] == [
+            5 * hll.M, 3 * hll.M]
+        assert recs[-1].to_dict()["reset_deferred_bytes"] == 3 * hll.M
+        assert recs[-1].to_dict()["reset_host_bytes"] == 0
+        assert {m.name for m in cap.metrics} >= {"u.0", "u.4"}
+        for rec in recs:
+            deadline = time.monotonic() + 10
+            spans = []
+            while time.monotonic() < deadline and not spans:
+                spans = [s for s in srv.trace_index.get(rec.trace_id)
+                         if s["name"] == "flush.snapshot.state_reset"]
+                time.sleep(0.01)
+            assert spans and spans[0]["tags"]["host_bytes"] == "0"
+    finally:
+        srv.shutdown()
+
+
+def test_compile_leaves_the_first_swap_nothing_to_compile(mesh, cfg):
+    """``compile()`` before traffic: the first interval's update
+    calls, the sketches' union, the merge and the fresh state compile
+    nothing (an aggregator that skipped it compiles in its swap)."""
+    from jax import monitoring
+
+    from veneur_tpu.ops import hll
+    events = []
+
+    def note(name, secs, **kw):
+        if name.startswith("/jax/core/compile/"):
+            events.append(name)
+
+    def first_swap(agg):
+        rows = np.arange(6, dtype=np.int32)
+        agg.stage(1, counter_rows=rows, counter_vals=np.ones(6, np.float32),
+                  counter_wts=np.ones(6, np.float32),
+                  histo_rows=rows, histo_vals=np.ones(6, np.float32),
+                  histo_wts=np.ones(6, np.float32))
+        plane, touched = agg.set_plane(0)
+        plane[2] = np.arange(hll.M) % 7
+        touched[2] = True
+        del events[:]
+        monitoring.register_event_duration_secs_listener(note)
+        try:
+            merged = agg.swap()
+            jax.block_until_ready(agg.state)
+        finally:
+            monitoring.unregister_event_duration_listener(note)
+        assert float(np.asarray(merged["counters"]).sum()) == 6.0
+        return list(events)
+
+    agg = ShardedAggregator(mesh, cfg)
+    agg.compile()
+    assert first_swap(agg) == []
+    assert first_swap(ShardedAggregator(mesh, cfg)) != []

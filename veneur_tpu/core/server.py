@@ -2559,6 +2559,13 @@ class Server:
                 record=cyc.record)
         except Exception:
             log.exception("self-telemetry emission failed")
+        # the closed interval's host register planes, cleared now that
+        # its flush has delivered: on this thread, off the ingest lock
+        # and the lag's path, before the next swap turns to them (the
+        # mesh table's; one chip's recycles its plane at release)
+        clear_closed = getattr(self.table, "clear_closed", None)
+        if clear_closed is not None:
+            clear_closed()
         return res
 
     def _route_sinks(self, res, other, cyc, led, parent):

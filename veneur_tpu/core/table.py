@@ -543,7 +543,8 @@ class Snapshot:
     import_counts: dict[str, int] = field(default_factory=dict)
     # what a mesh table's swap did, under the ``FlushRecord``'s field
     # names (``shard_steps``, ``shard_staged``, ``mesh``,
-    # ``merge_path``); empty from one chip's table
+    # ``merge_path``, ``reset_host_bytes``, ``reset_deferred_bytes``);
+    # empty from one chip's table
     mesh_counts: dict[str, Any] = field(default_factory=dict)
 
     @property

@@ -71,11 +71,17 @@ class FlushRecord:
     # shard, the mesh as ``<shards>x<series>``, and the digest path
     # its flush merge took over the gathered slots (``pallas``, or
     # the fallback past the kernel's lanes).  The swap's parts are in
-    # ``stages`` under ``snapshot.final_step|shard_merge|state_reset``
+    # ``stages`` under ``snapshot.final_step|shard_merge|state_reset``.
+    # Of the reset: the bytes it built, shipped or cleared on the host
+    # (0 when the closed interval's register planes were cleared after
+    # their flush), and the bytes of those planes it left to be
+    # cleared once this cycle has delivered
     shard_steps: int = 0
     shard_staged: list[int] = field(default_factory=list)
     mesh: str = ""
     merge_path: str = ""
+    reset_host_bytes: int = 0
+    reset_deferred_bytes: int = 0
     tally: dict[str, int] = field(default_factory=dict)
     compiles: int = 0  # compile events observed during this cycle
     # collector pauses that ended inside the cycle, on any thread
@@ -114,6 +120,8 @@ class FlushRecord:
                 "shard_staged": list(self.shard_staged),
                 "mesh": self.mesh,
                 "merge_path": self.merge_path,
+                "reset_host_bytes": self.reset_host_bytes,
+                "reset_deferred_bytes": self.reset_deferred_bytes,
                 "tally": dict(self.tally),
                 "compiles": self.compiles,
                 "gc_pause_ns": self.gc_pause_ns,

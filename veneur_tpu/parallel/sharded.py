@@ -120,33 +120,41 @@ def _specs(mesh: Mesh):
     }
 
 
-def empty_state(mesh: Mesh, cfg: ShardedConfig) -> dict:
-    """Allocate the sharded state pytree on the mesh."""
+def make_empty_state(mesh: Mesh, cfg: ShardedConfig):
+    """Jitted program with no input: a fresh state pytree, made on the
+    mesh in its sharding.  Every plane is a broadcast constant, so
+    nothing is built on the host or crosses to the device."""
     s = mesh.shape[SHARD]
     r, rs = cfg.rows, cfg.set_rows
     rc, rg = cfg.c_rows(), cfg.g_rows()
     cap = cfg.capacity()
-    specs = _specs(mesh)
+    empty_row = np.zeros(HISTO_STAT_COLS, np.float32)
+    empty_row[STAT_MIN] = STAT_MIN_EMPTY
+    empty_row[STAT_MAX] = STAT_MAX_EMPTY
 
-    def dev(name, arr):
-        return jax.device_put(arr, NamedSharding(mesh, specs[name]))
+    def fresh():
+        def stats():
+            return jnp.broadcast_to(jnp.asarray(empty_row),
+                                    (s, r, HISTO_STAT_COLS))
+        return {
+            "counters": jnp.zeros((s, rc), jnp.float32),
+            "gauges": jnp.zeros((s, rg), jnp.float32),
+            "gauge_ticket": jnp.full((s, rg), -1, jnp.int32),
+            "histo_stats": stats(),
+            "histo_import_stats": stats(),
+            "histo_means": jnp.zeros((s, r, cap), jnp.float32),
+            "histo_weights": jnp.zeros((s, r, cap), jnp.float32),
+            "hll": jnp.zeros((s, rs, hll_ops.M), jnp.uint8),
+        }
 
-    stats = np.zeros((s, r, HISTO_STAT_COLS), np.float32)
-    stats[:, :, STAT_MIN] = STAT_MIN_EMPTY
-    stats[:, :, STAT_MAX] = STAT_MAX_EMPTY
-    return {
-        "counters": dev("counters", np.zeros((s, rc), np.float32)),
-        "gauges": dev("gauges", np.zeros((s, rg), np.float32)),
-        "gauge_ticket": dev("gauge_ticket",
-                            np.full((s, rg), -1, np.int32)),
-        "histo_stats": dev("histo_stats", stats),
-        "histo_import_stats": dev("histo_import_stats", stats),
-        "histo_means": dev("histo_means",
-                           np.zeros((s, r, cap), np.float32)),
-        "histo_weights": dev("histo_weights",
-                             np.zeros((s, r, cap), np.float32)),
-        "hll": dev("hll", np.zeros((s, rs, hll_ops.M), np.uint8)),
-    }
+    return jax.jit(fresh, out_shardings={
+        k: NamedSharding(mesh, spec)
+        for k, spec in _specs(mesh).items()})
+
+
+def empty_state(mesh: Mesh, cfg: ShardedConfig) -> dict:
+    """Allocate the sharded state pytree on the mesh."""
+    return make_empty_state(mesh, cfg)()
 
 
 # a forwarded digest's statistics, staged as a row of their own in
@@ -572,7 +580,10 @@ class ShardedAggregator:
         self.mesh = mesh
         self.cfg = cfg or ShardedConfig()
         self.n_shard = mesh.shape[SHARD]
-        self.state = empty_state(mesh, self.cfg)
+        # the fresh state, made on the chips: the first one here (which
+        # compiles the program before traffic), the next at each reset
+        self._empty_state = make_empty_state(mesh, self.cfg)
+        self.state = self._empty_state()
         self._update = make_update_step(mesh, self.cfg)
         self._merge = make_merge_step(mesh, self.cfg)
         self._ticket = 0
@@ -608,10 +619,20 @@ class ShardedAggregator:
         self.staged = [0] * self.n_shard
         # forwarded sketches: a host register plane a shard, unioned
         # natively as the wires arrive and shipped whole at the merge;
-        # made with its touched mask at the first imported sketch
+        # made with its touched mask at the first imported sketch.
+        # Two such buffers: ``reset`` turns to the clean one, and the
+        # closed interval's is cleared by ``clear_closed`` once its
+        # flush has delivered, off the ingest lock
         self._set_planes: np.ndarray | None = None
         self._set_touched = np.zeros(
             (self.n_shard, self.cfg.set_rows), bool)
+        self._closed_planes: np.ndarray | None = None
+        self._closed_touched = np.zeros_like(self._set_touched)
+        # what the last reset did on the host: the bytes it cleared
+        # itself (0 unless the closed buffer was not cleared after its
+        # flush) and the bytes it left to ``clear_closed``
+        self.reset_host_bytes = 0
+        self.reset_deferred_bytes = 0
 
     @staticmethod
     def _empty_stage():
@@ -643,8 +664,9 @@ class ShardedAggregator:
         """(register plane u8[set_rows, M], touched rows) of the
         host-side union of ``shard``'s forwarded sketches."""
         if self._set_planes is None:
-            self._set_planes = np.zeros(
-                (self.n_shard, self.cfg.set_rows, hll_ops.M), np.uint8)
+            shape = (self.n_shard, self.cfg.set_rows, hll_ops.M)
+            self._set_planes = np.zeros(shape, np.uint8)
+            self._closed_planes = np.zeros(shape, np.uint8)
         shard %= self.n_shard
         return self._set_planes[shard], self._set_touched[shard]
 
@@ -754,14 +776,18 @@ class ShardedAggregator:
         through the update step and the state through the merge,
         whose result is dropped.  A first-sight compile takes tens of seconds
         on a mesh and would otherwise run inside an import handler or
-        a flush, under the server's ingest lock."""
+        a flush, under the server's ingest lock.  The fresh-state
+        program compiled at construction, making the first state."""
         batch = {k: np.full((self.n_shard, self.cfg.batch),
                             self._fill.get(k, 0), dt)
                  for k, dt in self._DTYPES.items()}
         # a batch of padding leaves the state as it was (and a build
-        # that donates the state has the new one in its place)
-        self.state = self._update(
-            self.state, jax.device_put(batch, self._batch_sharding))
+        # that donates the state has the new one in its place); twice,
+        # since jit traces the update once for a fresh state and once
+        # more for a state the update made
+        for _ in range(2):
+            self.state = self._update(
+                self.state, jax.device_put(batch, self._batch_sharding))
         self.set_plane(0)       # the sketches' union compiles as well
         jax.block_until_ready(self._merge({
             **self.state,
@@ -796,14 +822,33 @@ class ShardedAggregator:
     def reset(self) -> None:
         """Fresh partial state for the next interval (the
         double-buffer swap the single-chip table does at flush,
-        worker.go:498).  The host planes are cleared where the closed
-        interval wrote them; the merge that read them is fenced."""
-        self.state = empty_state(self.mesh, self.cfg)
+        worker.go:498): made on the chips, and the host planes turned
+        to the clean buffer.  The closed interval's planes, which the
+        fenced merge has shipped, are left to ``clear_closed``; a
+        buffer still dirty from the interval before is cleared here,
+        and counted in ``reset_host_bytes``."""
+        self.state = self._empty_state()
+        self.reset_host_bytes = self.clear_closed()
+        self.reset_deferred_bytes = (int(self._set_touched.sum()) *
+                                     hll_ops.M)
         if self._set_planes is not None:
-            self._set_planes[self._set_touched] = 0
-            self._set_touched[:] = False
+            self._set_planes, self._closed_planes = (
+                self._closed_planes, self._set_planes)
+            self._set_touched, self._closed_touched = (
+                self._closed_touched, self._set_touched)
         self.steps = 0
         self.staged = [0] * self.n_shard
+
+    def clear_closed(self) -> int:
+        """Clear the closed interval's host planes where it wrote them,
+        so the next ``reset`` finds that buffer clean.  Returns the
+        bytes cleared."""
+        touched = self._closed_touched
+        n = int(touched.sum())
+        if n:
+            self._closed_planes[touched] = 0
+            touched[:] = False
+        return n * hll_ops.M
 
     def flush(self, qs=(0.5, 0.9, 0.99)) -> dict:
         """Merge partial shards with collectives and read out."""
@@ -1179,6 +1224,12 @@ class ShardedTable:
             self.agg.step()
             self._staged_n = 0
 
+    def clear_closed(self) -> int:
+        """The closed interval's host register planes cleared, off the
+        ingest lock: the server calls it once the interval's flush has
+        delivered (``ShardedAggregator.clear_closed``)."""
+        return self.agg.clear_closed()
+
     def take_status(self):
         out = self.status
         self.status = {}
@@ -1193,8 +1244,10 @@ class ShardedTable:
         span or None (the server passes its ``snapshot`` stage's):
         ``final_step``, the staged remainder through SPMD updates;
         ``shard_merge``, the collective merge up to its fence, tagged
-        with what it did; ``state_reset``, the fresh state on the
-        mesh."""
+        with what it did; ``state_reset``, the fresh state made on the
+        mesh, tagged ``host_bytes`` (what it built, shipped or cleared
+        on the host: 0 once the closed planes are cleared after each
+        flush, ``clear_closed``)."""
         from veneur_tpu.core import table as core_table
         with stage("final_step"):
             self.device_step(final=True)
@@ -1211,8 +1264,13 @@ class ShardedTable:
                        "shard_staged": list(self.agg.staged),
                        "mesh": info["mesh"],
                        "merge_path": info["merge_path"]}
-        with stage("state_reset"):
+        with stage("state_reset") as sp:
             self.agg.reset()
+            if sp is not None:
+                sp.add_tag("host_bytes", str(self.agg.reset_host_bytes))
+        mesh_counts["reset_host_bytes"] = self.agg.reset_host_bytes
+        mesh_counts["reset_deferred_bytes"] = \
+            self.agg.reset_deferred_bytes
         snap = core_table.Snapshot(
             gen=self.gen,
             counters=merged["counters"],
